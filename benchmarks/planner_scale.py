@@ -15,7 +15,7 @@ spec-resolved batched solve and reports:
   tensor, asserted bitwise identical (same splits, costs, feasibility).
 * **rebuild** — a surface rebuild driven through ``FleetGateway``'s
   rebuilder twice: in-process (the spec resolved on this process) vs
-  out-of-process (the spec pickled to a spawned
+  out-of-process (the spec pickled to a spawned, CPU-pinned
   ``ProcessPoolExecutor`` worker via
   ``repro.core.spec.build_surfaces_from_spec``). The pool wall
   includes worker spawn + import — the honest cold-start cost of the
@@ -37,12 +37,11 @@ from __future__ import annotations
 
 import argparse
 import json
-import multiprocessing as mp
 import time
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 
+from repro.core.async_replan import cpu_process_pool
 from repro.core.profiles import (
     ESP_NOW,
     PROTOCOLS,
@@ -57,6 +56,7 @@ from repro.core.spec import (
     tensor_spec,
 )
 from repro.core.sweep import solve_batched
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.gateway import FleetGateway
 
 SMOKE_S, FULL_S = 2_000, 100_000
@@ -146,8 +146,7 @@ def _serialization(solve_wall_s: float, repeats: int = 200) -> dict:
 
 def _rebuild() -> dict:
     model = paper_cost_model("mobilenet_v2", "esp_now")
-    pool = ProcessPoolExecutor(max_workers=1,
-                               mp_context=mp.get_context("spawn"))
+    pool = cpu_process_pool()
     gw = FleetGateway(model, PROTOCOLS, (2, 3), surface_grid=GRID,
                       executor=pool)
     try:
@@ -203,6 +202,7 @@ def main() -> None:
                     help="path for the machine-readable result "
                          "(empty to skip)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("\n=== planner_scale: PlanSpec-resolved solves at fleet scale ===")
     report = run(smoke=args.smoke)

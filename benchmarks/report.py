@@ -1,12 +1,11 @@
-"""Generate the §Dry-run and §Roofline markdown tables into EXPERIMENTS.md
-(replaces the <!-- DRYRUN_TABLE --> / <!-- ROOFLINE_TABLE --> markers)."""
+"""Generate the §Dry-run markdown table into EXPERIMENTS.md (replaces the
+<!-- DRYRUN_TABLE --> marker)."""
 
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-from benchmarks.roofline import run as roofline_run
 from repro.configs import ARCH_IDS, applicable_shapes
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -36,25 +35,10 @@ def dryrun_table() -> str:
     return "\n".join(lines)
 
 
-def roofline_table() -> str:
-    lines = [
-        "| arch | shape | t_compute | t_memory | t_coll | dominant | roofline frac | useful frac |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in roofline_run("16x16"):
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['t_compute_s'] * 1e3:.2f} ms "
-            f"| {r['t_memory_s'] * 1e3:.2f} ms | {r['t_coll_s'] * 1e3:.2f} ms "
-            f"| **{r['dominant']}** | {100 * r['roofline_frac']:.0f}% "
-            f"| {100 * r['useful_frac']:.0f}% |")
-    return "\n".join(lines)
-
-
 def main():
     exp = ROOT / "EXPERIMENTS.md"
     text = exp.read_text()
     text = text.replace("<!-- DRYRUN_TABLE -->", dryrun_table())
-    text = text.replace("<!-- ROOFLINE_TABLE -->", roofline_table())
     exp.write_text(text)
     print("EXPERIMENTS.md tables updated")
 

@@ -1,5 +1,5 @@
 """Benchmark orchestrator: one section per paper table/figure + the
-roofline and beyond-paper planner benchmarks.
+beyond-paper planner benchmarks.
 
 Usage:
   PYTHONPATH=src python benchmarks/run.py                 # every section
@@ -30,6 +30,8 @@ from pathlib import Path
 _ROOT = str(Path(__file__).resolve().parent.parent)
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
+
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def _timed(name, derive):
@@ -135,15 +137,6 @@ def _run_planner(csv_lines):
           f"checks={ok} ===")
 
 
-def _run_roofline(csv_lines):
-    try:
-        _timed("roofline",
-               lambda r: f"{r['arch']}/{r['shape']}_dom={r['dominant']}"
-                         f"_frac={r['roofline_frac']:.2f}")(csv_lines)
-    except Exception as e:  # dry-run artifacts may not exist yet
-        print(f"[roofline] skipped: {e}")
-
-
 # THE registry: name -> runner(csv_lines). Insertion order is run order.
 SECTIONS = {
     "table2_transmission": _timed(
@@ -170,7 +163,6 @@ SECTIONS = {
     "surface_replan": _run_surface_replan,
     "gateway": _run_gateway,
     "planner": _run_planner,
-    "roofline": _run_roofline,
 }
 
 BENCHMARKS = tuple(SECTIONS)
@@ -190,6 +182,7 @@ def main(argv: list[str] | None = None) -> None:
             f"error: unknown benchmark name(s): {', '.join(unknown)}\n"
             f"available benchmarks: {', '.join(BENCHMARKS)}")
     selected = set(args.names) if args.names else set(BENCHMARKS)
+    enable_compile_cache()
 
     csv_lines = ["name,us_per_call,derived"]
     for name, runner in SECTIONS.items():

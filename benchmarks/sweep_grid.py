@@ -60,6 +60,7 @@ from repro.core.sweep import (
     sweep,
     sweep_scalar,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 LOSS_P = (None, 0.01, 0.05, 0.10)
 RATE_SCALE = (1.0, 0.5, 0.25, 0.125)
@@ -459,6 +460,7 @@ def main() -> None:
                          "check_bench --sweep candidate (required "
                          "sections are missing by construction).")
     args = ap.parse_args()
+    enable_compile_cache()
     sections = tuple(s for s in args.sections.split(",") if s)
     unknown = set(sections) - set(ALL_SECTIONS)
     if unknown:

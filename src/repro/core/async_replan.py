@@ -47,7 +47,10 @@ This module makes rebuilds *asynchronous* (stale-while-revalidate):
   surface family back. Generation/swap adoption semantics are
   identical to the thread path (the done-callback publishes under the
   same lock), so process-built surfaces are node-identical to their
-  in-process twins.
+  in-process twins. A chip belongs to one process: pool workers must
+  stay off the accelerator (:func:`cpu_process_pool` pins them to the
+  CPU), so a pool rebuilder accepts ``backend="numpy"`` only — device
+  backends rebuild in-process, on the thread executor.
 
 The executor contract (:class:`RebuildExecutor`): ``submit()`` is
 REQUIRED, ``shutdown()`` is OPTIONAL — :class:`ManualExecutor` has
@@ -70,6 +73,8 @@ the serving loop instead of dying silently on a worker.
 
 from __future__ import annotations
 
+import multiprocessing
+import os
 import threading
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -91,10 +96,30 @@ __all__ = [
     "RebuildHandle",
     "RebuildRequest",
     "SurfaceRebuilder",
+    "cpu_process_pool",
     "recentered_axes",
 ]
 
 _StateMap = Mapping[str, tuple[float, float]]
+
+
+def _pin_worker_to_cpu() -> None:
+    """Pool-worker initializer: keep the worker's JAX on the CPU. The
+    serving process holds the chip, and a worker that initialized a
+    device backend would contend for it (or hang waiting)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
+def cpu_process_pool(max_workers: int = 1) -> ProcessPoolExecutor:
+    """A spawn-context process pool whose workers are pinned to the CPU
+    — the executor for out-of-process ``backend="numpy"`` rebuilds."""
+    return ProcessPoolExecutor(
+        max_workers=max_workers,
+        mp_context=multiprocessing.get_context("spawn"),
+        initializer=_pin_worker_to_cpu)
 
 
 class RebuildExecutor(Protocol):
@@ -261,7 +286,8 @@ class SurfaceRebuilder:
     — ``shutdown()`` is optional and probed for, never assumed): the
     default is a single-worker thread pool; pass a
     :class:`ManualExecutor` for deterministic tests, or a
-    ``ProcessPoolExecutor`` to move builds out of the serving process —
+    ``ProcessPoolExecutor`` (:func:`cpu_process_pool`;
+    ``backend="numpy"`` only) to move builds out of the serving process —
     the request then travels as a pickled
     :class:`~repro.core.spec.PlanSpec` (:meth:`spec_for`) and the
     worker runs :func:`~repro.core.spec.build_surfaces_from_spec`.
@@ -290,6 +316,12 @@ class SurfaceRebuilder:
         variants=None,
         accuracy_floor: float | None = None,
     ):
+        if isinstance(executor, ProcessPoolExecutor) and backend != "numpy":
+            raise ValueError(
+                f"process-pool rebuilds run backend='numpy' only (got "
+                f"{backend!r}): a pool worker that starts a device backend "
+                f"contends for the chip this process holds. Rebuild "
+                f"in-process (the default thread executor) instead.")
         self.cost_model = cost_model
         self.protocols = dict(protocols)
         self.solver = solver

@@ -139,8 +139,7 @@ def _dp_step_tile(dp, ck_shift, ns, k, combine):
     else:
         cand = jnp.maximum(dp[:, :, None], ck_shift)
     ndp = jnp.min(cand, axis=1)
-    arg = jnp.where(jnp.isfinite(ndp),
-                    jnp.argmin(cand, axis=1).astype(jnp.int32) + 1, -1)
+    arg = jnp.where(jnp.isfinite(ndp), SW._first_argmin(cand, ndp, 1) + 1, -1)
     act = ns >= k
     ndp = jnp.where(act, ndp, dp)
     arg = jnp.where(act, arg, -1)
@@ -308,6 +307,20 @@ def _pad_ns_column(ns_arr: np.ndarray, Sn: int, Sp: int) -> np.ndarray:
     return nsp
 
 
+def _pad_cost_tensor(C: np.ndarray, Sp: int, Lp: int, dtype) -> np.ndarray:
+    """``C`` cast to the kernel dtype, +inf-padded to ``Lp`` lanes and
+    replica-padded to ``Sp`` rows. Built directly in ``dtype``: a float64
+    staging copy would double the host footprint of the padded tensor
+    (10.7 GB at S=16,384, N=5, Lp=128), and the cast rounds each entry
+    exactly as the device transfer of a float64 copy would."""
+    Sn, N, L, _ = C.shape
+    Cp = np.full((Sp, N, Lp, Lp), INF, dtype=dtype)
+    Cp[:Sn, :, :L, :L] = C
+    if Sp > Sn:
+        Cp[Sn:] = Cp[Sn - 1]  # replica rows: already-valid inputs
+    return Cp
+
+
 def _trivial_tables(dp0, Sn: int, N: int, L: int, dtype):
     """Host-side tables for the kernel-free cases (N == 1 or S == 0)."""
     dps = np.zeros((Sn, max(N - 1, 0), L), dtype=dtype)
@@ -345,15 +358,12 @@ def pallas_dp_tables(
         return _trivial_tables(C[:, 0, 0, :].astype(dtype), Sn, N, L, dtype)
     bs, itp = _resolve_opts(block_s, interpret)
     Lp, Sp = _pad_lanes(L), _pad_rows(Sn, bs)
-    Cp = np.full((Sp, N, Lp, Lp), INF, dtype=np.float64)
-    Cp[:Sn, :, :L, :L] = C
-    if Sp > Sn:
-        Cp[Sn:] = Cp[Sn - 1]  # replica rows: already-valid inputs
+    Cp = _pad_cost_tensor(C, Sp, Lp, dtype)
     nsp = _pad_ns_column(ns_arr, Sn, Sp)
     import jax.numpy as jnp
 
     solver = _pallas_dp_solver("dense", combine, bs, itp)
-    dp0, dps, args = solver(jnp.asarray(Cp, dtype=dtype), jnp.asarray(nsp))
+    dp0, dps, args = solver(jnp.asarray(Cp), jnp.asarray(nsp))
     dp0 = np.asarray(dp0)[:Sn, :L]
     dps = np.asarray(dps)[:Sn, :, :L]
     args = np.asarray(args)[:Sn, :, :L]

@@ -9,9 +9,7 @@ reads scenario ``t``. This module partitions exactly that axis:
 
 * :func:`sharded_dp_tables` — the stacked ``C[S, N, L, L]`` tensor is
   padded to a multiple of the shard count, split over a 1-D device
-  mesh with ``shard_map`` (``jax.shard_map`` on modern JAX,
-  ``jax.experimental.shard_map`` on 0.4/0.5), and each
-  shard runs the SAME vmapped ``lax.scan`` DP kernel the single-device
+  mesh with ``jax.shard_map``, and each shard runs the SAME vmapped ``lax.scan`` DP kernel the single-device
   JAX backend runs (:func:`repro.core.sweep._dp_jax_kernel` — shared
   by construction, so per-scenario arithmetic is identical and results
   are node-identical to ``backend="jax"``). Padding rows are replicas
@@ -187,12 +185,8 @@ def _sharded_dp_solver(combine: str, n_shards: int, kernel: str = "jax",
     node-identical to single-device pallas, which is node-identical to
     jax). ``block_s``/``interpret`` apply to the pallas kernel only."""
     import jax
-
-    try:  # jax >= 0.6: shard_map's public home
-        from jax import shard_map
-    except ImportError:  # jax 0.4/0.5 (this container pins 0.4.37)
-        from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     rep_kwargs = {}
     if kernel == "jax":
@@ -203,7 +197,7 @@ def _sharded_dp_solver(combine: str, n_shards: int, kernel: str = "jax",
         fn = PD._raw_pallas_fn("dense", combine, block_s, interpret)
         # pallas_call has no shard_map replication rule; the check is
         # moot anyway — every in/out spec partitions along "s"
-        rep_kwargs = {"check_rep": False}
+        rep_kwargs = {"check_vma": False}
     else:
         raise ValueError(f"unknown shard kernel {kernel!r}; "
                          f"options: ['jax', 'pallas']")
@@ -215,7 +209,10 @@ def _sharded_dp_solver(combine: str, n_shards: int, kernel: str = "jax",
         out_specs=(P(axis), P(axis), P(axis)),
         **rep_kwargs,
     )
-    return jax.jit(sharded)
+    # host operands go straight to their shards: without in_shardings a
+    # host array lands whole on the first device before being split
+    split = NamedSharding(mesh, P(axis))
+    return jax.jit(sharded, in_shardings=(split, split))
 
 
 def sharded_dp_tables(
@@ -266,17 +263,11 @@ def sharded_dp_tables(
         dtype = jax.dtypes.canonicalize_dtype(np.float64)
         Lp = PD._pad_lanes(L)
         Sp = Sn + _pad_to_multiple(Sn, shards * bs)  # whole blocks/shard
-        Cp = np.full((Sp, N, Lp, Lp), float("inf"), dtype=np.float64)
-        Cp[:Sn, :, :L, :L] = C
-        if Sp > Sn:
-            Cp[Sn:] = Cp[Sn - 1]
+        Cp = PD._pad_cost_tensor(C, Sp, Lp, dtype)
         nsp = PD._pad_ns_column(ns_arr, Sn, Sp)
-        import jax.numpy as jnp
-
         solver = _sharded_dp_solver(combine, shards, "pallas", bs, itp,
                                     mesh_spec=mesh_spec)
-        dp0, dps, args = solver(jnp.asarray(Cp, dtype=dtype),
-                                jnp.asarray(nsp))
+        dp0, dps, args = solver(Cp, nsp)
         dp0 = np.asarray(dp0)[:Sn, :L]
         dps = np.asarray(dps)[:Sn, :, :L]
         args = np.asarray(args)[:Sn, :, :L]
@@ -285,11 +276,12 @@ def sharded_dp_tables(
     if pad:
         C = np.concatenate([C, np.repeat(C[-1:], pad, axis=0)], axis=0)
         ns_arr = np.concatenate([ns_arr, np.repeat(ns_arr[-1:], pad)])
-    import jax.numpy as jnp
+    import jax
 
+    dtype = jax.dtypes.canonicalize_dtype(np.float64)
     solver = _sharded_dp_solver(combine, shards, kernel,
                                 mesh_spec=mesh_spec)
-    dp0, dps, args = solver(jnp.asarray(C), jnp.asarray(ns_arr))
+    dp0, dps, args = solver(np.asarray(C, dtype=dtype), ns_arr)
     dp0, dps, args = np.asarray(dp0), np.asarray(dps), np.asarray(args)
     if pad:
         dp0, dps, args = dp0[:Sn], dps[:Sn], args[:Sn]

@@ -427,6 +427,23 @@ def _dp_numpy(C: np.ndarray, combine: str, ns: np.ndarray | None = None):
 _DP_JAX_TRACE_COUNT = 0
 
 
+def _first_argmin(cand, ndp, axis: int):
+    """Index (int32) of the FIRST minimum of ``cand`` along ``axis``,
+    given its minimum ``ndp`` — the NumPy oracle's tie-break, spelled
+    out. ``jnp.argmin`` does not promise it on every backend: on a TPU,
+    Mosaic's argmin in the Pallas kernel and XLA's in the ``lax.scan``
+    kernel broke exact-cost ties differently over identical tables. The
+    index is carried as a float (exact below 2**24) so Mosaic reduces it
+    like the costs; +inf candidates tie only in all-+inf columns, which
+    every caller masks to -1."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    idx = lax.broadcasted_iota(jnp.int32, cand.shape, axis).astype(cand.dtype)
+    hit = cand == jnp.expand_dims(ndp, axis)
+    return jnp.min(jnp.where(hit, idx, jnp.inf), axis=axis).astype(jnp.int32)
+
+
 @functools.lru_cache(maxsize=None)
 def _dp_jax_kernel(combine: str):
     """The raw (unjitted) vmapped DP kernel for one combine mode.
@@ -461,7 +478,8 @@ def _dp_jax_kernel(combine: str):
             else:
                 cand = jnp.maximum(dp[: L - 1, None], Ck[1:L, :])
             ndp = jnp.min(cand, axis=0)
-            arg = jnp.where(jnp.isfinite(ndp), jnp.argmin(cand, axis=0) + 1, -1)
+            arg = jnp.where(jnp.isfinite(ndp),
+                            _first_argmin(cand, ndp, 0) + 1, -1)
             # frozen-row subsetting: a scenario whose fleet completed at
             # n_s < k carries its stale table forward (exactly what the
             # NumPy path's active-subset indexing does); its parents

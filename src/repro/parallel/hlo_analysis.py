@@ -8,8 +8,8 @@ computations (scan counters compare an induction variable against a
 constant), and propagates multipliers through the call graph so every
 collective is weighted by how many times it actually executes.
 
-Used by the roofline benchmark for the collective term; the same weighted
-walk also yields loop-aware totals for any op predicate.
+The same weighted walk also yields loop-aware totals for any op
+predicate.
 """
 
 from __future__ import annotations

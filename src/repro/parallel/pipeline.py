@@ -28,7 +28,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.planner import SplitPlan
@@ -78,7 +78,6 @@ def pipelined_forward(
         # runs per-stage under shard_map: leading stage axis is local (=1)
         stage_p = jax.tree.map(lambda t: t[0], stage_p)
         mask = mask[0]
-        mb = mb[0]  # (M, mbatch, ...)
         sidx = jax.lax.axis_index(axis)
 
         def apply_stage(x):
@@ -117,20 +116,20 @@ def pipelined_forward(
             tick, (buf, outputs), jnp.arange(n_ticks, dtype=jnp.int32))
         # outputs live on the last stage; broadcast via psum of masked value
         outputs = jnp.where(sidx == S - 1, outputs, jnp.zeros_like(outputs))
-        outputs = jax.lax.psum(outputs, axis)
-        return outputs[None]
+        return jax.lax.psum(outputs, axis)
 
+    # the resident buffer starts replicated and turns stage-varying after
+    # the first ppermute, so the scan carry has no single replication type
+    # (check_vma off); after the psum every stage holds the same outputs,
+    # so the result is returned replicated (out_specs=P())
     fn = shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
-        out_specs=P(axis),
-        check_rep=False,
+        out_specs=P(),
+        check_vma=False,
     )
-    # microbatches replicated to every stage; take stage 0's view back
-    out = fn(stage_params, layer_mask,
-             jnp.broadcast_to(microbatches[None], (S, *microbatches.shape)))
-    return out[0]
+    return fn(stage_params, layer_mask, microbatches)
 
 
 def run_pipeline(plan: SplitPlan, block_apply, stacked_params, n_layers: int,

@@ -13,6 +13,7 @@ from repro.core.adaptive import AdaptiveSplitManager, fleet_managers
 from repro.core.async_replan import (
     ManualExecutor,
     SurfaceRebuilder,
+    cpu_process_pool,
     recentered_axes,
 )
 from repro.core.profiles import ESP_NOW, PROTOCOLS, paper_cost_model
@@ -621,11 +622,7 @@ class TestExecutorContract:
         ProcessPoolExecutor raised out of poll() and left _inflight
         wedged. The submit failure must surface like any failed build
         (stashed, re-raised once) and leave the rebuilder serviceable."""
-        import multiprocessing as mp
-        from concurrent.futures import ProcessPoolExecutor
-
-        pool = ProcessPoolExecutor(max_workers=1,
-                                   mp_context=mp.get_context("spawn"))
+        pool = cpu_process_pool()
         pool.shutdown(wait=True)  # dead before the rebuilder ever submits
         rb = self._rb(pool)
         pt = ESP_NOW.packet_time_s()
@@ -672,11 +669,8 @@ class TestExecutorContract:
         the same generation/swap semantics and the adopted surface is
         node-identical to the synchronous build."""
         import time as _time
-        import multiprocessing as mp
-        from concurrent.futures import ProcessPoolExecutor
 
-        pool = ProcessPoolExecutor(max_workers=1,
-                                   mp_context=mp.get_context("spawn"))
+        pool = cpu_process_pool()
         rb = self._rb(pool)
         try:
             pt = ESP_NOW.packet_time_s()
@@ -692,4 +686,29 @@ class TestExecutorContract:
             _assert_node_identical(got, rb.build_sync(rb.last_request)[2])
         finally:
             rb.shutdown()
+            pool.shutdown(wait=True)
+
+    def test_process_pool_refuses_device_backend(self):
+        """A chip belongs to one process: a pool rebuilder must not ship
+        device-backend builds to workers that would contend for it."""
+        pool = cpu_process_pool()
+        try:
+            with pytest.raises(ValueError, match="backend='numpy' only"):
+                SurfaceRebuilder(paper_cost_model("mobilenet_v2", "esp_now"),
+                                 dict(PROTOCOLS), solver="batched_dp",
+                                 backend="pallas", executor=pool, **GRID)
+        finally:
+            pool.shutdown(wait=True)
+
+    def test_cpu_pool_workers_pin_jax_to_cpu(self, monkeypatch):
+        """The pool's initializer pins each worker's JAX to the CPU even
+        when the parent's environment names no platform."""
+        import os
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        pool = cpu_process_pool()
+        try:
+            assert pool.submit(os.getenv, "JAX_PLATFORMS").result(
+                timeout=120) == "cpu"
+        finally:
             pool.shutdown(wait=True)

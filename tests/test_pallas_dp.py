@@ -95,6 +95,21 @@ class TestDenseNodeIdentity:
             assert np.array_equal(a.cost_s, b.cost_s), (shape, combine, kw)
             assert np.array_equal(a.feasible, b.feasible), (shape, combine, kw)
 
+    @pytest.mark.parametrize("combine", ["sum", "max"])
+    def test_exact_ties_break_to_first_minimum(self, combine):
+        """Small-integer costs are exact in float32 and tie everywhere:
+        every device backend must break them to the oracle's FIRST
+        minimum (on a TPU, Mosaic's and XLA's argmin once disagreed)."""
+        rng = np.random.RandomState(5)
+        C = rng.randint(1, 4, size=(9, 4, 12, 12)).astype(np.float64)
+        il = np.tril_indices(12, -1)
+        C[:, :, il[0], il[1]] = INF
+        ref = SW.batched_optimal_dp(C, combine=combine)
+        for backend in ("jax", "pallas"):
+            got = SW.batched_optimal_dp(C, combine=combine, backend=backend)
+            assert np.array_equal(ref.splits, got.splits), backend
+            assert np.array_equal(ref.cost_s, got.cost_s), backend
+
     def test_all_k_bitwise_vs_jax(self):
         C = make_C(6, 4, 12, seed=7)
         ref = SW.batched_optimal_dp(C, return_all_k=True, backend="jax")

@@ -26,16 +26,15 @@ Three families pin the PR-10 contract:
 """
 
 import math
-import multiprocessing as mp
 import pickle
 import time
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 import pytest
 
 from repro.core import planner as PL
 from repro.core import sweep as SW
+from repro.core.async_replan import cpu_process_pool
 from repro.core.latency import COST_CHANNELS
 from repro.core.profiles import (
     ESP_NOW,
@@ -333,8 +332,7 @@ class TestManagersRouteThroughSpec:
 
 
 def _spawn_pool(workers=1):
-    return ProcessPoolExecutor(max_workers=workers,
-                               mp_context=mp.get_context("spawn"))
+    return cpu_process_pool(workers)
 
 
 class TestProcessBoundary:
