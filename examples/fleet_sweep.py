@@ -23,7 +23,6 @@ Run: PYTHONPATH=src python examples/fleet_sweep.py
 
 from __future__ import annotations
 
-import time
 from dataclasses import replace
 
 from repro.core.profiles import ESP32, PROTOCOLS, mobilenet_cost_profile
@@ -50,10 +49,9 @@ def main():
                                    compute_scale=2.0),),
         },
     )
-    t0 = time.perf_counter()
     result = sweep(grid, solver="batched_dp")
-    wall = time.perf_counter() - t0
-    print(f"swept {result.n_scenarios} scenarios in {wall * 1e3:.1f} ms "
+    print(f"swept {result.n_scenarios} scenarios in "
+          f"{result.wall_time_s * 1e3:.1f} ms "
           f"({result.scenarios_per_sec:,.0f} scenarios/s)")
 
     print("\n-- best protocol per fleet size (nominal link, homogeneous) --")

@@ -76,6 +76,7 @@ from __future__ import annotations
 import multiprocessing
 import os
 import threading
+import time
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Mapping, Protocol, Sequence
@@ -88,6 +89,7 @@ from repro.core.surface import (
     DegradationSurface,
     _resolve_axes,
 )
+from repro.core.spans import span
 
 __all__ = [
     "ManualExecutor",
@@ -353,6 +355,9 @@ class SurfaceRebuilder:
         # entry by per-protocol max (the envelope-dominant direction),
         # bounding the rebuilt grid size.
         self._queued: dict[int, list[dict[str, tuple[float, float]]]] = {}
+        # when the first request of the next build was queued (the
+        # repro.rebuild span's queued_ms runs from here to the build)
+        self._queued_since = 0.0
         self._inflight: RebuildRequest | None = None
         self._results: dict[int, tuple[int, DegradationSurface]] = {}
         self._adopted_gen: dict[int, int] = {}
@@ -393,6 +398,8 @@ class SurfaceRebuilder:
                         last[name] = (max(pt0, pt), max(lp0, lp))
                 self.requests_coalesced += 1
                 return "coalesced"
+            if not self._queued:
+                self._queued_since = time.perf_counter()
             self._queued[n_devices] = [dict(states)]
             self._maybe_actionable = True
             return "queued"
@@ -519,6 +526,7 @@ class SurfaceRebuilder:
             pt_scale=self.pt_scale, loss_p=self.loss_p,
             pt_pad=self.pt_pad, loss_pad=self.loss_pad)
         self._queued.clear()
+        queued_since = self._queued_since
         self.generation += 1
         req = RebuildRequest(
             generation=self.generation, sizes=sizes,
@@ -544,7 +552,8 @@ class SurfaceRebuilder:
                 fut.add_done_callback(
                     lambda f, req=req: self._finish_future(req, f))
             else:
-                self._executor.submit(lambda: self._run_build(req))
+                self._executor.submit(
+                    lambda: self._run_build(req, queued_since))
         except BaseException as e:  # noqa: BLE001 - dead/broken pool
             # submit on a terminated pool raises in the SERVING thread;
             # surface it like any failed build instead of crashing the
@@ -552,15 +561,17 @@ class SurfaceRebuilder:
             # surface)
             self._fail_locked(e)
 
-    def _run_build(self, req: RebuildRequest) -> None:
-        try:
-            surfaces = self.build_sync(req)
-        except BaseException as e:  # noqa: BLE001 - surfaced via poll()
+    def _run_build(self, req: RebuildRequest, queued_since: float) -> None:
+        queued_ms = (time.perf_counter() - queued_since) * 1e3
+        with span("rebuild", queued_ms=queued_ms):
+            try:
+                surfaces = self.build_sync(req)
+            except BaseException as e:  # noqa: BLE001 - surfaced via poll()
+                with self._lock:
+                    self._fail_locked(e)
+                return
             with self._lock:
-                self._fail_locked(e)
-            return
-        with self._lock:
-            self._publish_locked(req, surfaces)
+                self._publish_locked(req, surfaces)
 
     def _finish_future(self, req: RebuildRequest, fut) -> None:
         """Done-callback for process-pool builds: publish the shipped

@@ -76,6 +76,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.core import sweep as SW
+from repro.core.spans import span
 
 __all__ = [
     "LANE",
@@ -233,6 +234,7 @@ def _raw_pallas_fn(mode: str, combine: str, block_s: int, interpret: bool):
                     jax.ShapeDtypeStruct((Sp, N - 1, Lp), jnp.int32),
                 ],
                 interpret=interpret,
+                name="solve_dense",
             )(Cp, nsp)
 
         return fn
@@ -263,6 +265,7 @@ def _raw_pallas_fn(mode: str, combine: str, block_s: int, interpret: bool):
                     jax.ShapeDtypeStruct((Sp, N - 1, Lp), jnp.int32),
                 ],
                 interpret=interpret,
+                name="solve_fused",
             )(localp, txp, nsp)
 
         return fn
@@ -273,7 +276,9 @@ def _raw_pallas_fn(mode: str, combine: str, block_s: int, interpret: bool):
 @functools.lru_cache(maxsize=None)
 def _pallas_dp_solver(mode: str, combine: str, block_s: int,
                       interpret: bool):
-    """Jitted entry to :func:`_raw_pallas_fn`, cached per configuration.
+    """Jitted entry to :func:`_raw_pallas_fn`, cached per configuration,
+    named ``solve_<mode>`` (XLA prints ``jit_solve_fused`` /
+    ``jit_solve_dense``).
 
     ``jax.jit``'s executable cache keys on operand shapes, so two
     same-shape calls compile exactly once (regression-tested via
@@ -288,6 +293,7 @@ def _pallas_dp_solver(mode: str, combine: str, block_s: int,
         _PALLAS_TRACE_COUNT += 1  # Python side effect: runs at trace only
         return fn(*operands)
 
+    solve.__name__ = solve.__qualname__ = f"solve_{mode}"
     return jax.jit(solve)
 
 
@@ -358,39 +364,49 @@ def pallas_dp_tables(
         return _trivial_tables(C[:, 0, 0, :].astype(dtype), Sn, N, L, dtype)
     bs, itp = _resolve_opts(block_s, interpret)
     Lp, Sp = _pad_lanes(L), _pad_rows(Sn, bs)
-    Cp = _pad_cost_tensor(C, Sp, Lp, dtype)
-    nsp = _pad_ns_column(ns_arr, Sn, Sp)
     import jax.numpy as jnp
 
-    solver = _pallas_dp_solver("dense", combine, bs, itp)
-    dp0, dps, args = solver(jnp.asarray(Cp), jnp.asarray(nsp))
-    dp0 = np.asarray(dp0)[:Sn, :L]
-    dps = np.asarray(dps)[:Sn, :, :L]
-    args = np.asarray(args)[:Sn, :, :L]
+    def operands():
+        return (jnp.asarray(_pad_cost_tensor(C, Sp, Lp, dtype)),
+                jnp.asarray(_pad_ns_column(ns_arr, Sn, Sp)))
+
+    dp0, dps, args = SW._dp_launch(
+        "solve_dense", _pallas_dp_solver("dense", combine, bs, itp),
+        operands, rows=Sn, rows_padded=Sp, lanes=L, lanes_padded=Lp)
     return SW._dp_tables_to_numpy(dp0, dps, args, Sn, N, L)
 
 
-def _fused_tables_arrays(local, tx, ns_arr, combine, bs, itp, dtype):
-    """Unpadded (dp0, dps, args) from the fused kernel; N >= 2, S >= 1."""
+def _empty_tables(Sn: int, N: int, L: int, dtype):
+    """Host (dp0, dps, args) for the fused launches to scatter into."""
+    return (np.empty((Sn, L), dtype=dtype),
+            np.empty((Sn, N - 1, L), dtype=dtype),
+            np.empty((Sn, N - 1, L), dtype=np.int32))
+
+
+def _fused_launch(local, tx, ns_arr, sel, combine, bs, itp, dtype, tables):
+    """One fused kernel launch over the scenarios ``sel`` of ``tx`` /
+    ``ns_arr`` on the shared stack ``local``; its unpadded tables land
+    in rows ``sel`` of ``tables``. N >= 2, at least one scenario."""
     N, L, _ = local.shape
-    Sn = tx.shape[0]
-    Lp, Sp = _pad_lanes(L), _pad_rows(Sn, bs)
-    localp = np.full((N, Lp, Lp), INF, dtype=np.float64)
-    localp[:, :L, :L] = local
-    txp = np.zeros((Sp, Lp), dtype=np.float64)
-    txp[:Sn, :L] = tx
-    if Sp > Sn:
-        txp[Sn:] = txp[Sn - 1]
-    nsp = _pad_ns_column(ns_arr, Sn, Sp)
     import jax.numpy as jnp
 
-    solver = _pallas_dp_solver("fused", combine, bs, itp)
-    dp0, dps, args = solver(jnp.asarray(localp, dtype=dtype),
-                            jnp.asarray(txp, dtype=dtype),
-                            jnp.asarray(nsp))
-    return (np.asarray(dp0)[:Sn, :L],
-            np.asarray(dps)[:Sn, :, :L],
-            np.asarray(args)[:Sn, :, :L])
+    ns_sel = ns_arr[sel]
+    Sn = len(ns_sel)
+    Lp, Sp = _pad_lanes(L), _pad_rows(Sn, bs)
+
+    def operands():
+        localp = np.full((N, Lp, Lp), INF, dtype=np.float64)
+        localp[:, :L, :L] = local
+        txp = np.zeros((Sp, Lp), dtype=np.float64)
+        txp[:Sn, :L] = tx[sel]
+        if Sp > Sn:
+            txp[Sn:] = txp[Sn - 1]
+        return (jnp.asarray(localp, dtype=dtype), jnp.asarray(txp, dtype=dtype),
+                jnp.asarray(_pad_ns_column(ns_sel, Sn, Sp)))
+
+    SW._dp_launch("solve_fused", _pallas_dp_solver("fused", combine, bs, itp),
+                  operands, rows=Sn, rows_padded=Sp, lanes=L, lanes_padded=Lp,
+                  into=(tables, sel))
 
 
 def _fused_dp0_host(local, tx, dtype):
@@ -436,9 +452,10 @@ def pallas_fused_dp_tables(
         return _trivial_tables(_fused_dp0_host(local, tx, dtype),
                                Sn, N, L, dtype)
     bs, itp = _resolve_opts(block_s, interpret)
-    dp0, dps, args = _fused_tables_arrays(local, tx, ns_arr, combine,
-                                          bs, itp, dtype)
-    return SW._dp_tables_to_numpy(dp0, dps, args, Sn, N, L)
+    tables = _empty_tables(Sn, N, L, dtype)
+    _fused_launch(local, tx, ns_arr, slice(None), combine, bs, itp, dtype,
+                  tables)
+    return SW._dp_tables_to_numpy(*tables, Sn, N, L)
 
 
 def pallas_optimal_dp(
@@ -459,12 +476,13 @@ def pallas_optimal_dp(
     ``return_all_k``, the shared timing scope) and is node-identical to
     ``backend="jax"``: bit-equal tables, bit-equal parents."""
     Sn, N, L, ns = SW._validate_dp_inputs(C, return_all_k, n_devices)
-    t0 = time.perf_counter()
-    dp_per_k, parents = pallas_dp_tables(C, combine, ns=ns,
-                                         block_s=block_s,
-                                         interpret=interpret)
-    return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
-                                      "pallas", ns, return_all_k, t0)
+    with span("dp"):
+        t0 = time.perf_counter()
+        dp_per_k, parents = pallas_dp_tables(C, combine, ns=ns,
+                                             block_s=block_s,
+                                             interpret=interpret)
+        return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
+                                          "pallas", ns, return_all_k, t0)
 
 
 def pallas_fused_optimal_dp(
@@ -525,11 +543,13 @@ def pallas_fused_optimal_dp(
             raise ValueError("return_all_k and per-scenario n_devices "
                              "are mutually exclusive")
         ns = None if n_devices is None else SW._normalize_ns(n_devices, Sn, N)
-        t0 = time.perf_counter()
-        dp_per_k, parents = pallas_fused_dp_tables(
-            bank, tx, combine, ns=ns, block_s=block_s, interpret=interpret)
-        return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
-                                          "pallas", ns, return_all_k, t0)
+        with span("dp"):
+            t0 = time.perf_counter()
+            dp_per_k, parents = pallas_fused_dp_tables(
+                bank, tx, combine, ns=ns, block_s=block_s,
+                interpret=interpret)
+            return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
+                                              "pallas", ns, return_all_k, t0)
 
     bank_idx = np.asarray(bank_idx, dtype=np.int64)
     if bank_idx.ndim != 2 or bank_idx.shape[0] != Sn:
@@ -543,32 +563,28 @@ def pallas_fused_optimal_dp(
     import jax
 
     dtype = jax.dtypes.canonicalize_dtype(np.float64)
-    t0 = time.perf_counter()
-    ns_arr = np.full(Sn, N, dtype=np.int64) if ns is None else ns
-    if Sn == 0 or N == 1:
-        dp0 = np.empty((Sn, L), dtype=dtype)
-        for s in range(Sn):
-            dp0[s] = _fused_dp0_host(bank[bank_idx[s]], tx[s:s + 1],
-                                     dtype)[0]
-        dp_per_k, parents = _trivial_tables(dp0, Sn, N, L, dtype)
+    with span("dp"):
+        t0 = time.perf_counter()
+        ns_arr = np.full(Sn, N, dtype=np.int64) if ns is None else ns
+        if Sn == 0 or N == 1:
+            dp0 = np.empty((Sn, L), dtype=dtype)
+            for s in range(Sn):
+                dp0[s] = _fused_dp0_host(bank[bank_idx[s]], tx[s:s + 1],
+                                         dtype)[0]
+            dp_per_k, parents = _trivial_tables(dp0, Sn, N, L, dtype)
+            return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
+                                              "pallas", ns, return_all_k, t0)
+        bs, itp = _resolve_opts(block_s, interpret)
+        # canonicalize dead device slots (>= a scenario's own fleet size)
+        # to row 0 so stacks differing only there share one kernel launch
+        # — the solvers never read those slots (frozen-row contract)
+        canon = bank_idx.copy()
+        canon[np.arange(N)[None, :] >= ns_arr[:, None]] = 0
+        stacks, inv = np.unique(canon, axis=0, return_inverse=True)
+        tables = _empty_tables(Sn, N, L, dtype)
+        for u in range(stacks.shape[0]):
+            _fused_launch(bank[stacks[u]], tx, ns_arr, np.flatnonzero(inv == u),
+                          combine, bs, itp, dtype, tables)
+        dp_per_k, parents = SW._dp_tables_to_numpy(*tables, Sn, N, L)
         return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
                                           "pallas", ns, return_all_k, t0)
-    bs, itp = _resolve_opts(block_s, interpret)
-    # canonicalize dead device slots (>= a scenario's own fleet size) to
-    # row 0 so stacks differing only there share one kernel launch —
-    # the solvers never read those slots (frozen-row contract)
-    canon = bank_idx.copy()
-    canon[np.arange(N)[None, :] >= ns_arr[:, None]] = 0
-    stacks, inv = np.unique(canon, axis=0, return_inverse=True)
-    dp0_all = np.empty((Sn, L), dtype=dtype)
-    dps_all = np.empty((Sn, N - 1, L), dtype=dtype)
-    args_all = np.empty((Sn, N - 1, L), dtype=np.int32)
-    for u in range(stacks.shape[0]):
-        sel = np.flatnonzero(inv == u)
-        d0, dv, ag = _fused_tables_arrays(
-            bank[stacks[u]], tx[sel], ns_arr[sel], combine, bs, itp, dtype)
-        dp0_all[sel], dps_all[sel], args_all[sel] = d0, dv, ag
-    dp_per_k, parents = SW._dp_tables_to_numpy(dp0_all, dps_all, args_all,
-                                               Sn, N, L)
-    return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
-                                      "pallas", ns, return_all_k, t0)

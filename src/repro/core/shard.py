@@ -56,6 +56,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.core import sweep as SW
+from repro.core.spans import span
 from repro.core.spec import MeshSpec
 
 __all__ = [
@@ -212,7 +213,11 @@ def _sharded_dp_solver(combine: str, n_shards: int, kernel: str = "jax",
     # host operands go straight to their shards: without in_shardings a
     # host array lands whole on the first device before being split
     split = NamedSharding(mesh, P(axis))
-    return jax.jit(sharded, in_shardings=(split, split))
+
+    def solve_sharded(C, ns):  # XLA prints it as jit_solve_sharded
+        return sharded(C, ns)
+
+    return jax.jit(solve_sharded, in_shardings=(split, split))
 
 
 def sharded_dp_tables(
@@ -251,40 +256,42 @@ def sharded_dp_tables(
     shards = _resolve_shards(mesh_spec, n_shards)
     ns_arr = np.full(Sn, N, dtype=np.int64) if ns is None \
         else np.asarray(ns, dtype=np.int64)
+    import jax
+
+    dtype = jax.dtypes.canonicalize_dtype(np.float64)
     if kernel == "pallas":
         from repro.core import pallas_dp as PD
 
         if N == 1 or Sn == 0:  # kernel-free cases: no scenario tiles
             return PD.pallas_dp_tables(C, combine, ns=ns_arr,
                                        block_s=block_s, interpret=interpret)
-        import jax
-
         bs, itp = PD._resolve_opts(block_s, interpret)
-        dtype = jax.dtypes.canonicalize_dtype(np.float64)
         Lp = PD._pad_lanes(L)
         Sp = Sn + _pad_to_multiple(Sn, shards * bs)  # whole blocks/shard
-        Cp = PD._pad_cost_tensor(C, Sp, Lp, dtype)
-        nsp = PD._pad_ns_column(ns_arr, Sn, Sp)
         solver = _sharded_dp_solver(combine, shards, "pallas", bs, itp,
                                     mesh_spec=mesh_spec)
-        dp0, dps, args = solver(Cp, nsp)
-        dp0 = np.asarray(dp0)[:Sn, :L]
-        dps = np.asarray(dps)[:Sn, :, :L]
-        args = np.asarray(args)[:Sn, :, :L]
-        return SW._dp_tables_to_numpy(dp0, dps, args, Sn, N, L)
-    pad = _pad_to_multiple(Sn, shards)
-    if pad:
-        C = np.concatenate([C, np.repeat(C[-1:], pad, axis=0)], axis=0)
-        ns_arr = np.concatenate([ns_arr, np.repeat(ns_arr[-1:], pad)])
-    import jax
 
-    dtype = jax.dtypes.canonicalize_dtype(np.float64)
-    solver = _sharded_dp_solver(combine, shards, kernel,
-                                mesh_spec=mesh_spec)
-    dp0, dps, args = solver(np.asarray(C, dtype=dtype), ns_arr)
-    dp0, dps, args = np.asarray(dp0), np.asarray(dps), np.asarray(args)
-    if pad:
-        dp0, dps, args = dp0[:Sn], dps[:Sn], args[:Sn]
+        def operands():
+            return (PD._pad_cost_tensor(C, Sp, Lp, dtype),
+                    PD._pad_ns_column(ns_arr, Sn, Sp))
+    else:
+        Lp = L
+        Sp = Sn + _pad_to_multiple(Sn, shards)
+        solver = _sharded_dp_solver(combine, shards, kernel,
+                                    mesh_spec=mesh_spec)
+        ns_dtype = jax.dtypes.canonicalize_dtype(np.int64)
+
+        def operands():
+            Cp, nsp = C, ns_arr
+            if Sp > Sn:  # replicas of the last scenario
+                Cp = np.concatenate([C, np.repeat(C[-1:], Sp - Sn, axis=0)])
+                nsp = np.concatenate([ns_arr, np.repeat(ns_arr[-1:], Sp - Sn)])
+            # cast on the host: the bytes counted are the bytes that cross
+            return np.asarray(Cp, dtype=dtype), nsp.astype(ns_dtype)
+
+    dp0, dps, args = SW._dp_launch(
+        "solve_sharded", solver, operands, rows=Sn, rows_padded=Sp,
+        lanes=L, lanes_padded=Lp)
     return SW._dp_tables_to_numpy(dp0, dps, args, Sn, N, L)
 
 
@@ -311,9 +318,11 @@ def sharded_optimal_dp(
     cost-close to the NumPy float64 oracle (bit-identical under an x64
     JAX config)."""
     Sn, N, L, ns = SW._validate_dp_inputs(C, return_all_k, n_devices)
-    t0 = time.perf_counter()
-    dp_per_k, parents = sharded_dp_tables(C, combine, ns=ns,
-                                          n_shards=n_shards, kernel=kernel,
-                                          mesh_spec=mesh_spec)
-    return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
-                                      "sharded", ns, return_all_k, t0)
+    with span("dp"):
+        t0 = time.perf_counter()
+        dp_per_k, parents = sharded_dp_tables(C, combine, ns=ns,
+                                              n_shards=n_shards,
+                                              kernel=kernel,
+                                              mesh_spec=mesh_spec)
+        return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
+                                          "sharded", ns, return_all_k, t0)

@@ -1,0 +1,52 @@
+"""Named spans of the planner's hot path, on the profiler's clock.
+
+``span(name, **counts)`` is ``jax.profiler.TraceAnnotation("repro." +
+name, **counts)``. While a JAX profiler trace runs, the span lands in
+the same trace as the device's operations, on the same clock, and its
+keyword arguments (and any later ``set_metadata``) arrive as the event's
+stats. With no trace running it records nothing and costs about a
+microsecond. The profiler is the recorder: there is no switch.
+
+Spans mark phases, never single scenarios: a ``sweep`` call opens about
+twenty, whatever its grid size.
+
+:data:`SPANS` lists every name the program emits, with what it covers
+and the counts it carries.
+"""
+
+from __future__ import annotations
+
+SPANS = {
+    "repro.sweep": "one sweep() call, the SweepResult.wall_time_s interval; "
+                   "counts scenarios",
+    "repro.sweep.enumerate": "grid.scenarios() and grouping by model",
+    "repro.sweep.build": "one group's grid build, the interval "
+                         "SweepResult.build_time_s adds up",
+    "repro.sweep.bank": "profile-bank matrices and the bank_idx loop",
+    "repro.sweep.tx": "the group's transmission vectors (_group_tx_vectors)",
+    "repro.sweep.gather": "dense paths: the C gather and the TX add",
+    "repro.sweep.energy": "budgeted groups: the energy tensor and the "
+                          "budget mask",
+    "repro.sweep.rows": "one group's SweepRow loop; once more, the final "
+                        "ordering",
+    "repro.dp": "a DP solve, solver entry to the wall_time_s stamp",
+    "repro.dp.launch": "one kernel launch; counts kernel (its jitted name), "
+                       "rows, rows_padded, lanes, lanes_padded, h2d_bytes, "
+                       "d2h_bytes (padding included)",
+    "repro.dp.prepare": "padding, the host-to-device copy and the dispatch",
+    "repro.dp.fetch": "the wait for the kernel, the device-to-host copy and "
+                      "the scatter of the unpadded tables",
+    "repro.dp.reconstruct": "tables to float64, result selection and the "
+                            "split walk (_reconstruct_splits)",
+    "repro.rebuild": "one in-process surface rebuild on the executor; "
+                     "counts queued_ms (first queued request to build start)",
+}
+
+
+def span(name: str, **counts):
+    """The ``repro.<name>`` span: use as ``with span("dp.launch",
+    rows=n):``; ``set_metadata(**counts)`` on it adds counts known only
+    later."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation("repro." + name, **counts)

@@ -81,6 +81,7 @@ from repro.core.latency import (
     bottleneck_variant,
 )
 from repro.core import solvers as S
+from repro.core.spans import span
 
 INF = float("inf")
 
@@ -494,12 +495,12 @@ def _dp_jax_kernel(combine: str):
         _, (dps, args) = lax.scan(step, dp0, (Cs[1:N], ks))
         return dp0, dps, args
 
-    def solve(C, ns):
+    def solve_scan(C, ns):  # XLA prints the jitted program as jit_solve_scan
         global _DP_JAX_TRACE_COUNT
         _DP_JAX_TRACE_COUNT += 1  # Python side effect: runs at trace only
         return vmap(one)(C, ns)
 
-    return solve
+    return solve_scan
 
 
 @functools.lru_cache(maxsize=None)
@@ -531,19 +532,51 @@ def _dp_jax(C: np.ndarray, combine: str, ns: np.ndarray | None = None):
 
     Sn, N, L, _ = C.shape
     ns_arr = np.full(Sn, N, dtype=np.int64) if ns is None else ns
-    solver = _dp_jax_solver(combine)
-    dp0, dps, args = solver(jnp.asarray(C), jnp.asarray(ns_arr))
+    dp0, dps, args = _dp_launch(
+        "solve_scan", _dp_jax_solver(combine),
+        lambda: (jnp.asarray(C), jnp.asarray(ns_arr)),
+        rows=Sn, rows_padded=Sn, lanes=L, lanes_padded=L)
     return _dp_tables_to_numpy(dp0, dps, args, Sn, N, L)
 
 
+def _dp_launch(kernel: str, solver, operands: Callable[[], tuple], *,
+               rows: int, rows_padded: int, lanes: int, lanes_padded: int,
+               into=None):
+    """One DP kernel launch, shared by every device backend, in a
+    ``repro.dp.launch`` span named for its ``kernel``.
+
+    ``operands()`` pads the inputs and places them; with the dispatch of
+    ``solver`` that is ``repro.dp.prepare``. ``repro.dp.fetch`` waits
+    for the outputs, copies them to the host and slices the padding off
+    (the first ``rows`` rows, the first ``lanes`` lanes); with
+    ``into=(tables, sel)`` it also scatters them into rows ``sel`` of
+    the host ``tables``. Returns the unpadded host (dp0, dps, args)."""
+    with span("dp.launch", kernel=kernel, rows=rows, rows_padded=rows_padded,
+              lanes=lanes, lanes_padded=lanes_padded) as sp:
+        with span("dp.prepare"):
+            ins = operands()
+            out = solver(*ins)
+        sp.set_metadata(h2d_bytes=sum(int(x.nbytes) for x in ins),
+                        d2h_bytes=sum(int(o.nbytes) for o in out))
+        with span("dp.fetch"):
+            host = tuple(np.asarray(o)[:rows, ..., :lanes] for o in out)
+            if into is not None:
+                tables, sel = into
+                for t, h in zip(tables, host):
+                    t[sel] = h
+    return host
+
+
 def _dp_tables_to_numpy(dp0, dps, args, Sn: int, N: int, L: int):
-    """Device DP outputs -> the (dp_per_k, parents) host format every
+    """Host DP outputs -> the (dp_per_k, parents) format every
     result-selection path consumes (shared with :mod:`repro.core.shard`)."""
-    dp0 = np.asarray(dp0, dtype=np.float64)
-    dp_per_k = [dp0] + [np.asarray(dps[:, i], dtype=np.float64) for i in range(N - 1)]
-    parents = np.asarray(args, dtype=np.int64)  # (S, N-1, L) from the vmapped scan
-    if N == 1:
-        parents = np.full((Sn, 0, L), -1, dtype=np.int64)
+    with span("dp.reconstruct"):
+        dp0 = np.asarray(dp0, dtype=np.float64)
+        dp_per_k = [dp0] + [np.asarray(dps[:, i], dtype=np.float64)
+                            for i in range(N - 1)]
+        parents = np.asarray(args, dtype=np.int64)  # (S, N-1, L)
+        if N == 1:
+            parents = np.full((Sn, 0, L), -1, dtype=np.int64)
     return dp_per_k, parents
 
 
@@ -647,22 +680,23 @@ def batched_optimal_dp(
     per-scenario ``n_devices`` with the same frozen-row semantics and
     supports ``return_all_k``."""
     Sn, N, L, ns = _validate_dp_inputs(C, return_all_k, n_devices)
-    t0 = time.perf_counter()
     try:
         tables_fn = DP_BACKENDS[backend]
     except KeyError:
         raise ValueError(f"unknown backend {backend!r}; "
                          f"options: {sorted(DP_BACKENDS)}") from None
-    if mesh_spec is not None:
-        if backend != "sharded":
-            raise ValueError(
-                f"mesh_spec is a backend='sharded' knob; got "
-                f"backend={backend!r}")
-        dp_per_k, parents = tables_fn(C, combine, ns, mesh_spec=mesh_spec)
-    else:
-        dp_per_k, parents = tables_fn(C, combine, ns)
-    return _results_from_dp_tables(dp_per_k, parents, L, N, Sn, backend,
-                                   ns, return_all_k, t0)
+    if mesh_spec is not None and backend != "sharded":
+        raise ValueError(
+            f"mesh_spec is a backend='sharded' knob; got "
+            f"backend={backend!r}")
+    with span("dp"):
+        t0 = time.perf_counter()
+        if mesh_spec is not None:
+            dp_per_k, parents = tables_fn(C, combine, ns, mesh_spec=mesh_spec)
+        else:
+            dp_per_k, parents = tables_fn(C, combine, ns)
+        return _results_from_dp_tables(dp_per_k, parents, L, N, Sn, backend,
+                                       ns, return_all_k, t0)
 
 
 def _results_from_dp_tables(
@@ -690,20 +724,21 @@ def _results_from_dp_tables(
             splits=splits, cost_s=cost, feasible=feas, wall_time_s=0.0,
         )
 
-    if return_all_k:
-        out = {n: result_for(n) for n in range(1, N + 1)}
-        wall = time.perf_counter() - t0
-        return {n: replace(r, wall_time_s=wall) for n, r in out.items()}
-    if ns is not None:
-        dpk = np.stack([d[:, L - 1] for d in dp_per_k])  # (N, S)
-        cost = dpk[ns - 1, np.arange(Sn)].astype(np.float64, copy=True)
-        splits, feas = _reconstruct_splits(parents, cost, L, N, ns=ns)
-        return BatchedSolverResult(
-            solver="batched_dp", backend=backend, n_devices=N,
-            splits=splits, cost_s=cost, feasible=feas,
-            wall_time_s=time.perf_counter() - t0, n_devices_s=ns,
-        )
-    return replace(result_for(N), wall_time_s=time.perf_counter() - t0)
+    with span("dp.reconstruct"):
+        if return_all_k:
+            out = {n: result_for(n) for n in range(1, N + 1)}
+            wall = time.perf_counter() - t0
+            return {n: replace(r, wall_time_s=wall) for n, r in out.items()}
+        if ns is not None:
+            dpk = np.stack([d[:, L - 1] for d in dp_per_k])  # (N, S)
+            cost = dpk[ns - 1, np.arange(Sn)].astype(np.float64, copy=True)
+            splits, feas = _reconstruct_splits(parents, cost, L, N, ns=ns)
+            return BatchedSolverResult(
+                solver="batched_dp", backend=backend, n_devices=N,
+                splits=splits, cost_s=cost, feasible=feas,
+                wall_time_s=time.perf_counter() - t0, n_devices_s=ns,
+            )
+        return replace(result_for(N), wall_time_s=time.perf_counter() - t0)
 
 
 # ---------------------------------------------------------------------------
@@ -1795,7 +1830,6 @@ class SweepRow:
     total_latency_s: float  # Eq. 8 incl. link setup + feedback overheads
     device_s: float  # summed device-local segment latency
     transmission_s: float  # summed cut transmission + encoder latency
-    solver_wall_s: float  # this scenario's share of the batched solve
     accuracy_proxy: float = 1.0  # the scenario variant's accuracy proxy
 
     def to_dict(self) -> dict:
@@ -1805,7 +1839,6 @@ class SweepRow:
             objective_cost_s=self.objective_cost_s,
             total_latency_s=self.total_latency_s,
             device_s=self.device_s, transmission_s=self.transmission_s,
-            solver_wall_s=self.solver_wall_s,
             accuracy_proxy=self.accuracy_proxy,
         )
         return d
@@ -1820,6 +1853,7 @@ class SweepResult:
     backend: str
     solve_time_s: float  # batched solver passes only
     build_time_s: float  # cost-tensor assembly
+    wall_time_s: float  # the whole call, enumeration and row assembly included
 
     @property
     def n_scenarios(self) -> int:
@@ -1827,8 +1861,8 @@ class SweepResult:
 
     @property
     def scenarios_per_sec(self) -> float:
-        total = self.solve_time_s + self.build_time_s
-        return self.n_scenarios / total if total > 0 else INF
+        wall = self.wall_time_s
+        return self.n_scenarios / wall if wall > 0 else INF
 
     def best(self, **filters) -> SweepRow:
         """Lowest-latency feasible row among those matching scenario-field
@@ -1853,6 +1887,7 @@ class SweepResult:
             "solver": self.solver, "backend": self.backend,
             "n_scenarios": self.n_scenarios,
             "solve_time_s": self.solve_time_s, "build_time_s": self.build_time_s,
+            "wall_time_s": self.wall_time_s,
             "scenarios_per_sec": self.scenarios_per_sec,
             "rows": [{k: _clean(v) for k, v in d.items()} for d in self.to_dicts()],
         }
@@ -1862,8 +1897,7 @@ class SweepResult:
         cols = ["model", "protocol", "n_devices", "loss_p", "rate_scale",
                 "mix", "contention", "energy_budget", "compression",
                 "feasible", "splits", "objective_cost_s", "total_latency_s",
-                "accuracy_proxy", "device_s", "transmission_s",
-                "solver_wall_s"]
+                "accuracy_proxy", "device_s", "transmission_s"]
         lines = [",".join(cols)]
         for d in self.to_dicts():
             d["splits"] = "|".join(str(x) for x in d["splits"])
@@ -2097,137 +2131,163 @@ def sweep(
         raise ValueError(f"{solver} supports backend='numpy' only "
                          f"(got {backend!r})")
     combine = "max" if grid.objective == "bottleneck" else "sum"
-    order = grid.scenarios()
-    # group scenarios (preserving order within groups) by model; fleet
-    # size and device mix are per-scenario data, not group keys
-    groups: dict[str, list[int]] = {}
-    for idx, sc in enumerate(order):
-        groups.setdefault(sc.model, []).append(idx)
+    with span("sweep", scenarios=grid.size):
+        t0 = time.perf_counter()
+        rows, build_time, solve_time = _sweep_groups(
+            grid, solver, backend, combine, beam_width)
+        wall = time.perf_counter() - t0
+    return SweepResult(rows=rows, solver=solver, backend=backend,
+                       solve_time_s=solve_time, build_time_s=build_time,
+                       wall_time_s=wall)
+
+
+def _sweep_groups(grid: ScenarioGrid, solver: str, backend: str,
+                  combine: str, beam_width: int):
+    """:func:`sweep`'s work: (rows in grid order, build seconds, solve
+    seconds)."""
+    with span("sweep.enumerate"):
+        order = grid.scenarios()
+        # group scenarios (preserving order within groups) by model;
+        # fleet size and device mix are per-scenario data, not group keys
+        groups: dict[str, tuple[list[int], list[Scenario]]] = {}
+        for idx, sc in enumerate(order):
+            idxs, group = groups.setdefault(sc.model, ([], []))
+            idxs.append(idx)
+            group.append(sc)
 
     rows: dict[int, SweepRow] = {}
     build_time = 0.0
     solve_time = 0.0
-    for model_name, idxs in groups.items():
+    for model_name, (idxs, group) in groups.items():
         profile = grid.models[model_name]
         L = profile.num_layers
-        group = [order[i] for i in idxs]
         t0 = time.perf_counter()
-        n_max = max(sc.n_devices for sc in group)
-        ns = np.array([sc.n_devices for sc in group], dtype=np.int64)
-        base_model = SplitCostModel(
-            profile=profile, devices=grid.devices_for(group[0]),
-            link=next(iter(grid.links.values())), objective=grid.objective,
-        )
-        # profile bank: one local matrix per (device profile, is-first);
-        # every scenario's tensor is ONE vectorized gather over the
-        # stacked bank, so heterogeneous mixes cost O(bank) matrix
-        # builds + a single fancy-index, not O(S) Python copies
-        bank_rows: dict[tuple[DeviceProfile, bool], int] = {}
-        bank_mats: list[np.ndarray] = []
+        with span("sweep.build"):
+            n_max = max(sc.n_devices for sc in group)
+            ns = np.array([sc.n_devices for sc in group], dtype=np.int64)
+            with span("sweep.bank"):
+                base_model = SplitCostModel(
+                    profile=profile, devices=grid.devices_for(group[0]),
+                    link=next(iter(grid.links.values())),
+                    objective=grid.objective,
+                )
+                # profile bank: one local matrix per (device profile,
+                # is-first); every scenario's tensor is ONE vectorized
+                # gather over the stacked bank, so heterogeneous mixes
+                # cost O(bank) matrix builds + a single fancy-index, not
+                # O(S) Python copies
+                bank_rows: dict[tuple[DeviceProfile, bool], int] = {}
+                bank_mats: list[np.ndarray] = []
 
-        def bank_index(dev: DeviceProfile, is_first: bool) -> int:
-            key = (dev, is_first)
-            row = bank_rows.get(key)
-            if row is None:
-                row = len(bank_mats)
-                bank_rows[key] = row
-                bank_mats.append(base_model._local_cost_matrix(dev, is_first))
-            return row
+                def bank_index(dev: DeviceProfile, is_first: bool) -> int:
+                    key = (dev, is_first)
+                    row = bank_rows.get(key)
+                    if row is None:
+                        row = len(bank_mats)
+                        bank_rows[key] = row
+                        bank_mats.append(
+                            base_model._local_cost_matrix(dev, is_first))
+                    return row
 
-        bank_idx = np.zeros((len(group), n_max), dtype=np.int64)
-        for gi, sc in enumerate(group):
-            devs = grid.devices_for(sc)
-            for k in range(1, sc.n_devices + 1):
-                dev = devs[0] if len(devs) == 1 else devs[k - 1]
-                bank_idx[gi, k - 1] = bank_index(dev, k == 1)
-            # device slots beyond a scenario's own fleet size keep row 0
-            # filler: the solvers never read them (the per-scenario
-            # n_devices vector masks every k > n_s)
-        # TX = airtime + encoder time per scenario (AIR/ENC split them
-        # out for energy pricing; None when the group is all-identity)
-        TX, AIR, ENC = _group_tx_vectors(grid, profile, group)  # (S_g, L)
-        bank = np.stack(bank_mats)
-        budgets = np.array(
-            [INF if sc.energy_budget is None else float(sc.energy_budget)
-             for sc in group])
-        budgeted = bool(np.isfinite(budgets).any())
-        if backend == "pallas" and not budgeted:
+                bank_idx = np.zeros((len(group), n_max), dtype=np.int64)
+                for gi, sc in enumerate(group):
+                    devs = grid.devices_for(sc)
+                    for k in range(1, sc.n_devices + 1):
+                        dev = devs[0] if len(devs) == 1 else devs[k - 1]
+                        bank_idx[gi, k - 1] = bank_index(dev, k == 1)
+                    # device slots beyond a scenario's own fleet size keep
+                    # row 0 filler: the solvers never read them (the
+                    # per-scenario n_devices vector masks every k > n_s)
+                bank = np.stack(bank_mats)
+            # TX = airtime + encoder time per scenario (AIR/ENC split them
+            # out for energy pricing; None when the group is all-identity)
+            with span("sweep.tx"):
+                TX, AIR, ENC = _group_tx_vectors(grid, profile, group)  # (S_g, L)
+            budgets = np.array(
+                [INF if sc.energy_budget is None else float(sc.energy_budget)
+                 for sc in group])
+            budgeted = bool(np.isfinite(budgets).any())
             # fused path: the kernel builds C[s,k] = bank[idx] + TX[s]
             # inside each reduction step — the (S_g, N, L, L) tensor is
             # never materialized, on host or device
-            build_time += time.perf_counter() - t0
+            fused = backend == "pallas" and not budgeted
+            if not fused:
+                with span("sweep.gather"):
+                    if bool((bank_idx == bank_idx[0]).all()):
+                        # homogeneous group (every scenario the same device
+                        # stack): broadcast one local tensor, don't gather
+                        # S copies
+                        local = bank[bank_idx[0]]  # (N_max, L, L)
+                        C = local[None, :, :, :] + TX[:, None, None, :]
+                    else:
+                        C = bank[bank_idx]  # (S_g, N_max, L, L) gather
+                        C += TX[:, None, None, :]
+                if budgeted:
+                    # energy budgets mask the latency tensor before
+                    # dispatch, so every backend — pallas included, in
+                    # dense mode on the materialized masked tensor —
+                    # solves unchanged
+                    with span("sweep.energy"):
+                        E = _group_energy_tensor(
+                            grid, group, bank, bank_rows, bank_idx,
+                            AIR if AIR is not None else TX, ENC)
+                        C = apply_energy_budget(C, E, budgets)
+        build_time += time.perf_counter() - t0
+
+        if fused:
             from repro.core import pallas_dp as _pallas  # lazy, like shard
 
             res = _pallas.pallas_fused_optimal_dp(
                 bank, bank_idx, TX, combine=combine, n_devices=ns)
         else:
-            if bool((bank_idx == bank_idx[0]).all()):
-                # homogeneous group (every scenario the same device
-                # stack): broadcast one local tensor, don't gather S copies
-                local = bank[bank_idx[0]]  # (N_max, L, L)
-                C = local[None, :, :, :] + TX[:, None, None, :]
-            else:
-                C = bank[bank_idx]  # (S_g, N_max, L, L) gather
-                C += TX[:, None, None, :]
-            if budgeted:
-                # energy budgets mask the latency tensor before dispatch,
-                # so every backend — pallas included, in dense mode on
-                # the materialized masked tensor — solves unchanged
-                E = _group_energy_tensor(grid, group, bank, bank_rows,
-                                         bank_idx,
-                                         AIR if AIR is not None else TX, ENC)
-                C = apply_energy_budget(C, E, budgets)
-            build_time += time.perf_counter() - t0
-
             kwargs = {"beam_width": beam_width} if solver == "batched_beam" else {}
             res = solve_batched(C, solver=solver, combine=combine,
                                 backend=backend, n_devices=ns, **kwargs)
         solve_time += res.wall_time_s
-        per_scn_wall = res.wall_time_s / max(1, len(group))
 
         # cost breakdowns from the same tensors (no scalar re-walks)
-        for gi, (idx, sc) in enumerate(zip(idxs, group)):
-            n = sc.n_devices
-            splits_t = res.splits_tuple(gi)
-            feasible = bool(res.feasible[gi])
-            link = grid.effective_link(sc)
-            if splits_t or n == 1:
-                bounds = [0, *splits_t, L] if feasible else None
-            else:
-                bounds = None
-            if feasible and bounds is not None:
-                tx_total = float(np.sum(TX[gi, [b - 1 for b in bounds[1:-1]]])) \
-                    if len(bounds) > 2 else 0.0
-                obj = float(res.cost_s[gi])
-                # device/transmission totals summed over all segments; for
-                # the "sum" objective device_s + transmission_s == objective.
-                # Priced from the bank + TX decomposition (bitwise equal to
-                # the C entries, which are built as exactly this f64 sum) so
-                # the pallas path needs no materialized tensor either.
-                seg_sum = float(sum(
-                    bank[bank_idx[gi, i], bounds[i], bounds[i + 1] - 1]
-                    + TX[gi, bounds[i + 1] - 1]
-                    for i in range(len(bounds) - 1)))
-                device_s = seg_sum - tx_total
-                total = obj + link.t_setup_s + link.t_feedback_s
-                rows[idx] = SweepRow(
-                    scenario=sc, splits=splits_t, feasible=True,
-                    objective_cost_s=obj, total_latency_s=total,
-                    device_s=device_s, transmission_s=tx_total,
-                    solver_wall_s=per_scn_wall,
-                    accuracy_proxy=grid.accuracy_for(sc),
-                )
-            else:
-                rows[idx] = SweepRow(
-                    scenario=sc, splits=splits_t, feasible=False,
-                    objective_cost_s=INF, total_latency_s=INF,
-                    device_s=INF, transmission_s=INF,
-                    solver_wall_s=per_scn_wall,
-                    accuracy_proxy=grid.accuracy_for(sc),
-                )
-    ordered = tuple(rows[i] for i in range(len(order)))
-    return SweepResult(rows=ordered, solver=solver, backend=backend,
-                       solve_time_s=solve_time, build_time_s=build_time)
+        with span("sweep.rows"):
+            for gi, (idx, sc) in enumerate(zip(idxs, group)):
+                n = sc.n_devices
+                splits_t = res.splits_tuple(gi)
+                feasible = bool(res.feasible[gi])
+                link = grid.effective_link(sc)
+                if splits_t or n == 1:
+                    bounds = [0, *splits_t, L] if feasible else None
+                else:
+                    bounds = None
+                if feasible and bounds is not None:
+                    tx_total = float(np.sum(TX[gi, [b - 1 for b in bounds[1:-1]]])) \
+                        if len(bounds) > 2 else 0.0
+                    obj = float(res.cost_s[gi])
+                    # device/transmission totals summed over all segments;
+                    # for the "sum" objective device_s + transmission_s ==
+                    # objective. Priced from the bank + TX decomposition
+                    # (bitwise equal to the C entries, which are built as
+                    # exactly this f64 sum) so the pallas path needs no
+                    # materialized tensor either.
+                    seg_sum = float(sum(
+                        bank[bank_idx[gi, i], bounds[i], bounds[i + 1] - 1]
+                        + TX[gi, bounds[i + 1] - 1]
+                        for i in range(len(bounds) - 1)))
+                    device_s = seg_sum - tx_total
+                    total = obj + link.t_setup_s + link.t_feedback_s
+                    rows[idx] = SweepRow(
+                        scenario=sc, splits=splits_t, feasible=True,
+                        objective_cost_s=obj, total_latency_s=total,
+                        device_s=device_s, transmission_s=tx_total,
+                        accuracy_proxy=grid.accuracy_for(sc),
+                    )
+                else:
+                    rows[idx] = SweepRow(
+                        scenario=sc, splits=splits_t, feasible=False,
+                        objective_cost_s=INF, total_latency_s=INF,
+                        device_s=INF, transmission_s=INF,
+                        accuracy_proxy=grid.accuracy_for(sc),
+                    )
+    with span("sweep.rows"):
+        ordered = tuple(rows[i] for i in range(len(order)))
+    return ordered, build_time, solve_time
 
 
 def sweep_scalar(grid: ScenarioGrid, solver: str = "optimal_dp") -> SweepResult:
@@ -2238,6 +2298,7 @@ def sweep_scalar(grid: ScenarioGrid, solver: str = "optimal_dp") -> SweepResult:
     (each scenario's :class:`SplitCostModel` carries its own fleet), so
     this loop is also the heterogeneous-fleet oracle."""
     combine = "max" if grid.objective == "bottleneck" else "sum"
+    t_call = time.perf_counter()
     rows = []
     solve_time = 0.0
     build_time = 0.0
@@ -2271,18 +2332,17 @@ def sweep_scalar(grid: ScenarioGrid, solver: str = "optimal_dp") -> SweepResult:
                 objective_cost_s=obj,
                 total_latency_s=obj + link.t_setup_s + link.t_feedback_s,
                 device_s=device_s, transmission_s=tx_total,
-                solver_wall_s=res.wall_time_s,
                 accuracy_proxy=grid.accuracy_for(sc),
             ))
         else:
             rows.append(SweepRow(
                 scenario=sc, splits=res.splits, feasible=False,
                 objective_cost_s=INF, total_latency_s=INF, device_s=INF,
-                transmission_s=INF, solver_wall_s=res.wall_time_s,
-                accuracy_proxy=grid.accuracy_for(sc),
+                transmission_s=INF, accuracy_proxy=grid.accuracy_for(sc),
             ))
     return SweepResult(rows=tuple(rows), solver=solver, backend="scalar",
-                       solve_time_s=solve_time, build_time_s=build_time)
+                       solve_time_s=solve_time, build_time_s=build_time,
+                       wall_time_s=time.perf_counter() - t_call)
 
 
 def parity_report(batched: SweepResult, scalar: SweepResult) -> list[str]:

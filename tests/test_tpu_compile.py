@@ -99,3 +99,39 @@ def test_dp_program_compiles_for_v5e(one_chip, name):
     if is_pallas:
         # a Mosaic kernel, not an XLA fallback
         assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def _named_solver(mode: str):
+    """The jitted DP entry a backend dispatches, with operand shapes of
+    two scenario blocks at ResNet50's depth."""
+    import jax.numpy as jnp
+
+    from repro.core import pallas_dp as PD
+    from repro.core import sweep as SW
+
+    f32, i32 = jnp.float32, jnp.int32
+    S = 2 * PD.DEFAULT_BLOCK_S
+    if mode == "scan":
+        return SW._dp_jax_solver("sum"), (((S, 5, 52, 52), f32), ((S,), i32))
+    fn = PD._pallas_dp_solver(mode, "sum", PD.DEFAULT_BLOCK_S, False)
+    if mode == "fused":
+        return fn, (((5, PD.LANE, PD.LANE), f32), ((S, PD.LANE), f32),
+                    ((S, 1), i32))
+    return fn, (((S, 5, PD.LANE, PD.LANE), f32), ((S, 1), i32))
+
+
+@pytest.mark.parametrize("mode", ["fused", "dense", "scan"])
+def test_dp_program_keeps_its_name_on_v5e(one_chip, mode):
+    """The device trace prints the compiled module as ``jit_solve_<mode>``
+    and a Pallas kernel's op as ``%solve_<mode>.n``; the benchmark's
+    kernel readers match ``solve`` in them."""
+    import re
+
+    import jax
+
+    fn, shapes = _named_solver(mode)
+    text = fn.lower(*(jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                      for s, dt in shapes)).compile().as_text()
+    assert re.search(rf"^HloModule jit_solve_{mode}\b", text, re.M), mode
+    if mode != "scan":
+        assert re.search(rf"%solve_{mode}(\.\d+)? = .*custom-call\(", text), mode
