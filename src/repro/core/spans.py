@@ -27,8 +27,9 @@ SPANS = {
     "repro.sweep.gather": "dense paths: the C gather and the TX add",
     "repro.sweep.energy": "budgeted groups: the energy tensor and the "
                           "budget mask",
-    "repro.sweep.rows": "one group's SweepRow loop; once more, the final "
-                        "ordering",
+    "repro.sweep.rows": "one group's rows: whole-array pricing, SweepRow "
+                        "construction and placement by index "
+                        "(_group_rows); once more, the final tuple",
     "repro.dp": "a DP solve, solver entry to the wall_time_s stamp",
     "repro.dp.launch": "one kernel launch; counts kernel (its jitted name), "
                        "rows, rows_padded, lanes, lanes_padded, h2d_bytes, "

@@ -1981,7 +1981,8 @@ class ParetoFrontier:
 
 def _group_tx_vectors(
     grid: ScenarioGrid, profile: ModelCostProfile, group: list[Scenario]
-) -> tuple[np.ndarray, np.ndarray | None, np.ndarray | None]:
+) -> tuple[np.ndarray, np.ndarray | None, np.ndarray | None,
+           np.ndarray, np.ndarray]:
     """(S_g, L) transmission-cost vectors, amortizing packet counts per
     (MTU, compression factor) against per-scenario packet times.
     Airtime is priced on each scenario's contention-scaled effective
@@ -1990,13 +1991,15 @@ def _group_tx_vectors(
     bytes and adds the encoder-time vector, matching
     :meth:`SplitCostModel.transmission_cost_vector` term-for-term.
 
-    Returns ``(TX, AIR, ENC)``. ``TX`` is what the latency tensor adds
-    (airtime + encoder time). ``AIR``/``ENC`` split that into pure
-    airtime and encoder time for the energy tensor, which prices them
-    at different powers (radio vs device); both are ``None`` when no
-    scenario in the group carries a variant — the historical
+    Returns ``(TX, AIR, ENC, setup_s, feedback_s)``. ``TX`` is what the
+    latency tensor adds (airtime + encoder time). ``AIR``/``ENC`` split
+    that into pure airtime and encoder time for the energy tensor, which
+    prices them at different powers (radio vs device); both are ``None``
+    when no scenario in the group carries a variant — the historical
     single-array path, bit-exact because identity rows never see a
-    ``+ 0.0``."""
+    ``+ 0.0``. ``setup_s``/``feedback_s`` are the ``(S_g,)`` link setup
+    and feedback times of the same effective links, which the rows'
+    total latency adds."""
     L = profile.num_layers
     act_raw = profile.segment_arrays.boundary_act_bytes[1:].astype(np.float64)
     variants = [grid.variant_for(sc) for sc in group]
@@ -2006,8 +2009,12 @@ def _group_tx_vectors(
     out = np.empty((len(group), L))
     air_out = np.empty((len(group), L)) if any_variant else None
     enc_out = np.zeros((len(group), L)) if any_variant else None
+    setup_s = np.empty(len(group))
+    feedback_s = np.empty(len(group))
     for i, (sc, v) in enumerate(zip(group, variants)):
         link = grid.effective_link(sc)
+        setup_s[i] = link.t_setup_s
+        feedback_s[i] = link.t_feedback_s
         factor = 1.0 if v is None else v.compression_factor
         K = packets_by_key.get((link.mtu_bytes, factor))
         if K is None:
@@ -2033,7 +2040,7 @@ def _group_tx_vectors(
             enc_out[i] = enc
             tx = tx + enc
         out[i] = tx
-    return out, air_out, enc_out
+    return out, air_out, enc_out, setup_s, feedback_s
 
 
 def _group_energy_tensor(
@@ -2155,12 +2162,11 @@ def _sweep_groups(grid: ScenarioGrid, solver: str, backend: str,
             idxs.append(idx)
             group.append(sc)
 
-    rows: dict[int, SweepRow] = {}
+    rows: list[SweepRow | None] = [None] * len(order)
     build_time = 0.0
     solve_time = 0.0
     for model_name, (idxs, group) in groups.items():
         profile = grid.models[model_name]
-        L = profile.num_layers
         t0 = time.perf_counter()
         with span("sweep.build"):
             n_max = max(sc.n_devices for sc in group)
@@ -2202,7 +2208,8 @@ def _sweep_groups(grid: ScenarioGrid, solver: str, backend: str,
             # TX = airtime + encoder time per scenario (AIR/ENC split them
             # out for energy pricing; None when the group is all-identity)
             with span("sweep.tx"):
-                TX, AIR, ENC = _group_tx_vectors(grid, profile, group)  # (S_g, L)
+                TX, AIR, ENC, setup_s, feedback_s = _group_tx_vectors(
+                    grid, profile, group)  # (S_g, L) x 3, (S_g,) x 2
             budgets = np.array(
                 [INF if sc.energy_budget is None else float(sc.energy_budget)
                  for sc in group])
@@ -2245,49 +2252,78 @@ def _sweep_groups(grid: ScenarioGrid, solver: str, backend: str,
                                 backend=backend, n_devices=ns, **kwargs)
         solve_time += res.wall_time_s
 
-        # cost breakdowns from the same tensors (no scalar re-walks)
         with span("sweep.rows"):
-            for gi, (idx, sc) in enumerate(zip(idxs, group)):
-                n = sc.n_devices
-                splits_t = res.splits_tuple(gi)
-                feasible = bool(res.feasible[gi])
-                link = grid.effective_link(sc)
-                if splits_t or n == 1:
-                    bounds = [0, *splits_t, L] if feasible else None
-                else:
-                    bounds = None
-                if feasible and bounds is not None:
-                    tx_total = float(np.sum(TX[gi, [b - 1 for b in bounds[1:-1]]])) \
-                        if len(bounds) > 2 else 0.0
-                    obj = float(res.cost_s[gi])
-                    # device/transmission totals summed over all segments;
-                    # for the "sum" objective device_s + transmission_s ==
-                    # objective. Priced from the bank + TX decomposition
-                    # (bitwise equal to the C entries, which are built as
-                    # exactly this f64 sum) so the pallas path needs no
-                    # materialized tensor either.
-                    seg_sum = float(sum(
-                        bank[bank_idx[gi, i], bounds[i], bounds[i + 1] - 1]
-                        + TX[gi, bounds[i + 1] - 1]
-                        for i in range(len(bounds) - 1)))
-                    device_s = seg_sum - tx_total
-                    total = obj + link.t_setup_s + link.t_feedback_s
-                    rows[idx] = SweepRow(
-                        scenario=sc, splits=splits_t, feasible=True,
-                        objective_cost_s=obj, total_latency_s=total,
-                        device_s=device_s, transmission_s=tx_total,
-                        accuracy_proxy=grid.accuracy_for(sc),
-                    )
-                else:
-                    rows[idx] = SweepRow(
-                        scenario=sc, splits=splits_t, feasible=False,
-                        objective_cost_s=INF, total_latency_s=INF,
-                        device_s=INF, transmission_s=INF,
-                        accuracy_proxy=grid.accuracy_for(sc),
-                    )
+            group_rows = _group_rows(grid, group, res, bank, bank_idx, TX,
+                                     setup_s, feedback_s)
+            for idx, row in zip(idxs, group_rows):
+                rows[idx] = row
     with span("sweep.rows"):
-        ordered = tuple(rows[i] for i in range(len(order)))
+        ordered = tuple(rows)
     return ordered, build_time, solve_time
+
+
+def _group_rows(
+    grid: ScenarioGrid,
+    group: list[Scenario],
+    res: BatchedSolverResult,
+    bank: np.ndarray,
+    bank_idx: np.ndarray,
+    TX: np.ndarray,
+    setup_s: np.ndarray,
+    feedback_s: np.ndarray,
+) -> list[SweepRow]:
+    """One sweep group's rows (:class:`SweepRow`), in group order.
+
+    Every row is priced in whole-array passes that loop over the device
+    slots, never over scenarios; only the final ``SweepRow``
+    construction walks the rows. A row is priced iff the solver marks
+    it feasible and none of its live splits (the first ``n - 1``) is
+    negative; any other row is infeasible with +inf costs. ``splits``
+    are those of :meth:`BatchedSolverResult.splits_tuple`.
+
+    ``device_s`` and ``transmission_s`` come from the bank + TX
+    decomposition (bitwise equal to the ``C`` entries, which are built
+    as exactly this f64 sum), so the fused path needs no materialised
+    tensor. Both sums run left to right over the live cuts and segments
+    one column at a time, adding ``0.0`` for dead ones, so each row is
+    bit-identical to summing its own segments in order; for the "sum"
+    objective ``device_s + transmission_s`` is the objective."""
+    S_g, L = TX.shape
+    s = np.arange(S_g)
+    splits = res.splits  # (S_g, N - 1)
+    W = splits.shape[1]
+    width = _normalize_ns(res.n_devices_s, S_g, res.n_devices) - 1
+    col = np.arange(W)[None, :]
+    no_config = ((col < width[:, None]) & (splits < 0)).any(axis=1)
+    priced = res.feasible & ~no_config
+    cuts = np.where(priced, width, 0)  # live cuts of a priced row
+    live_cut = col < cuts[:, None]
+    bounds = np.concatenate(
+        [np.zeros((S_g, 1), np.int64), np.where(live_cut, splits, L),
+         np.full((S_g, 1), L, np.int64)], axis=1)  # (S_g, W + 2)
+    start = np.minimum(bounds[:, :-1], L - 1)  # dead segments start at L
+    end = bounds[:, 1:] - 1
+    tx_total = np.zeros(S_g)
+    for j in range(W):
+        tx_total = tx_total + np.where(live_cut[:, j], TX[s, end[:, j]], 0.0)
+    seg_sum = bank[bank_idx[:, 0], start[:, 0], end[:, 0]] + TX[s, end[:, 0]]
+    for i in range(1, W + 1):
+        seg = bank[bank_idx[:, i], start[:, i], end[:, i]] + TX[s, end[:, i]]
+        seg_sum = seg_sum + np.where(i <= cuts, seg, 0.0)
+    obj = np.where(priced, res.cost_s, INF)
+    total = np.where(priced, obj + setup_s + feedback_s, INF).tolist()
+    device_s = np.where(priced, seg_sum - tx_total, INF).tolist()
+    tx_total = np.where(priced, tx_total, INF).tolist()
+    keep = np.where(no_config, 0, width).tolist()
+    split_rows = [tuple(row[:w]) for row, w in zip(splits.tolist(), keep)]
+    by_factor = {sc.compression: sc for sc in group}
+    accuracy = {cf: grid.accuracy_for(sc) for cf, sc in by_factor.items()}
+    return [
+        SweepRow(sc, sp, f, o, t, d, x, accuracy[sc.compression])
+        for sc, sp, f, o, t, d, x in zip(group, split_rows, priced.tolist(),
+                                         obj.tolist(), total, device_s,
+                                         tx_total)
+    ]
 
 
 def sweep_scalar(grid: ScenarioGrid, solver: str = "optimal_dp") -> SweepResult:
