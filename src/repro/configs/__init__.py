@@ -26,6 +26,7 @@ _MODULES = {
     "granite-34b": "granite_34b",
     "qwen2-vl-72b": "qwen2_vl_72b",
     "xlstm-1.3b": "xlstm_1p3b",
+    "deepseek-v3": "deepseek_v3",
 }
 
 ARCH_IDS = list(_MODULES)

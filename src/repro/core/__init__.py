@@ -65,6 +65,7 @@ from repro.core.planner import (  # noqa: F401
     SegmentPlan,
     SplitPlan,
     compare_solvers,
+    pipeline_grid,
     plan_pipeline,
     plan_split,
     plan_split_batch,

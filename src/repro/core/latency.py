@@ -21,9 +21,10 @@ tier (ICI intra-pod / DCN inter-pod); see ``profiles.py``.
 from __future__ import annotations
 
 import math
+import operator
 from dataclasses import dataclass, field, replace
-from functools import cached_property
-from typing import Callable, Sequence
+from functools import cached_property, reduce
+from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
@@ -33,6 +34,14 @@ INF = float("inf")
 #: (``segment_cost_tensor(n, channels=...)`` and
 #: ``sweep.stack_cost_tensors(..., channels=...)``), in canonical order.
 COST_CHANNELS = ("latency", "energy")
+
+
+def lsum(values: Iterable[float]) -> float:
+    """Left-to-right sum from 0, one rounding per add, as ``np.cumsum``
+    and the batched engines accumulate. Python's ``sum`` compensates
+    float rounding since 3.12, so it can differ from them in the last
+    bit; every scalar-oracle sum uses this instead."""
+    return reduce(operator.add, values, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -327,17 +336,17 @@ class ModelCostProfile:
 
     def segment_infer_s(self, a: int, b: int) -> float:
         """Sum of per-layer inference times for layers [a, b] (1-indexed inclusive)."""
-        return sum(lc.t_infer_s for lc in self.layers[a - 1 : b])
+        return lsum(lc.t_infer_s for lc in self.layers[a - 1 : b])
 
     def segment_param_bytes(self, a: int, b: int) -> int:
-        return sum(lc.param_bytes for lc in self.layers[a - 1 : b])
+        return lsum(lc.param_bytes for lc in self.layers[a - 1 : b])
 
     def segment_work_bytes(self, a: int, b: int) -> int:
         seg = self.layers[a - 1 : b]
         return max((lc.work_bytes for lc in seg), default=0)
 
     def segment_flops(self, a: int, b: int) -> float:
-        return sum(lc.flops for lc in self.layers[a - 1 : b])
+        return lsum(lc.flops for lc in self.layers[a - 1 : b])
 
     def boundary_act_bytes(self, b: int) -> int:
         """Bytes crossing a cut after layer ``b`` (1-indexed); 0 at b=0/L."""
@@ -598,7 +607,7 @@ class SplitCostModel:
         if self.objective == "bottleneck":
             total = max(seg_costs)
         else:
-            total = sum(seg_costs)
+            total = lsum(seg_costs)
         if with_overheads:
             link = self.effective_link
             total += link.t_setup_s + link.t_feedback_s
@@ -764,7 +773,7 @@ class RTTBreakdown:
 
     @property
     def rtt_s(self) -> float:
-        return self.setup_s + sum(self.device_s) + sum(self.transmission_s) + self.feedback_s
+        return self.setup_s + lsum(self.device_s) + lsum(self.transmission_s) + self.feedback_s
 
 
 def rtt_breakdown(model: SplitCostModel, splits: Sequence[int]) -> RTTBreakdown:
@@ -803,7 +812,7 @@ def scale_profile(profile: ModelCostProfile, infer_total_s: float) -> ModelCostP
     """Rescale per-layer inference times so they sum to ``infer_total_s``
     (used to calibrate analytic FLOP-proportional tables to a measured
     end-to-end inference time, Table III)."""
-    cur = sum(lc.t_infer_s for lc in profile.layers)
+    cur = lsum(lc.t_infer_s for lc in profile.layers)
     if cur <= 0:
         raise ValueError("profile has no inference time to scale")
     f = infer_total_s / cur

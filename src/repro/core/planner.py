@@ -10,13 +10,17 @@ Two entry points:
   inter-stage activation traffic costed on an interconnect tier (ICI/DCN)
   via the *same* Eq. 7 packetized-link model. Objective defaults to
   ``bottleneck`` (steady-state pipeline throughput).
+
+* :func:`pipeline_grid` — the same question as a what-if grid (shapes x
+  chip-group sizes x stage counts x links x loss x rate) for the batched
+  engines: ``sweep(pipeline_grid(...), backend="pallas")``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import TYPE_CHECKING, Sequence
+from typing import TYPE_CHECKING, Mapping, Sequence
 
 import numpy as np
 
@@ -30,7 +34,8 @@ from repro.core.latency import (
     SplitCostModel,
     rtt_breakdown,
 )
-from repro.core.profiles import ICI, tpu_layer_time_s, tpu_stage_device
+from repro.core.profiles import ICI, TPU_LINKS, tpu_layer_time_s, tpu_stage_device
+from repro.core.spans import span
 
 if TYPE_CHECKING:  # avoid the core <-> models import cycle at runtime
     from repro.models.graph import LayerGraph
@@ -125,9 +130,7 @@ def plan_split(
     :data:`repro.core.solvers.SOLVERS` plus the vectorized engines
     (``"batched_dp"``, ``"batched_beam"``, ``"batched_greedy"``) which
     run on the dense cost tensor in one array pass instead of a Python
-    segment loop. ``batched_dp``/``batched_greedy`` are bit-identical
-    to their scalar oracles; ``batched_beam`` is bit-identical except
-    on exact floating-point cost ties (see its docstring).
+    segment loop. All three are bit-identical to their scalar oracles.
 
     ``energy_budget`` caps every device's segment energy in Joules:
     scalar solvers see over-budget segments as +inf via
@@ -400,22 +403,27 @@ def tpu_cost_profile(
 ) -> ModelCostProfile:
     """Analytic per-layer TPU times: max(compute, memory) roofline terms.
 
-    ``bytes_moved`` per layer approximates params read once plus
-    activations in+out (training adds backward traffic uniformly — a
-    constant factor that does not move split decisions)."""
+    ``bytes_moved`` per layer is the parameters a step reads
+    (``LayerNode.params_read``), activations in+out and the cache a step
+    reads (training adds backward traffic uniformly — a constant factor
+    that does not move split decisions). ``param_bytes`` is what the
+    layer holds, weights plus cache (the cache in the activation dtype),
+    so a segment's memory check sums both."""
     from repro.core.latency import LayerCost
 
     layers = []
     for n in graph.nodes:
         bytes_moved = (
-            n.param_count * param_dtype_bytes + n.work_elems * act_dtype_bytes
+            n.params_read * param_dtype_bytes + n.work_elems * act_dtype_bytes
+            + n.cache_read_elems * act_dtype_bytes
         )
         layers.append(
             LayerCost(
                 name=n.name,
                 t_infer_s=tpu_layer_time_s(n.flops, bytes_moved, chips_per_stage),
                 act_bytes=n.out_elems * act_dtype_bytes,
-                param_bytes=n.param_count * param_dtype_bytes,
+                param_bytes=(n.param_count * param_dtype_bytes
+                             + n.cache_elems * act_dtype_bytes),
                 work_bytes=n.work_elems * act_dtype_bytes,
                 flops=n.flops,
             )
@@ -436,16 +444,16 @@ def plan_pipeline(
     objective: str = "bottleneck",
     **solver_kwargs,
 ) -> SplitPlan:
-    if solver == "beam":
-        # memory-cliff instances (segments that barely fit a stage) need a
-        # wider beam than the paper's IoT cases; still < 100 ms to plan
-        solver_kwargs.setdefault("beam_width", 16)
     """Beam-search pipeline-stage boundaries for a transformer block chain.
 
     This is the paper's split-point optimization re-targeted at TPU
     pipeline parallelism: stages are chip groups, the link is ICI (intra
     pod) or DCN (across pods), and the objective is the steady-state
     bottleneck stage time."""
+    if solver == "beam":
+        # memory-cliff instances (segments that barely fit a stage) need a
+        # wider beam than the paper's IoT cases; still < 100 ms to plan
+        solver_kwargs.setdefault("beam_width", 16)
     prof = tpu_cost_profile(
         graph, act_dtype_bytes=act_dtype_bytes, chips_per_stage=chips_per_stage
     )
@@ -456,6 +464,54 @@ def plan_pipeline(
         objective=objective,
     )
     return plan_split(model, n_stages, solver=solver, **solver_kwargs)
+
+
+def pipeline_grid(
+    cfg,
+    shapes: Sequence,
+    chips_per_stage: Sequence[int],
+    stages: Sequence[int],
+    links: Mapping[str, LinkProfile] = TPU_LINKS,
+    loss_p: Sequence[float | None] = (None,),
+    rate_scale: Sequence[float] = (1.0,),
+) -> "SW.ScenarioGrid":
+    """A pipeline-stage what-if grid for the batched engines.
+
+    ``cfg`` is a :class:`~repro.models.config.ModelConfig`; ``shapes``
+    are :class:`~repro.configs.shapes.ShapeSpec`-like steps (``name``,
+    ``kind``, ``seq_len``, ``global_batch``): a ``"decode"`` step is one
+    token per sequence against ``seq_len`` cached positions, any other a
+    forward pass over ``seq_len`` tokens. Each shape becomes one
+    ``models`` entry (its :func:`tpu_cost_profile` at one chip, keyed by
+    ``shape.name``), each chip-group size ``c`` one device mix ``"x<c>"``
+    of :func:`~repro.core.profiles.tpu_stage_device` ``(c)`` (``c``
+    chips divide the layer times; ``0.9`` of their HBM bounds a stage),
+    ``stages`` the fleet sizes, ``links`` the interconnects. The
+    objective is the bottleneck stage time:
+    ``sweep(grid, backend="pallas")`` plans it on the device and
+    :func:`~repro.core.sweep.sweep_scalar` is its oracle."""
+    from repro.models.graph import arch_layer_graph, experts_touched
+
+    mixes = {f"x{c}": (tpu_stage_device(c),) for c in chips_per_stage}
+    models = {}
+    with span("plan.pipeline", shapes=len(shapes), mixes=len(mixes)) as sp:
+        for shape in shapes:
+            decode = shape.kind == "decode"
+            batch = shape.global_batch
+            seq = 1 if decode else shape.seq_len
+            touched = (experts_touched(cfg.n_experts, cfg.top_k, batch * seq)
+                       if cfg.is_moe else 0.0)
+            with span("plan.pipeline.profile", experts_touched=touched) as pp:
+                graph = arch_layer_graph(
+                    cfg, batch, seq, kv_len=shape.seq_len if decode else None)
+                models[shape.name] = tpu_cost_profile(graph)
+                pp.set_metadata(layers=graph.num_layers)
+        sp.set_metadata(layers=max((m.num_layers for m in models.values()),
+                                   default=0))
+        return SW.ScenarioGrid(
+            models=models, links=dict(links), n_devices=tuple(stages),
+            loss_p=tuple(loss_p), rate_scale=tuple(rate_scale),
+            objective="bottleneck", device_mixes=mixes)
 
 
 def uniform_split(L: int, n_devices: int) -> tuple[int, ...]:

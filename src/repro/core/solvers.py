@@ -43,6 +43,8 @@ import time
 from dataclasses import dataclass, replace
 from typing import Callable, Sequence
 
+from repro.core.latency import lsum
+
 INF = float("inf")
 
 CostFn = Callable[[int, int, int], float]
@@ -335,7 +337,9 @@ def beam_search(
                 cur = best_by_pos.get(cand[1])
                 if cur is None or cand[0] < cur[0]:
                     best_by_pos[cand[1]] = cand
-            new = list(best_by_pos.values())
+            # landing-position order, so the stable ranking sort below
+            # breaks exact key ties by position, as the batched beam does
+            new = [best_by_pos[p] for p in sorted(best_by_pos)]
         if k < N:
             new.sort(key=lambda t: comb(t[0], completion_bound(t[1], k)))
             beam = new[:beam_width]
@@ -437,7 +441,7 @@ def first_fit_search(
             # infeasible-on-one-device models: budget = mean feasible-segment cost
             finite = [memo(a, a, 2) for a in range(1, L + 1)]
             finite = [c for c in finite if c < INF]
-            whole = (sum(finite) if finite else 1.0) * 1.5
+            whole = (lsum(finite) if finite else 1.0) * 1.5
         thresholds = [whole / N] * N
     elif isinstance(thresholds, (int, float)):
         thresholds = [float(thresholds)] * N

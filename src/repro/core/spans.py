@@ -5,7 +5,9 @@ name, **counts)``. While a JAX profiler trace runs, the span lands in
 the same trace as the device's operations, on the same clock, and its
 keyword arguments (and any later ``set_metadata``) arrive as the event's
 stats. With no trace running it records nothing and costs about a
-microsecond. The profiler is the recorder: there is no switch.
+microsecond. The profiler is the recorder: there is no switch. Before
+anything has imported JAX no trace can run, so a span then is a no-op
+and a NumPy-only caller never pays JAX's half-second import for it.
 
 Spans mark phases, never single scenarios: a ``sweep`` call opens about
 twenty, whatever its grid size.
@@ -15,6 +17,8 @@ and the counts it carries.
 """
 
 from __future__ import annotations
+
+import sys
 
 SPANS = {
     "repro.sweep": "one sweep() call, the SweepResult.wall_time_s interval; "
@@ -39,6 +43,12 @@ SPANS = {
                       "the scatter of the unpadded tables",
     "repro.dp.reconstruct": "tables to float64, result selection and the "
                             "split walk (_reconstruct_splits)",
+    "repro.plan.pipeline": "planner.pipeline_grid: the shapes' cost "
+                           "profiles and the grid; counts shapes, layers, "
+                           "mixes",
+    "repro.plan.pipeline.profile": "one shape's layer graph and TPU cost "
+                                   "profile; counts layers, "
+                                   "experts_touched (a MoE layer, a step)",
     "repro.rebuild": "one in-process surface rebuild on the executor; "
                      "counts queued_ms (first queued request to build start)",
 }
@@ -48,6 +58,23 @@ def span(name: str, **counts):
     """The ``repro.<name>`` span: use as ``with span("dp.launch",
     rows=n):``; ``set_metadata(**counts)`` on it adds counts known only
     later."""
-    from jax.profiler import TraceAnnotation
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _UNRECORDED
+    return profiler.TraceAnnotation("repro." + name, **counts)
 
-    return TraceAnnotation("repro." + name, **counts)
+
+class _Unrecorded:
+    """The span while no profiler can be running."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **counts):
+        pass
+
+
+_UNRECORDED = _Unrecorded()

@@ -19,9 +19,7 @@ communication-aware re-planning). This module amortizes them:
 * :func:`batched_beam_search` / :func:`batched_greedy_search` — the
   paper's Algorithm 1/2 heuristics vectorized over scenarios,
   semantics-faithful to the scalar implementations (same pruning,
-  dominance, and windows; greedy is bit-identical always, beam is
-  bit-identical except under exact floating-point cost ties, where
-  truncation may keep a different equally-ranked candidate).
+  dominance, windows and tie order; both are bit-identical to them).
 * :func:`batched_total_cost` — score candidate split *sets* across every
   scenario at once (plan-portfolio evaluation / warm starts).
 * :class:`ScenarioGrid` / :func:`sweep` — the fleet API: declare a grid
@@ -79,6 +77,7 @@ from repro.core.latency import (
     ModelCostProfile,
     SplitCostModel,
     bottleneck_variant,
+    lsum,
 )
 from repro.core import solvers as S
 from repro.core.spans import span
@@ -932,12 +931,9 @@ def batched_beam_search(
     Faithful to :func:`repro.core.solvers.beam_search`: the same
     admissible completion bound ranks candidates before truncation, the
     same per-position dominance collapses ties (first-seen beam order
-    wins), and the suffix-packability lookahead prunes dead ends. On
-    instances without exact floating-point cost ties it returns
-    bit-identical splits to the scalar solver; under exact ties the
-    truncation order differs (landing-position vs generation order) and
-    either beam may keep the luckier candidate — only ``batched_dp``
-    carries an unconditional bit-parity guarantee.
+    wins), the suffix-packability lookahead prunes dead ends, and exact
+    ties of the ranking key break by landing position in both, so the
+    splits are bit-identical to the scalar solver's.
 
     ``n_devices`` optionally gives each scenario its own fleet size
     (see :func:`_normalize_ns`). Scenario ``s`` pins its final segment
@@ -2122,11 +2118,10 @@ def sweep(
     mixes no longer force per-(model, N) re-solve loops.
 
     Invariants:
-      * With ``solver="batched_dp"`` (and ``batched_greedy``) the
-        returned splits are bit-identical to running the scalar oracle
-        per scenario — the property-test contract
-        (``tests/test_solver_properties.py``); ``batched_beam`` matches
-        except under exact floating-point cost ties.
+      * With every batched solver on ``backend="numpy"`` the returned
+        splits are bit-identical to running the scalar oracle per
+        scenario — the property-test contract
+        (``tests/test_solver_properties.py``).
       * Row order always equals ``grid.scenarios()`` order regardless
         of grouping."""
     if solver not in BATCHED_SOLVERS:
@@ -2359,7 +2354,7 @@ def sweep_scalar(grid: ScenarioGrid, solver: str = "optimal_dp") -> SweepResult:
             bounds = [0, *res.splits, L]
             # cut_cost_s = compressed airtime + encoder time (identical
             # to the bare airtime for identity-variant scenarios)
-            tx_total = sum(m.cut_cost_s(b) for b in bounds[1:-1])
+            tx_total = lsum(m.cut_cost_s(b) for b in bounds[1:-1])
             obj = res.cost_s
             seg_sum = S.total_cost(fn, res.splits, L, "sum")
             device_s = seg_sum - tx_total

@@ -30,6 +30,10 @@ class ModelConfig:
     top_k: int = 0
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 1024  # tokens per dispatch group (GShard-style)
+    first_k_dense: int = 0  # leading layers with a dense FFN of width d_ff
+    moe_d_ff: int = 0  # routed/shared expert width; 0 -> d_ff
+    n_shared_experts: int = 0  # experts every token passes through
+    n_mtp_modules: int = 0  # multi-token-prediction modules after the head
 
     # --- MLA (MiniCPM3 / DeepSeek-V2-style latent attention) ---------------
     use_mla: bool = False
@@ -107,6 +111,15 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    def is_moe_layer(self, i: int) -> bool:
+        """Whether decoder layer ``i`` has an expert FFN (the first
+        ``first_k_dense`` layers of a MoE model are dense)."""
+        return self.is_moe and i >= self.first_k_dense
+
+    @property
     def vocab_padded(self) -> int:
         if not self.pad_vocab_to:
             return self.vocab
@@ -128,7 +141,7 @@ class ModelConfig:
         """Approximate parameter count (embeddings + blocks + head)."""
         d, hd = self.d_model, self.head_dim
         total = self.vocab * d * (1 if self.tie_embeddings else 2)
-        for kind in self.pattern:
+        for i, kind in enumerate(self.pattern):
             if kind in ("attn",):
                 if self.use_mla:
                     q = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * (
@@ -143,8 +156,10 @@ class ModelConfig:
                 else:
                     attn = (self.n_heads + 2 * self.n_kv_heads) * hd * d
                     attn += self.n_heads * hd * d
-                if self.is_moe:
-                    ff = self.n_experts * (3 if self.gated_mlp else 2) * d * self.d_ff
+                if self.is_moe_layer(i):
+                    mats = 3 if self.gated_mlp else 2
+                    ff = ((self.n_experts + self.n_shared_experts) * mats * d
+                          * self.expert_d_ff)
                     ff += d * self.n_experts
                 else:
                     ff = (3 if self.gated_mlp else 2) * d * self.d_ff
@@ -179,6 +194,10 @@ class ModelConfig:
         )
         if self.is_moe:
             small.update(n_experts=4, top_k=2)
+            if self.moe_d_ff:
+                small.update(moe_d_ff=64)
+            if self.first_k_dense:
+                small.update(first_k_dense=1)
         if self.use_mla:
             small.update(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
                          qk_rope_head_dim=8, v_head_dim=16)

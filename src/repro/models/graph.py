@@ -14,6 +14,12 @@ Conventions:
     (Table II packet counts confirm only the main tensor is shipped).
   * ``work_bytes`` approximates the peak resident activation set for the
     node (input + output), used for device memory feasibility.
+  * ``param_count`` is what the node holds; ``streamed_params`` what a
+    step reads of it (``None``: all of it). ``cache_elems`` is the KV or
+    latent cache the node holds, ``cache_read_elems`` what a step reads
+    of it. Only the DeepSeek-V3 layout (:func:`deepseek_layer_graph`)
+    sets the three; every other graph reads its parameters whole and
+    counts no cache.
 """
 
 from __future__ import annotations
@@ -32,6 +38,13 @@ class LayerNode:
     param_count: int
     out_elems: int  # elements of the output tensor (act bytes = elems * act_dtype)
     work_elems: int  # peak resident activation elements
+    streamed_params: float | None = None  # parameters read a step
+    cache_elems: int = 0  # resident cache elements
+    cache_read_elems: int = 0  # cache elements read a step
+
+    @property
+    def params_read(self) -> float:
+        return self.param_count if self.streamed_params is None else self.streamed_params
 
 
 @dataclass(frozen=True)
@@ -332,7 +345,11 @@ def arch_layer_graph(cfg, batch: int, seq: int, kv_len: int | None = None,
                      act_dtype_bytes: int = 2) -> LayerGraph:
     """LayerGraph for any assigned :class:`ModelConfig` — walks the block
     pattern with per-kind FLOP/param/activation formulas. Used by the
-    analytic roofline terms and by :func:`plan_pipeline` on real archs."""
+    analytic roofline terms and by :func:`plan_pipeline` on real archs.
+    A config with a dense prefix, shared experts or MTP modules takes
+    the DeepSeek-V3 layout (:func:`deepseek_layer_graph`)."""
+    if cfg.first_k_dense or cfg.n_shared_experts or cfg.n_mtp_modules:
+        return deepseek_layer_graph(cfg, batch, seq, kv_len)
     d = cfg.d_model
     nodes: list[LayerNode] = []
     act = batch * seq * d
@@ -351,9 +368,7 @@ def arch_layer_graph(cfg, batch: int, seq: int, kv_len: int | None = None,
                 # absorbed-score decode path: latent-space attention
                 f += 2.0 * batch * H * seq * kv * (cfg.kv_lora_rank + dr) * 2
                 f += 2.0 * batch * seq * H * dv * d
-                p = (d * cfg.q_lora_rank + cfg.q_lora_rank * H * (dn + dr)
-                     + d * (cfg.kv_lora_rank + dr)
-                     + cfg.kv_lora_rank * H * (dn + dv) + H * dv * d)
+                p = _mla_weights(cfg)
             else:
                 f = _attn_flops(batch, seq, d, cfg.n_heads, cfg.n_kv_heads,
                                 cfg.head_dim, kv_len)
@@ -402,6 +417,133 @@ def arch_layer_graph(cfg, batch: int, seq: int, kv_len: int | None = None,
         out_elems=batch * seq * cfg.vocab_padded,
         work_elems=act + batch * seq * cfg.vocab_padded))
     return LayerGraph(cfg.name, tuple(nodes), batch * seq)
+
+
+def experts_touched(n_experts: int, top_k: int, tokens: int) -> float:
+    """Expected number of distinct experts ``tokens`` tokens route to,
+    each picking ``top_k`` of ``n_experts`` uniformly:
+    E * (1 - (1 - k/E)^T)."""
+    return n_experts * (1.0 - (1.0 - top_k / n_experts) ** tokens)
+
+
+def _mla_weights(cfg) -> int:
+    """One MLA block's projection matrices: W_DQ, W_UQ/W_QR; W_DKV/W_KR,
+    W_UK/W_UV; W_O."""
+    d, H = cfg.d_model, cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    return (d * qr + qr * H * (dn + dr)
+            + d * (kr + dr) + kr * H * (dn + dv)
+            + H * dv * d)
+
+
+def _mla_params(cfg) -> int:
+    """One MLA block's weights: the projections plus the norms of the
+    query and key-value latents."""
+    return _mla_weights(cfg) + cfg.q_lora_rank + cfg.kv_lora_rank
+
+
+def _mla_flops(cfg, batch: int, seq: int, kv: int) -> float:
+    """One MLA block, latent-space (absorbed) form: projections, W_UK
+    folded into the query, scores over the latent plus rope key, the
+    latent-weighted sum, W_UV and W_O. Every (query, key) pair counts:
+    no causal halving."""
+    d, H = cfg.d_model, cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    T = batch * seq
+    return (2.0 * T * d * qr + 2.0 * T * qr * H * dn + 2.0 * T * qr * H * dr
+            + 2.0 * T * d * kr + 2.0 * T * d * dr  # down/up projections
+            + 2.0 * T * H * dn * kr  # W_UK folded into the query
+            + 2.0 * batch * H * seq * kv * (kr + dr)  # scores
+            + 2.0 * batch * H * seq * kv * kr  # latent-weighted sum
+            + 2.0 * T * H * kr * dv + 2.0 * T * H * dv * d)  # W_UV, W_O
+
+
+def _deepseek_block(cfg, i: int | None, batch: int, seq: int, kv: int):
+    """(flops, resident, streamed, cache) of one decoder block: MLA plus
+    a dense SwiGLU (layer ``i`` < ``first_k_dense``) or the routed
+    experts, the shared experts and the router (``i`` None: the MTP
+    module's block, which is MoE)."""
+    d, T = cfg.d_model, batch * seq
+    mats = 3 if cfg.gated_mlp else 2
+    flops = _mla_flops(cfg, batch, seq, kv)
+    attn = _mla_params(cfg) + 2 * d  # + attention and FFN norms
+    if i is not None and not cfg.is_moe_layer(i):
+        ffn = mats * d * cfg.d_ff
+        flops += 2.0 * T * ffn
+        params, streamed = attn + ffn, float(attn + ffn)
+    else:
+        E, Es = cfg.n_experts, cfg.n_shared_experts
+        expert = mats * d * cfg.expert_d_ff
+        router = d * E + E  # gate and its score-correction bias
+        flops += 2.0 * T * cfg.top_k * expert + 2.0 * T * Es * expert
+        flops += 2.0 * T * d * E
+        params = attn + E * expert + Es * expert + router
+        streamed = (attn + experts_touched(E, cfg.top_k, T) * expert
+                    + Es * expert + router)
+    cache = batch * kv * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+    return flops, params, streamed, cache
+
+
+def deepseek_layer_graph(cfg, batch: int, seq: int,
+                         kv_len: int | None = None) -> LayerGraph:
+    """DeepSeek-V3's layout as pipeline-stage candidates (arXiv:2412.19437):
+    ``embed``, ``layer_0`` ... ``layer_{n-1}``, ``head``.
+
+    Layers below ``first_k_dense`` are MLA plus a dense SwiGLU of
+    ``d_ff``; the rest are MLA plus ``n_experts`` routed experts of
+    ``moe_d_ff`` (``top_k`` a token), ``n_shared_experts`` shared ones
+    and the router. ``head`` holds the final norm, the output head and
+    the MTP modules (each: two norms, the ``2d -> d`` projection, one
+    MLA + MoE block, the shared head's norm and the shared head applied
+    again); MTP sits on ``head`` because it reads both the last hidden
+    state and the next token's embedding, so a cut between them would
+    ship two tensors. The embedding copy MTP reads is resident there.
+
+    A step of ``batch x seq`` tokens (``kv_len`` cached positions at
+    decode; ``seq`` at prefill) reads the experts it touches
+    (:func:`experts_touched`, uniform routing), the embedding rows it
+    looks up, and every other weight once; the output head is read
+    once per application. Each MLA block holds and reads a latent cache
+    of ``kv_lora_rank + qk_rope_head_dim`` a token.
+
+    Only MLA attention is priced here: a config without ``use_mla`` is
+    refused rather than given attention with no weights or cache."""
+    if not cfg.use_mla:
+        raise ValueError(
+            f"{cfg.name}: the dense-prefix / shared-expert / MTP layout is "
+            f"priced for MLA attention only (use_mla is False)")
+    d, V = cfg.d_model, cfg.vocab
+    T = batch * seq
+    kv = seq if kv_len is None else kv_len
+    act = T * d
+    rows = experts_touched(V, 1, T) * d  # embedding rows a lookup reads
+    nodes = [LayerNode("embed", flops=0.0, param_count=V * d, out_elems=act,
+                       work_elems=2 * act, streamed_params=rows)]
+    for i in range(cfg.n_layers):
+        f, p, s, c = _deepseek_block(cfg, i, batch, seq, kv)
+        nodes.append(LayerNode(f"layer_{i}", flops=f, param_count=p,
+                               out_elems=act, work_elems=2 * act,
+                               streamed_params=s, cache_elems=c,
+                               cache_read_elems=c))
+    head_w = V * d
+    flops = 2.0 * T * d * V
+    params = d + head_w
+    streamed = float(d + head_w)
+    cache = 0
+    for _ in range(cfg.n_mtp_modules):
+        f, p, s, c = _deepseek_block(cfg, None, batch, seq, kv)
+        own = 2 * d + 2 * d * d + d  # enorm, hnorm, eh_proj, head norm
+        flops += 2.0 * T * 2 * d * d + f + 2.0 * T * d * V
+        params += own + p + V * d  # + the embedding copy
+        streamed += own + s + rows + head_w
+        cache += c
+    nodes.append(LayerNode("head", flops=flops, param_count=params,
+                           out_elems=T * V, work_elems=act + T * V,
+                           streamed_params=streamed, cache_elems=cache,
+                           cache_read_elems=cache))
+    return LayerGraph(cfg.name, tuple(nodes), T)
 
 
 def ssm_layer_graph(

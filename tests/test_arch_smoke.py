@@ -112,6 +112,7 @@ class TestArchSmoke:
             "granite-34b": (88, 6144, 48, 1, 24576, 49152),
             "qwen2-vl-72b": (80, 8192, 64, 8, 29568, 152064),
             "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
+            "deepseek-v3": (61, 7168, 128, 128, 18432, 129280),
         }[arch_id]
         got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                cfg.d_ff, cfg.vocab)

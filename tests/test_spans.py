@@ -232,3 +232,25 @@ def test_rebuild_span_holds_its_build(tmp_path):
     dps = named(spans, "repro.dp")
     assert dps and all(parent(sp, spans) is build for sp in dps)
     assert rb.poll(2) is not None and rb.poll(3) is not None
+
+
+def test_pipeline_grid_spans_carry_their_counts(tmp_path):
+    from repro.configs import get_config
+    from repro.configs.shapes import ShapeSpec
+    from repro.core.planner import pipeline_grid
+    from repro.models.graph import experts_touched
+
+    shapes = (ShapeSpec("prefill", "prefill", 2048, 1),
+              ShapeSpec("decode", "decode", 4096, 8))
+    cfg = get_config("deepseek-v3")
+    grid, spans = traced(tmp_path, lambda: pipeline_grid(
+        cfg, shapes, (8, 16, 32), range(2, 17)))
+    assert {sp.name for sp in spans} <= set(SPANS)
+    (outer,) = named(spans, "repro.plan.pipeline")
+    assert outer.stats == {"shapes": 2, "mixes": 3, "layers": 63}
+    inner = named(spans, "repro.plan.pipeline.profile")
+    assert [parent(sp, spans) for sp in inner] == [outer, outer]
+    assert [sp.stats for sp in inner] == [
+        {"experts_touched": experts_touched(256, 8, 2048), "layers": 63},
+        {"experts_touched": experts_touched(256, 8, 8), "layers": 63}]
+    assert grid.size == 2 * 3 * 15 * 2

@@ -65,7 +65,9 @@ def _programs():
     S=8192; at S=100,000 it would need 30.5 GB); fused Pallas never
     builds ``C``, so it takes the ``iot-grid`` scale of S=100,000; the
     ``lax.scan`` DP runs the paper's MobileNetV2 depth (L=54) at
-    S=16,384."""
+    S=16,384. The bottleneck (``max``) fused kernel runs 16 pipeline
+    stages of DeepSeek-V3's 63 nodes (one lane tile, Lp=128) over a
+    23,040-scenario pipeline what-if."""
     import jax.numpy as jnp
 
     from repro.core import pallas_dp as PD
@@ -84,10 +86,15 @@ def _programs():
         "scan_dp": (
             SW._dp_jax_kernel("sum"),
             (((16_384, 5, 54, 54), f32), ((16_384,), i32)), False),
+        "fused_pallas_bottleneck_16": (
+            PD._raw_pallas_fn("fused", "max", bs, False),
+            (((16, PD.LANE, PD.LANE), f32), ((23_040, PD.LANE), f32),
+             ((23_040, 1), i32)), True),
     }
 
 
-@pytest.mark.parametrize("name", ["dense_pallas", "fused_pallas", "scan_dp"])
+@pytest.mark.parametrize("name", ["dense_pallas", "fused_pallas", "scan_dp",
+                                  "fused_pallas_bottleneck_16"])
 def test_dp_program_compiles_for_v5e(one_chip, name):
     import jax
 
