@@ -351,7 +351,6 @@ def pallas_dp_tables(
     tile, ``S`` replica-padded to a ``block_s`` multiple; padding is
     sliced off before returning. ``interpret=None`` resolves via
     :func:`pallas_interpret_default`."""
-    C = np.asarray(C, dtype=np.float64)
     Sn, N, L, _ = C.shape
     ns_arr = np.full(Sn, N, dtype=np.int64) if ns is None \
         else np.asarray(ns, dtype=np.int64)

@@ -28,9 +28,11 @@ SPANS = {
                          "SweepResult.build_time_s adds up",
     "repro.sweep.bank": "profile-bank matrices and the bank_idx loop",
     "repro.sweep.tx": "the group's transmission vectors (_group_tx_vectors)",
-    "repro.sweep.gather": "dense paths: the C gather and the TX add",
-    "repro.sweep.energy": "budgeted groups: the energy tensor and the "
-                          "budget mask",
+    "repro.sweep.gather": "dense paths: the cost tensor in one pass over "
+                          "scenario blocks (gather, TX add, energy and "
+                          "budget mask, cast to the DP's dtype); counts "
+                          "blocks, workers, budgeted (rows with a finite "
+                          "budget), masked (entries over budget)",
     "repro.sweep.rows": "one group's rows: whole-array pricing, SweepRow "
                         "construction and placement by index "
                         "(_group_rows); once more, the final tuple",

@@ -10,6 +10,7 @@ against values computed by hand from the shapes.
 
 from __future__ import annotations
 
+import dataclasses
 import glob
 import time
 from dataclasses import dataclass
@@ -18,8 +19,8 @@ import numpy as np
 import pytest
 
 from repro.core.async_replan import ManualExecutor, SurfaceRebuilder
-from repro.core.profiles import (ESP32, PROTOCOLS, paper_cost_model,
-                                 resnet50_cost_profile)
+from repro.core.profiles import (ESP32, PROTOCOLS, mobilenet_cost_profile,
+                                 paper_cost_model, resnet50_cost_profile)
 from repro.core.spans import SPANS
 from repro.core.sweep import ScenarioGrid, sweep
 
@@ -32,7 +33,6 @@ PARENT = {
     "repro.sweep.bank": "repro.sweep.build",
     "repro.sweep.tx": "repro.sweep.build",
     "repro.sweep.gather": "repro.sweep.build",
-    "repro.sweep.energy": "repro.sweep.build",
     "repro.sweep.rows": "repro.sweep",
     "repro.dp": "repro.sweep",
     "repro.dp.launch": "repro.dp",
@@ -110,13 +110,52 @@ def test_sweep_span_tree(tmp_path, backend, launches):
     # the one group's row loop, then the final ordering
     assert len(named(spans, "repro.sweep.rows")) == 2
     # the fused path never gathers C; no group carries a budget
-    assert len(named(spans, "repro.sweep.gather")) == (backend != "pallas")
-    assert not named(spans, "repro.sweep.energy")
+    gathers = named(spans, "repro.sweep.gather")
+    assert len(gathers) == (backend != "pallas")
+    assert all(sp.stats["budgeted"] == sp.stats["masked"] == 0
+               for sp in gathers)
     # one launch per distinct device stack on pallas, one on jax
     assert len(named(spans, "repro.dp.launch")) == launches
     for name in ("repro.dp.prepare", "repro.dp.fetch"):
         assert len(named(spans, name)) == launches
     assert named(spans, "repro.dp.reconstruct")
+    assert res.n_scenarios == grid.size
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+def test_budgeted_build_is_one_gather_a_group(tmp_path, backend):
+    """A budgeted group's cost tensor, energy mask included, is built in
+    one ``repro.sweep.gather`` that counts its blocks, workers, budgeted
+    rows and masked entries; there is no separate energy span."""
+    from repro.core import sweep as SW
+
+    esp = dataclasses.replace(ESP32, active_power_w=0.5)
+    links = {k: dataclasses.replace(v, tx_power_w=0.24, rx_power_w=0.12)
+             for k, v in PROTOCOLS.items()}
+    grid = ScenarioGrid(
+        models={"r50": resnet50_cost_profile(),
+                "mnv2": mobilenet_cost_profile()},
+        links=links, n_devices=(2, 3, 4, 5), devices=(esp,),
+        energy_budgets=(None, 30.0, 3.0))
+    sweep(grid, backend=backend)
+    res, spans = traced(tmp_path, lambda: sweep(grid, backend=backend))
+    assert {sp.name for sp in spans} <= set(SPANS)
+    assert "repro.sweep.energy" not in SPANS
+    builds = named(spans, "repro.sweep.build")
+    gathers = named(spans, "repro.sweep.gather")
+    assert len(builds) == len(gathers) == len(grid.models) == 2
+    for build, gather, profile in zip(builds, gathers, grid.models.values()):
+        assert parent(gather, spans) is build
+        per_group = grid.size // 2
+        bs = SW._BLOCK_BYTES // (5 * profile.num_layers ** 2 * 8)
+        blocks = -(-per_group // bs)
+        stats = gather.stats
+        assert sorted(stats) == ["blocks", "budgeted", "masked", "workers"]
+        assert stats["blocks"] == blocks
+        assert stats["workers"] == min(SW._CORES, blocks)
+        assert stats["budgeted"] == per_group * 2 // 3
+        assert 0 < stats["masked"] < stats["budgeted"] * 5 * \
+            profile.num_layers ** 2
     assert res.n_scenarios == grid.size
 
 

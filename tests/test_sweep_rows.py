@@ -129,6 +129,7 @@ CASES = {
                    "numpy"),
     "energy-budgets": (budget_grid, "batched_dp", "numpy"),
     "energy-budgets-pallas": (budget_grid, "batched_dp", "pallas"),
+    "energy-budgets-jax": (budget_grid, "batched_dp", "jax"),
     "compression": (lambda: grid_of(compression_factors=(1.0, 2.0, 4.0),
                                     variant_encoder_t_s=2e-3,
                                     variant_encoder_s_per_byte=1e-7),
@@ -193,9 +194,13 @@ def test_rows_bit_identical_to_reference_loop(monkeypatch, case):
                    for r in result.rows)
 
 
-@pytest.mark.parametrize("backend", ["numpy", "pallas"])
-def test_effective_link_once_per_scenario(monkeypatch, backend):
-    grid = grid_of(contention_groups=(1, 2), compression_factors=(1.0, 2.0))
+@pytest.mark.parametrize("backend,budgets", [
+    ("numpy", (None,)), ("pallas", (None,)),
+    ("numpy", (None, 0.02, 0.006)), ("jax", (None, 0.02, 0.006))],
+    ids=["numpy", "pallas", "numpy-budgets", "jax-budgets"])
+def test_effective_link_once_per_scenario(monkeypatch, backend, budgets):
+    grid = grid_of(contention_groups=(1, 2), compression_factors=(1.0, 2.0),
+                   energy_budgets=budgets)
     calls = []
     effective_link = SW.ScenarioGrid.effective_link
 
