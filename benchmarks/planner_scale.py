@@ -6,12 +6,12 @@ spec-resolved batched solve and reports:
 * **solve** — spec-path throughput (scenarios/s, us/scenario) of the
   planner tier end-to-end (spec construction + dispatch + batched DP).
 * **serialization** — what the serializable contract costs: bytes of a
-  fully-loaded surface spec (cost model + protocol bank + variant bank
-  + mesh), wall time of a ``to_json``/``from_json`` round trip, and
+  fully-loaded surface spec (cost model + protocol bank + variant
+  bank), wall time of a ``to_json``/``from_json`` round trip, and
   that overhead as a percentage of the solve itself (it is noise — the
   spec is O(model), the solve is O(S)). ``roundtrip_exact`` asserts the
   round trip is field-exact, non-finite floats included.
-* **parity** — the spec path vs the kwargs shim path on the same
+* **parity** — the spec path vs the kwargs path on the same
   tensor, asserted bitwise identical (same splits, costs, feasibility).
 * **rebuild** — a surface rebuild driven through ``FleetGateway``'s
   rebuilder twice: in-process (the spec resolved on this process) vs
@@ -49,7 +49,6 @@ from repro.core.profiles import (
     paper_cost_model,
 )
 from repro.core.spec import (
-    MeshSpec,
     PlannerService,
     PlanSpec,
     surfaces_spec,
@@ -103,8 +102,7 @@ def _rich_spec() -> PlanSpec:
         paper_cost_model("mobilenet_v2", "esp_now"), PROTOCOLS, (2, 3, 5),
         pt_scale=(1.0, 2.0, 4.0, 8.0, 16.0), loss_p=(None, 0.0, 0.05, 0.1),
         chunk_candidates=(256, 1024, 4096), energy_budget=float("inf"),
-        variants=esp32_variant_bank(), accuracy_floor=0.9,
-        mesh=MeshSpec(kind="local"))
+        variants=esp32_variant_bank(), accuracy_floor=0.9)
 
 
 def _solve_and_parity(S: int) -> tuple[dict, dict, float]:

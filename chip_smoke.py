@@ -495,13 +495,9 @@ def phase_four_chips(devs, loss, rate):
     C, ns = C[:-3], ns[:-3]  # S not divisible by the shard count
     S = C.shape[0]
     log(f"[4c] C{list(C.shape)}, S % 4 = {S % 4}")
-    results = {}
-    for kernel in ("jax", "pallas"):
-        results[kernel], first, second = twice(
-            lambda kernel=kernel: sharded_optimal_dp(
-                C, n_devices=ns, n_shards=4, kernel=kernel))
-        log(f"[4c] sharded kernel={kernel}: first {first:.2f}s second "
-            f"{second:.2f}s")
+    sharded, first, second = twice(
+        lambda: sharded_optimal_dp(C, n_devices=ns, n_shards=4))
+    log(f"[4c] sharded: first {first:.2f}s second {second:.2f}s")
     # every device must have held its quarter of C (float32)
     quarter = C.size * 4 // 4
     peaks = [d.memory_stats()["peak_bytes_in_use"] for d in devs[:4]]
@@ -512,12 +508,11 @@ def phase_four_chips(devs, loss, rate):
     one, first, second = twice(
         lambda: batched_optimal_dp(C, backend="jax", n_devices=ns))
     log(f"[4c] one-device jax: first {first:.2f}s second {second:.2f}s")
-    for kernel, res in results.items():
-        same = (np.array_equal(res.splits, one.splits)
-                and np.array_equal(res.cost_s, one.cost_s)
-                and np.array_equal(res.feasible, one.feasible))
-        log(f"[4c] sharded {kernel} node-identical to one-device jax: {same}")
-        check(same, f"sharded {kernel} differs from one-device jax")
+    same = (np.array_equal(sharded.splits, one.splits)
+            and np.array_equal(sharded.cost_s, one.cost_s)
+            and np.array_equal(sharded.feasible, one.feasible))
+    log(f"[4c] sharded node-identical to one-device jax: {same}")
+    check(same, "sharded differs from one-device jax")
 
 
 def main() -> None:

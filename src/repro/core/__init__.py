@@ -5,8 +5,7 @@ Public API (documented in ``docs/api.md``; layer map in
   latency    — Eq. 4-8 cost model (LinkProfile / DeviceProfile / SplitCostModel)
   spec       — the planner tier: PlanSpec (one serializable planning
                request; exact JSON round-trip), PlannerService (spec ->
-               batched engines; every kwarg entry point routes through
-               it), MeshSpec (single/multi-host shard mesh seam),
+               the public entry point it describes),
                build_surfaces_from_spec (process-pool rebuild worker)
   solvers    — beam / greedy / first_fit / random_fit / brute_force / optimal_dp
   planner    — plan_split (IoT), plan_pipeline (TPU PP), compare_solvers,
@@ -49,17 +48,6 @@ from repro.core.latency import (  # noqa: F401
     bottleneck_variant,
     bottleneck_variants,
     rtt_breakdown,
-)
-# NOTE: `repro.core.spec` sits below every layer it orchestrates (it
-# imports only latency at module scope; the engines load lazily inside
-# PlannerService), so it comes right after latency here.
-from repro.core.spec import (  # noqa: F401
-    MeshSpec,
-    PlanSpec,
-    PlannerService,
-    ScenarioRef,
-    SurfaceAxes,
-    build_surfaces_from_spec,
 )
 from repro.core.planner import (  # noqa: F401
     SegmentPlan,
@@ -114,7 +102,6 @@ from repro.core.sweep import (  # noqa: F401
 # imports sweep, so it must come after it here). Importing these names
 # is cheap — JAX loads lazily, on the first sharded solve.
 from repro.core.shard import (  # noqa: F401
-    mesh_from_spec,
     scenario_shards,
     sharded_dp_tables,
     sharded_optimal_dp,
@@ -141,6 +128,15 @@ from repro.core.solvers import (  # noqa: F401
     random_fit,
     total_cost,
     total_energy,
+)
+# NOTE: `repro.core.spec` sits above the engines it resolves to
+# (sweep/planner/surface import it nowhere), so it comes after them.
+from repro.core.spec import (  # noqa: F401
+    PlanSpec,
+    PlannerService,
+    ScenarioRef,
+    SurfaceAxes,
+    build_surfaces_from_spec,
 )
 # NOTE: `repro.core.async_replan` likewise stays a submodule attribute;
 # it imports surface, so it must come after it (and before adaptive,

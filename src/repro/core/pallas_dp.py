@@ -57,11 +57,9 @@ correctness in interpret mode; the >=10x fusion win is a real-hardware
 claim.
 
 Entry points up the stack: ``batched_optimal_dp(backend="pallas")``
-(dense), ``sweep(grid, backend="pallas")`` and ``build_surfaces(...,
-backend="pallas")`` (fused, via :func:`pallas_fused_optimal_dp`), and
-``sharded_dp_tables(kernel="pallas")`` (dense kernel under
-``shard_map`` — sharding partitions the scenario grid axis, the
-per-tile math is untouched).
+(dense), and ``sweep(grid, backend="pallas")`` and
+``build_surfaces(..., backend="pallas")`` (fused, via
+:func:`pallas_fused_optimal_dp`).
 
 Precision follows the active JAX config like every JAX-side backend:
 float32 by default, float64 when ``jax.config.jax_enable_x64`` is on.
@@ -201,10 +199,7 @@ def _raw_pallas_fn(mode: str, combine: str, block_s: int, interpret: bool):
     Shape-polymorphic: the ``pallas_call`` (grid, block specs, output
     shapes) is constructed at trace time from the operand shapes, so one
     wrapper serves every (S, N, L) — jit re-specializes per shape like
-    every other backend. Shared with :mod:`repro.core.shard` for
-    ``kernel="pallas"`` sharded solves (each shard traces this exact
-    function, so sharded and single-device pallas answers stay
-    node-identical). Callers pass pre-padded operands: ``Lp`` a lane
+    every other backend. Callers pass pre-padded operands: ``Lp`` a lane
     multiple (+inf padding), ``Sp`` a ``block_s`` multiple (replica
     rows), ``ns`` as an ``(Sp, 1)`` int32 column."""
     import jax

@@ -202,34 +202,6 @@ def plan_split_batch(
     energy_budget: float | Sequence[float] | None = None,
     variants: Sequence[BottleneckVariant] | None = None,
     accuracy_floor: float | None = None,
-    mesh_spec=None,
-    **solver_kwargs,
-) -> list[SplitPlan]:
-    """Kwarg shim over the planner tier for cost-model batches: builds a
-    :class:`repro.core.spec.PlanSpec` (:func:`repro.core.spec.
-    models_spec` — the cost models travel alongside as the operand) and
-    resolves it via :class:`repro.core.spec.PlannerService`, so kwarg
-    and spec callers run the same implementation
-    (:func:`_plan_split_batch_impl`) with bit-identical plans. See the
-    impl for the planning semantics."""
-    from repro.core.spec import PlannerService, models_spec  # lazy
-
-    spec = models_spec(
-        cost_models, n_devices=n_devices, solver=solver, backend=backend,
-        energy_budget=energy_budget, variants=variants,
-        accuracy_floor=accuracy_floor, mesh=mesh_spec, **solver_kwargs)
-    return PlannerService().plan(spec, cost_models)
-
-
-def _plan_split_batch_impl(
-    cost_models: Sequence[SplitCostModel],
-    n_devices: int | Sequence[int],
-    solver: str = "batched_dp",
-    backend: str = "numpy",
-    energy_budget: float | Sequence[float] | None = None,
-    variants: Sequence[BottleneckVariant] | None = None,
-    accuracy_floor: float | None = None,
-    mesh_spec=None,
     **solver_kwargs,
 ) -> list[SplitPlan]:
     """Plan many scenarios in one batched pass over stacked cost tensors.
@@ -268,6 +240,8 @@ def _plan_split_batch_impl(
     if accuracy_floor is not None and variants is None:
         raise ValueError("accuracy_floor requires a variants bank")
     L = cost_models[0].profile.num_layers
+    if isinstance(n_devices, np.integer):
+        n_devices = int(n_devices)
     if isinstance(n_devices, int):
         n_list = [n_devices] * len(cost_models)
     else:
@@ -306,8 +280,7 @@ def _plan_split_batch_impl(
         res = SW.solve_variant_bank(
             C, solver=solver, combine=combine, backend=backend, n_devices=ns,
             accuracy_proxy=[v.accuracy_proxy for v in variants],
-            accuracy_floor=accuracy_floor, mesh_spec=mesh_spec,
-            **solver_kwargs)
+            accuracy_floor=accuracy_floor, **solver_kwargs)
         return plans_from_batched(cost_models, res, n_list,
                                   nodes_expanded=int(np.prod(C.shape[2:])),
                                   variants=variants)
@@ -316,7 +289,7 @@ def _plan_split_batch_impl(
         E = SW.stack_cost_tensors(cost_models, n_arg, channels=("energy",))[0]
         C = SW.apply_energy_budget(C, E, energy_budget)
     res = SW.solve_batched(C, solver=solver, combine=combine, backend=backend,
-                           n_devices=ns, mesh_spec=mesh_spec, **solver_kwargs)
+                           n_devices=ns, **solver_kwargs)
     return plans_from_batched(cost_models, res, n_list,
                               nodes_expanded=int(np.prod(C.shape[1:])))
 

@@ -1,19 +1,15 @@
-"""Planner tier: one serializable request contract through every layer.
+"""Planner tier: one serializable form of a planning request.
 
-Nine PRs of features threaded their knobs (``backend=``, ``n_devices=``,
-``variants=``, ``accuracy_floor=``, ``energy_budget=``, ``channels=``,
-contention, mesh shape) hand-by-hand through ``solve_batched`` /
-``solve_multi_channel`` / ``solve_variant_bank``, ``plan_split_batch``,
-``build_surface(s)``, ``SurfaceRebuilder``, ``AdaptiveSplitManager`` and
-``FleetGateway``. A request that lives in kwargs cannot be serialized,
+The planning entry points (``solve_batched`` / ``solve_multi_channel`` /
+``solve_variant_bank``, ``plan_split_batch``, ``build_surfaces``) take
+keyword arguments. A request that lives in kwargs cannot be serialized,
 and a request that cannot be serialized cannot cross a process boundary
-— which blocks exactly the two ROADMAP scale seams (process-pool
-rebuilds and a multi-host planner mesh). This module is the control
-plane those seams hang off:
+— which a process-pool surface rebuild must. This module is the
+declarative form of those same requests:
 
 * :class:`PlanSpec` — a frozen, declarative description of ONE planning
   request: what to solve (scenario tensor shape / embedded surface
-  problem), how (solver + backend + combine + mesh), and under which
+  problem), how (solver + backend + combine), and under which
   constraints (fleet-size vector, channel weights, energy budget,
   variant bank, accuracy floor). ``to_json``/``from_json`` round-trip
   every field exactly — finite floats bit-exact via ``repr``, non-finite
@@ -21,20 +17,12 @@ plane those seams hang off:
   is strict, NaN-free JSON — and the spec pickles, so it crosses both
   ``json`` and ``multiprocessing`` boundaries.
 
-* :class:`PlannerService` — the execution tier that owns dispatch: it
-  resolves a spec (plus its big operands — a stacked cost tensor, a
-  list of cost models) to the existing batched implementations. The
-  public kwarg entry points up the stack are thin shims that construct
-  a spec and delegate here, so the spec path and the kwargs path are
-  the SAME code and bit-identical by construction (property-tested in
-  ``tests/test_spec.py`` across all four ``DP_BACKENDS``).
-
-* :class:`MeshSpec` — the multi-host seam for ``backend="sharded"``:
-  the shard mesh is constructed from the spec
-  (:func:`repro.core.shard.mesh_from_spec`) instead of hard-coding
-  ``jax.local_devices()``. The single-host default is node-identical to
-  the historical local mesh; ``kind="distributed"`` initializes
-  ``jax.distributed`` from the spec's coordinator fields.
+* :class:`PlannerService` — resolves a spec (plus its big operand — a
+  stacked cost tensor, a list of cost models) by checking the operand
+  against the spec and calling the matching public entry point, so a
+  spec solve and the equivalent kwargs call are the same code and
+  bit-identical (property-tested in ``tests/test_spec.py`` across all
+  four ``DP_BACKENDS``).
 
 * :func:`build_surfaces_from_spec` — the module-level (hence picklable)
   worker a :class:`~repro.core.async_replan.SurfaceRebuilder` submits
@@ -42,10 +30,9 @@ plane those seams hang off:
   the surfaces ship back, and the generation/swap semantics in the
   parent are untouched.
 
-Import discipline: this module imports only the leaf cost-model layer
-(:mod:`repro.core.latency`) at module scope; the solver/surface layers
-load lazily inside :class:`PlannerService` methods, so ``spec`` sits
-below every layer it orchestrates and anything can import it.
+Import discipline: this module sits ABOVE the engines it resolves to
+(:mod:`repro.core.sweep`, :mod:`repro.core.planner`,
+:mod:`repro.core.surface`); none of them imports it.
 """
 
 from __future__ import annotations
@@ -58,6 +45,9 @@ from typing import Mapping, Sequence
 
 import numpy as np
 
+from repro.core import planner as PL
+from repro.core import surface as SF
+from repro.core import sweep as SW
 from repro.core.latency import (
     COST_CHANNELS,
     BottleneckVariant,
@@ -70,7 +60,6 @@ from repro.core.latency import (
 )
 
 __all__ = [
-    "MeshSpec",
     "PlanSpec",
     "PlannerService",
     "ScenarioRef",
@@ -78,32 +67,6 @@ __all__ = [
     "build_surfaces_from_spec",
     "solve_from_json",
 ]
-
-
-@dataclass(frozen=True)
-class MeshSpec:
-    """How to build the ``backend="sharded"`` device mesh.
-
-    ``kind="local"`` (default) is today's mesh: the first ``n_shards``
-    local JAX devices (``None`` = all of them), node-identical to the
-    pre-spec sharded path by construction. ``kind="distributed"`` is
-    the multi-host seam: ``jax.distributed.initialize`` runs once from
-    ``coordinator``/``num_processes``/``process_id`` (all ``None``
-    means the environment — e.g. a launcher — already initialized it)
-    and the mesh spans the GLOBAL device list. Hashable, so solver
-    caches key on it like any other compile-relevant knob."""
-
-    kind: str = "local"  # "local" | "distributed"
-    n_shards: int | None = None
-    axis: str = "s"
-    coordinator: str | None = None  # "host:port" for jax.distributed
-    num_processes: int | None = None
-    process_id: int | None = None
-
-    def __post_init__(self):
-        if self.kind not in ("local", "distributed"):
-            raise ValueError(f"unknown mesh kind {self.kind!r}; "
-                             f"options: ['local', 'distributed']")
 
 
 @dataclass(frozen=True)
@@ -155,7 +118,8 @@ class PlanSpec:
     contract that lets a request cross a process boundary. Construct
     directly, or via the builders (:func:`tensor_spec`,
     :func:`channels_spec`, :func:`variant_bank_spec`,
-    :func:`models_spec`, :func:`surfaces_spec`) the kwarg shims use.
+    :func:`models_spec`, :func:`surfaces_spec`), which take the
+    matching entry point's keyword arguments.
 
     ``n_devices`` is the fleet-size vector: ``None`` (tensor width),
     one ``int`` for every scenario, or a per-scenario tuple.
@@ -178,7 +142,6 @@ class PlanSpec:
     cost_model: SplitCostModel | None = None
     protocols: tuple[tuple[str, LinkProfile], ...] | None = None
     surface: SurfaceAxes | None = None
-    mesh: MeshSpec | None = None
     solver_options: tuple[tuple[str, object], ...] = ()
 
     def options(self) -> dict:
@@ -220,7 +183,6 @@ _SPEC_TYPES: dict[str, type] = {
         SplitCostModel,
         ScenarioRef,
         SurfaceAxes,
-        MeshSpec,
         PlanSpec,
     )
 }
@@ -324,19 +286,19 @@ def _norm_variants(variants) -> tuple[BottleneckVariant, ...] | None:
 
 
 # ---------------------------------------------------------------------------
-# Spec builders — what the kwarg shims construct
+# Spec builders — one per entry point, taking its keyword arguments
 # ---------------------------------------------------------------------------
 
 
 def tensor_spec(C, *, solver="batched_dp", combine="sum", backend="numpy",
-                n_devices=None, mesh=None, **options) -> PlanSpec:
+                n_devices=None, **options) -> PlanSpec:
     """Spec for a plain batched solve over a stacked ``(S, N, L, L)``
     tensor (the :func:`repro.core.sweep.solve_batched` contract)."""
     return PlanSpec(
         solver=solver, backend=backend, combine=combine,
         scenario=ScenarioRef(kind="tensor",
                              shape=tuple(int(d) for d in np.shape(C))),
-        n_devices=_norm_n(n_devices), mesh=mesh,
+        n_devices=_norm_n(n_devices),
         solver_options=_norm_options(options),
     )
 
@@ -344,7 +306,7 @@ def tensor_spec(C, *, solver="batched_dp", combine="sum", backend="numpy",
 def channels_spec(C, *, channels=COST_CHANNELS, solver="batched_dp",
                   combine="sum", backend="numpy", n_devices=None,
                   energy_budget=None, channel_weights=None,
-                  channel_combines=None, mesh=None, **options) -> PlanSpec:
+                  channel_combines=None, **options) -> PlanSpec:
     """Spec for a multi-channel solve over ``(ch, S, N, L, L)`` (the
     :func:`repro.core.sweep.solve_multi_channel` contract)."""
     return PlanSpec(
@@ -356,14 +318,14 @@ def channels_spec(C, *, channels=COST_CHANNELS, solver="batched_dp",
         channel_weights=_norm_floats(channel_weights),
         channel_combines=(None if channel_combines is None
                           else tuple(channel_combines)),
-        energy_budget=_norm_budget(energy_budget), mesh=mesh,
+        energy_budget=_norm_budget(energy_budget),
         solver_options=_norm_options(options),
     )
 
 
 def variant_bank_spec(C, *, solver="batched_dp", combine="sum",
                       backend="numpy", n_devices=None, accuracy_proxy=None,
-                      accuracy_floor=None, mesh=None, **options) -> PlanSpec:
+                      accuracy_floor=None, **options) -> PlanSpec:
     """Spec for a joint (split, variant) solve over ``(V, S, N, L, L)``
     (the :func:`repro.core.sweep.solve_variant_bank` contract)."""
     return PlanSpec(
@@ -374,13 +336,13 @@ def variant_bank_spec(C, *, solver="batched_dp", combine="sum",
         accuracy_proxy=_norm_floats(accuracy_proxy),
         accuracy_floor=(None if accuracy_floor is None
                         else float(accuracy_floor)),
-        mesh=mesh, solver_options=_norm_options(options),
+        solver_options=_norm_options(options),
     )
 
 
 def models_spec(cost_models, *, n_devices, solver="batched_dp",
                 backend="numpy", energy_budget=None, variants=None,
-                accuracy_floor=None, mesh=None, **options) -> PlanSpec:
+                accuracy_floor=None, **options) -> PlanSpec:
     """Spec for a cost-model batch (the
     :func:`repro.core.planner.plan_split_batch` contract). The models
     travel ALONGSIDE the spec (they are the big operand); the spec
@@ -396,14 +358,14 @@ def models_spec(cost_models, *, n_devices, solver="batched_dp",
         variants=_norm_variants(variants),
         accuracy_floor=(None if accuracy_floor is None
                         else float(accuracy_floor)),
-        mesh=mesh, solver_options=_norm_options(options),
+        solver_options=_norm_options(options),
     )
 
 
 def surfaces_spec(cost_model, protocols, sizes, *, pt_scale, loss_p,
                   solver="batched_beam", backend="numpy", beam_width=8,
                   chunk_candidates=None, energy_budget=None, variants=None,
-                  accuracy_floor=None, mesh=None) -> PlanSpec:
+                  accuracy_floor=None) -> PlanSpec:
     """Spec for a surface-family build (the
     :func:`repro.core.surface.build_surfaces` contract). Unlike the
     tensor specs this one is SELF-CONTAINED — cost model, protocol
@@ -431,7 +393,6 @@ def surfaces_spec(cost_model, protocols, sizes, *, pt_scale, loss_p,
             chunk_candidates=(None if chunk_candidates is None
                               else tuple(int(c) for c in chunk_candidates)),
         ),
-        mesh=mesh,
         solver_options=(("beam_width", int(beam_width)),),
     )
 
@@ -444,13 +405,13 @@ def surfaces_spec(cost_model, protocols, sizes, *, pt_scale, loss_p,
 class PlannerService:
     """Resolves a :class:`PlanSpec` to the batched planning engines.
 
-    The service owns dispatch: the public kwarg entry points
+    Each method checks the operand against the spec, then calls the
+    public entry point the spec describes
     (``solve_batched``/``solve_multi_channel``/``solve_variant_bank``,
-    ``plan_split_batch``, ``build_surfaces``) are shims that build a
-    spec and call one of these methods, and the methods call the single
-    retained implementation — so spec-path and kwargs-path results are
-    the same code path and bit-identical by construction. Stateless and
-    cheap: construct freely (one per call site is fine)."""
+    ``plan_split_batch``, ``build_surfaces``) with the spec's fields —
+    so a spec solve and the equivalent kwargs call are the same code
+    and bit-identical. Stateless and cheap: construct freely (one per
+    call site is fine)."""
 
     # -- operand validation -------------------------------------------------
     @staticmethod
@@ -469,44 +430,34 @@ class PlannerService:
     # -- solves over stacked tensors ---------------------------------------
     def solve(self, spec: PlanSpec, C):
         """Resolve a ``"tensor"`` spec against its stacked cost tensor."""
-        from repro.core import sweep as SW
-
         self._check_operand(spec, "tensor", np.shape(C))
-        return SW._solve_batched_impl(
+        return SW.solve_batched(
             C, solver=spec.solver, combine=spec.combine,
             backend=spec.backend, n_devices=spec.n_devices,
-            mesh_spec=spec.mesh, **spec.options())
+            **spec.options())
 
     def solve_multi_channel(self, spec: PlanSpec, C):
         """Resolve a ``"channels"`` spec against ``(ch, S, N, L, L)``."""
-        from repro.core import sweep as SW
-
         self._check_operand(spec, "channels", np.shape(C))
-        return SW._solve_multi_channel_impl(
+        return SW.solve_multi_channel(
             C, channels=spec.channels or COST_CHANNELS,
             solver=spec.solver, combine=spec.combine, backend=spec.backend,
             n_devices=spec.n_devices, energy_budget=spec.energy_budget,
             channel_weights=spec.channel_weights,
-            channel_combines=spec.channel_combines,
-            mesh_spec=spec.mesh, **spec.options())
+            channel_combines=spec.channel_combines, **spec.options())
 
     def solve_variant_bank(self, spec: PlanSpec, C):
         """Resolve a ``"variant_bank"`` spec against ``(V, S, N, L, L)``."""
-        from repro.core import sweep as SW
-
         self._check_operand(spec, "variant_bank", np.shape(C))
-        return SW._solve_variant_bank_impl(
+        return SW.solve_variant_bank(
             C, solver=spec.solver, combine=spec.combine,
             backend=spec.backend, n_devices=spec.n_devices,
             accuracy_proxy=spec.accuracy_proxy,
-            accuracy_floor=spec.accuracy_floor,
-            mesh_spec=spec.mesh, **spec.options())
+            accuracy_floor=spec.accuracy_floor, **spec.options())
 
     # -- cost-model batches --------------------------------------------------
     def plan(self, spec: PlanSpec, cost_models: Sequence[SplitCostModel]):
         """Resolve a ``"models"`` spec against its cost-model batch."""
-        from repro.core import planner as PL
-
         self._check_operand(spec, "models")
         if spec.scenario is not None and spec.scenario.count is not None \
                 and spec.scenario.count != len(cost_models):
@@ -516,32 +467,29 @@ class PlannerService:
         n = spec.n_devices
         if n is None:
             raise ValueError("a 'models' spec needs n_devices")
-        return PL._plan_split_batch_impl(
+        return PL.plan_split_batch(
             cost_models, n, solver=spec.solver, backend=spec.backend,
             energy_budget=spec.energy_budget, variants=spec.variants,
-            accuracy_floor=spec.accuracy_floor, mesh_spec=spec.mesh,
-            **spec.options())
+            accuracy_floor=spec.accuracy_floor, **spec.options())
 
     # -- surface families ----------------------------------------------------
     def build_surfaces(self, spec: PlanSpec):
         """Resolve a self-contained ``"surface"`` spec to the surface
         family ``{n_devices: DegradationSurface}``."""
-        from repro.core import surface as SF
-
         self._check_operand(spec, "surface")
         if spec.cost_model is None or spec.protocols is None \
                 or spec.surface is None:
             raise ValueError("a 'surface' spec needs cost_model, protocols "
                              "and surface axes")
         opts = spec.options()
-        return SF._build_surfaces_impl(
+        return SF.build_surfaces(
             spec.cost_model, dict(spec.protocols), spec.n_devices,
             pt_scale=spec.surface.pt_scale, loss_p=spec.surface.loss_p,
             solver=spec.solver, backend=spec.backend,
             beam_width=int(opts.get("beam_width", 8)),
             chunk_candidates=spec.surface.chunk_candidates,
             energy_budget=spec.energy_budget, variants=spec.variants,
-            accuracy_floor=spec.accuracy_floor, mesh_spec=spec.mesh)
+            accuracy_floor=spec.accuracy_floor)
 
 
 # ---------------------------------------------------------------------------
@@ -564,7 +512,6 @@ def build_surfaces_from_spec(spec: PlanSpec | str):
 def solve_from_json(payload: str, C):
     """Solve a JSON-encoded ``"tensor"`` spec against ``C`` — the
     subprocess twin of :meth:`PlannerService.solve`, used by the
-    spec-pickling parity tests and :mod:`benchmarks.planner_scale` to
-    prove an out-of-process solve is bitwise identical to the
-    in-process one."""
+    spec-pickling parity test to prove an out-of-process solve is
+    bitwise identical to the in-process one."""
     return PlannerService().solve(PlanSpec.from_json(payload), C)

@@ -579,41 +579,6 @@ def build_surfaces(
     energy_budget: float | None = None,
     variants: Sequence[BottleneckVariant] | None = None,
     accuracy_floor: float | None = None,
-    mesh_spec=None,
-) -> dict[int, DegradationSurface]:
-    """Kwarg shim over the planner tier for surface-family builds: the
-    whole request becomes ONE self-contained
-    :class:`repro.core.spec.PlanSpec` (:func:`repro.core.spec.
-    surfaces_spec` — cost model, protocol links, and grid axes are all
-    spec fields) resolved by :class:`repro.core.spec.PlannerService`,
-    so a kwarg build, a spec build, and an out-of-process rebuild
-    (:func:`repro.core.spec.build_surfaces_from_spec`) all run the same
-    implementation (:func:`_build_surfaces_impl`) and return
-    node-identical families. See the impl for the build semantics."""
-    from repro.core.spec import PlannerService, surfaces_spec  # lazy
-
-    spec = surfaces_spec(
-        cost_model, protocols, n_devices, pt_scale=pt_scale, loss_p=loss_p,
-        solver=solver, backend=backend, beam_width=beam_width,
-        chunk_candidates=chunk_candidates, energy_budget=energy_budget,
-        variants=variants, accuracy_floor=accuracy_floor, mesh=mesh_spec)
-    return PlannerService().build_surfaces(spec)
-
-
-def _build_surfaces_impl(
-    cost_model: SplitCostModel,
-    protocols: Mapping[str, LinkProfile],
-    n_devices: Sequence[int],
-    pt_scale: Sequence[float] = DEFAULT_PT_SCALES,
-    loss_p: Sequence[float | None] | None = DEFAULT_LOSS_GRID,
-    solver: str = "batched_beam",
-    backend: str = "numpy",
-    beam_width: int = 8,
-    chunk_candidates: Sequence[int] | None = None,
-    energy_budget: float | None = None,
-    variants: Sequence[BottleneckVariant] | None = None,
-    accuracy_floor: float | None = None,
-    mesh_spec=None,
 ) -> dict[int, DegradationSurface]:
     """Precompute surfaces for SEVERAL fleet sizes in one batched solve.
 
@@ -649,7 +614,15 @@ def _build_surfaces_impl(
     if backend != "numpy" and solver != "batched_dp":
         raise ValueError(f"{solver} supports backend='numpy' only "
                          f"(got {backend!r})")
-    sizes = tuple(n_devices)
+    # the values a surface PlanSpec holds (spec.surfaces_spec), so a
+    # kwargs build and a spec or process-pool build price the same floats
+    sizes = tuple(int(n) for n in n_devices)
+    protocols = dict(protocols)
+    pt_scale = tuple(float(s) for s in pt_scale)
+    loss_p = None if loss_p is None else tuple(
+        None if lp is None else float(lp) for lp in loss_p)
+    if chunk_candidates is not None:
+        chunk_candidates = tuple(int(c) for c in chunk_candidates)
     if not sizes:
         raise ValueError("n_devices must name at least one fleet size")
     if len(set(sizes)) != len(sizes):
@@ -714,7 +687,7 @@ def _build_surfaces_impl(
             for lk in links
         ])
         C = SW.apply_energy_budget(C, E, energy_budget)
-    kwargs = {"beam_width": beam_width} if solver == "batched_beam" else {}
+    kwargs = {"beam_width": int(beam_width)} if solver == "batched_beam" else {}
 
     # ONE batched pass answers every requested fleet size
     res_by_n: dict[int, SW.BatchedSolverResult]
@@ -735,8 +708,7 @@ def _build_surfaces_impl(
         else:
             all_k = SW.batched_optimal_dp(C, combine=combine,
                                           backend=backend,
-                                          return_all_k=True,
-                                          mesh_spec=mesh_spec)
+                                          return_all_k=True)
         res_by_n = {n: all_k[n] for n in sizes}
         solve_time = all_k[n_max].wall_time_s
     elif solver == "batched_beam":

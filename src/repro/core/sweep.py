@@ -606,10 +606,10 @@ def _dp_tables_jax(C, combine, ns):
     return _dp_jax(C, combine, ns=ns)
 
 
-def _dp_tables_sharded(C, combine, ns, mesh_spec=None):
+def _dp_tables_sharded(C, combine, ns):
     from repro.core import shard as _shard  # lazy: no import cycle
 
-    return _shard.sharded_dp_tables(C, combine, ns=ns, mesh_spec=mesh_spec)
+    return _shard.sharded_dp_tables(C, combine, ns=ns)
 
 
 def _dp_tables_pallas(C, combine, ns):
@@ -650,7 +650,6 @@ def batched_optimal_dp(
     backend: str = "numpy",
     return_all_k: bool = False,
     n_devices: np.ndarray | Sequence[int] | int | None = None,
-    mesh_spec=None,
 ):
     """Exact split DP over a stacked cost tensor — one pass, every scenario.
 
@@ -670,9 +669,6 @@ def batched_optimal_dp(
         ``n_devices[s]`` devices in the same pass (heterogeneous fleet
         sizes batch like any other scenario axis). Mutually exclusive
         with ``return_all_k``.
-      mesh_spec: optional :class:`repro.core.spec.MeshSpec` describing
-        the device mesh for ``backend="sharded"`` (other backends
-        reject it). ``None`` keeps the historical local mesh.
 
     Returns a :class:`BatchedSolverResult` (or the all-k dict).
 
@@ -697,16 +693,9 @@ def batched_optimal_dp(
     except KeyError:
         raise ValueError(f"unknown backend {backend!r}; "
                          f"options: {sorted(DP_BACKENDS)}") from None
-    if mesh_spec is not None and backend != "sharded":
-        raise ValueError(
-            f"mesh_spec is a backend='sharded' knob; got "
-            f"backend={backend!r}")
     with span("dp"):
         t0 = time.perf_counter()
-        if mesh_spec is not None:
-            dp_per_k, parents = tables_fn(C, combine, ns, mesh_spec=mesh_spec)
-        else:
-            dp_per_k, parents = tables_fn(C, combine, ns)
+        dp_per_k, parents = tables_fn(C, combine, ns)
         return _results_from_dp_tables(dp_per_k, parents, L, N, Sn, backend,
                                        ns, return_all_k, t0)
 
@@ -1185,7 +1174,6 @@ def solve_batched(
     combine: str = "sum",
     backend: str = "numpy",
     n_devices: np.ndarray | Sequence[int] | int | None = None,
-    mesh_spec=None,
     **solver_kwargs,
 ) -> BatchedSolverResult:
     """The single dispatch point for batched solves over a stacked tensor
@@ -1193,46 +1181,13 @@ def solve_batched(
     builder, and the adaptive manager — one place to extend when adding
     a solver). ``n_devices`` (optional per-scenario fleet sizes) is
     threaded to every solver, so heterogeneous fleet sizes batch
-    uniformly regardless of algorithm.
-
-    This kwarg signature is a thin shim over the planner tier: it
-    constructs a :class:`repro.core.spec.PlanSpec` and resolves it via
-    :class:`repro.core.spec.PlannerService`, so kwarg callers and spec
-    callers run the SAME implementation (:func:`_solve_batched_impl`)
-    and get bit-identical results (property-tested across all four
-    :data:`DP_BACKENDS`). ``mesh_spec`` optionally names the
-    ``backend="sharded"`` device mesh (see
-    :class:`repro.core.spec.MeshSpec`)."""
-    from repro.core.spec import PlannerService, tensor_spec  # lazy: tier below
-
-    spec = tensor_spec(C, solver=solver, combine=combine, backend=backend,
-                       n_devices=n_devices, mesh=mesh_spec, **solver_kwargs)
-    return PlannerService().solve(spec, C)
-
-
-def _solve_batched_impl(
-    C: np.ndarray,
-    solver: str = "batched_dp",
-    combine: str = "sum",
-    backend: str = "numpy",
-    n_devices: np.ndarray | Sequence[int] | int | None = None,
-    mesh_spec=None,
-    **solver_kwargs,
-) -> BatchedSolverResult:
-    """The retained dispatch body behind :func:`solve_batched` —
-    called ONLY by :meth:`repro.core.spec.PlannerService.solve` so the
-    spec path and the kwargs path cannot diverge."""
+    uniformly regardless of algorithm."""
     if solver == "batched_dp":
         return batched_optimal_dp(C, combine=combine, backend=backend,
-                                  n_devices=n_devices, mesh_spec=mesh_spec,
-                                  **solver_kwargs)
+                                  n_devices=n_devices, **solver_kwargs)
     if solver in ("batched_beam", "batched_greedy"):
         if backend != "numpy":
             raise ValueError(f"{solver} supports backend='numpy' only")
-        if mesh_spec is not None:
-            raise ValueError(
-                f"mesh_spec is a backend='sharded' knob; {solver} "
-                f"runs on numpy only")
         fn = batched_beam_search if solver == "batched_beam" else batched_greedy_search
         return fn(C, combine=combine, n_devices=n_devices, **solver_kwargs)
     raise ValueError(f"unknown batched solver {solver!r}; "
@@ -1316,35 +1271,6 @@ def solve_multi_channel(
     energy_budget: float | np.ndarray | Sequence[float] | None = None,
     channel_weights: Sequence[float] | None = None,
     channel_combines: Sequence[str] | None = None,
-    mesh_spec=None,
-    **solver_kwargs,
-) -> BatchedSolverResult:
-    """Kwarg shim over the planner tier for multi-channel solves: builds
-    a :class:`repro.core.spec.PlanSpec` and resolves it via
-    :class:`repro.core.spec.PlannerService` — same implementation as
-    the spec path (:func:`_solve_multi_channel_impl`), bit-identical
-    results. See the impl for the solve semantics."""
-    from repro.core.spec import PlannerService, channels_spec  # lazy
-
-    spec = channels_spec(
-        C, channels=channels, solver=solver, combine=combine,
-        backend=backend, n_devices=n_devices, energy_budget=energy_budget,
-        channel_weights=channel_weights, channel_combines=channel_combines,
-        mesh=mesh_spec, **solver_kwargs)
-    return PlannerService().solve_multi_channel(spec, C)
-
-
-def _solve_multi_channel_impl(
-    C: np.ndarray,
-    channels: Sequence[str] = COST_CHANNELS,
-    solver: str = "batched_dp",
-    combine: str = "sum",
-    backend: str = "numpy",
-    n_devices: np.ndarray | Sequence[int] | int | None = None,
-    energy_budget: float | np.ndarray | Sequence[float] | None = None,
-    channel_weights: Sequence[float] | None = None,
-    channel_combines: Sequence[str] | None = None,
-    mesh_spec=None,
     **solver_kwargs,
 ) -> BatchedSolverResult:
     """Multi-objective batched solve over a stacked channel tensor
@@ -1383,7 +1309,7 @@ def _solve_multi_channel_impl(
     if len(channels) == 1 and energy_budget is None and channel_weights is None:
         return solve_batched(C[0], solver=solver, combine=combine,
                              backend=backend, n_devices=n_devices,
-                             mesh_spec=mesh_spec, **solver_kwargs)
+                             **solver_kwargs)
     try:
         lat = channels.index("latency")
     except ValueError:
@@ -1402,7 +1328,7 @@ def _solve_multi_channel_impl(
         C_eff = apply_energy_budget(C_eff, C[en], energy_budget)
     res = solve_batched(C_eff, solver=solver, combine=combine,
                         backend=backend, n_devices=n_devices,
-                        mesh_spec=mesh_spec, **solver_kwargs)
+                        **solver_kwargs)
     if channel_combines is None:
         channel_combines = tuple(
             combine if ch == "latency" else "sum" for ch in channels)
@@ -1495,32 +1421,6 @@ def solve_variant_bank(
     n_devices: np.ndarray | Sequence[int] | int | None = None,
     accuracy_proxy: np.ndarray | Sequence[float] | None = None,
     accuracy_floor: float | None = None,
-    mesh_spec=None,
-    **solver_kwargs,
-) -> BatchedSolverResult:
-    """Kwarg shim over the planner tier for joint (split, variant)
-    solves: builds a :class:`repro.core.spec.PlanSpec` and resolves it
-    via :class:`repro.core.spec.PlannerService` — same implementation
-    as the spec path (:func:`_solve_variant_bank_impl`), bit-identical
-    results. See the impl for the solve semantics."""
-    from repro.core.spec import PlannerService, variant_bank_spec  # lazy
-
-    spec = variant_bank_spec(
-        C, solver=solver, combine=combine, backend=backend,
-        n_devices=n_devices, accuracy_proxy=accuracy_proxy,
-        accuracy_floor=accuracy_floor, mesh=mesh_spec, **solver_kwargs)
-    return PlannerService().solve_variant_bank(spec, C)
-
-
-def _solve_variant_bank_impl(
-    C: np.ndarray,
-    solver: str = "batched_dp",
-    combine: str = "sum",
-    backend: str = "numpy",
-    n_devices: np.ndarray | Sequence[int] | int | None = None,
-    accuracy_proxy: np.ndarray | Sequence[float] | None = None,
-    accuracy_floor: float | None = None,
-    mesh_spec=None,
     **solver_kwargs,
 ) -> BatchedSolverResult:
     """Jointly optimize ``(split point, bottleneck variant)`` over a
@@ -1560,15 +1460,14 @@ def _solve_variant_bank_impl(
     if V == 1:
         res = solve_batched(C[0], solver=solver, combine=combine,
                             backend=backend, n_devices=n_devices,
-                            mesh_spec=mesh_spec, **solver_kwargs)
+                            **solver_kwargs)
         variant = np.where(res.feasible, 0, -1).astype(np.int64)
         return replace(res, variant=variant)
     ns = _normalize_ns(n_devices, Sn, N) if n_devices is not None else None
     folded_ns = None if ns is None else np.tile(ns, V)
     res = solve_batched(C.reshape(V * Sn, N, L, L), solver=solver,
                         combine=combine, backend=backend,
-                        n_devices=folded_ns, mesh_spec=mesh_spec,
-                        **solver_kwargs)
+                        n_devices=folded_ns, **solver_kwargs)
     folded, _ = _fold_variant_axis(res, V, Sn)
     return folded
 

@@ -158,8 +158,6 @@ class FleetGateway:
         grid = dict(self.surface_grid)
         grid.setdefault("pt_scale", DEFAULT_PT_SCALES)
         grid.setdefault("loss_p", DEFAULT_LOSS_GRID)
-        if "mesh_spec" in grid:  # build_surfaces spells the knob mesh_spec
-            grid["mesh"] = grid.pop("mesh_spec")
         self.plan_spec = surfaces_spec(
             cost_model, self.protocols, self.fleet_sizes,
             solver=batched, **grid)

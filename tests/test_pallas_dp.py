@@ -19,7 +19,6 @@ import numpy as np
 import pytest
 
 from repro.core import pallas_dp as PD
-from repro.core import shard as SH
 from repro.core import solvers as S
 from repro.core import sweep as SW
 from repro.core.latency import (
@@ -232,30 +231,6 @@ class TestFusedKernel:
             PD.pallas_fused_dp_tables(local, tx[:, :3])
         with pytest.raises(ValueError, match="bank_idx must be"):
             PD.pallas_fused_optimal_dp(local, np.zeros((4, 2), dtype=int), tx)
-
-
-# ---------------------------------------------------------------------------
-# Composition: sharded shard_map over the pallas tile kernel
-# ---------------------------------------------------------------------------
-
-
-class TestShardKernel:
-    def test_sharded_pallas_node_identical(self):
-        C = make_C(7, 3, 10, seed=13)
-        ns = make_ns(7, 3, seed=13)
-        a = SH.sharded_optimal_dp(C, n_devices=ns, kernel="jax")
-        b = SH.sharded_optimal_dp(C, n_devices=ns, kernel="pallas")
-        c = SW.batched_optimal_dp(C, n_devices=ns, backend="pallas")
-        assert np.array_equal(a.splits, b.splits)
-        assert np.array_equal(a.cost_s, b.cost_s)
-        assert np.array_equal(a.feasible, b.feasible)
-        assert np.array_equal(b.splits, c.splits)
-        assert np.array_equal(b.cost_s, c.cost_s)
-
-    def test_unknown_shard_kernel_rejected(self):
-        C = make_C(2, 2, 5, seed=1)
-        with pytest.raises(ValueError, match="unknown shard kernel"):
-            SH.sharded_optimal_dp(C, kernel="mosaic")
 
 
 # ---------------------------------------------------------------------------

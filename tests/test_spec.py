@@ -1,21 +1,25 @@
 """Planner-tier contract tests: PlanSpec serialization + spec≡kwargs parity.
 
-Three families pin the PR-10 contract:
+Four families pin the contract:
 
 * **Round-trip exactness** — ``PlanSpec.to_json``/``from_json`` is a
   field-exact bijection: finite floats bit-for-bit (``repr`` round-trip),
   non-finite floats through explicit tags (the payload itself stays
   strict, NaN-free JSON), tuples stay tuples, ``None`` loss entries stay
   ``None``, and every registered nested dataclass (cost model, variant
-  bank, mesh) reconstructs ``==``-equal. Pickle round-trips too — the
+  bank) reconstructs ``==``-equal. Pickle round-trips too — the
   process-boundary contract.
 
-* **Spec-path ≡ kwargs-path** — every public planning entry point is a
-  shim that builds a spec and resolves it through ``PlannerService``;
-  these tests call BOTH paths (and the retained ``_impl`` directly) and
-  assert bitwise-identical results across all four ``DP_BACKENDS`` for
-  the DP and both numpy-only solvers, plus multi-channel, variant-bank,
-  cost-model-batch and surface-family solves.
+* **Spec-path ≡ kwargs-path** — ``PlannerService`` resolves a spec by
+  calling the public entry point it describes; these tests call BOTH
+  paths and assert bitwise-identical results across all four
+  ``DP_BACKENDS`` for the DP and both numpy-only solvers, plus
+  multi-channel, variant-bank, cost-model-batch and surface-family
+  solves.
+
+* **One direction** — every public entry point runs with
+  ``PlannerService`` replaced by a class that refuses every call: the
+  engines never call up into the spec tier.
 
 * **Process boundary** — a spec serialized to JSON, shipped to a
   subprocess (spawn, so the child proves importability from scratch)
@@ -42,8 +46,8 @@ from repro.core.profiles import (
     esp32_variant_bank,
     paper_cost_model,
 )
+from repro.core import spec as SPEC
 from repro.core.spec import (
-    MeshSpec,
     PlannerService,
     PlanSpec,
     ScenarioRef,
@@ -112,7 +116,7 @@ def assert_surfaces_identical(a, b):
 
 def rich_spec():
     """A spec exercising every field family: nested cost model, protocol
-    pairs, variant bank, non-finite budget, awkward floats, mesh."""
+    pairs, variant bank, non-finite budget, awkward floats."""
     return surfaces_spec(
         paper_cost_model("mobilenet_v2", "esp_now"),
         PROTOCOLS, (2, 3, 5),
@@ -123,7 +127,6 @@ def rich_spec():
         energy_budget=INF,
         variants=esp32_variant_bank(),
         accuracy_floor=0.9,
-        mesh=MeshSpec(kind="local", n_shards=2),
     )
 
 
@@ -160,7 +163,8 @@ class TestRoundTrip:
 
     def test_payload_must_decode_to_planspec(self):
         with pytest.raises(ValueError, match="not PlanSpec"):
-            PlanSpec.from_json('{"__type__": "MeshSpec"}')
+            PlanSpec.from_json('{"__type__": "ScenarioRef", '
+                               '"kind": "tensor"}')
 
     def test_none_loss_entries_and_tuples_preserved(self):
         spec = rich_spec()
@@ -175,11 +179,9 @@ class TestRoundTrip:
         spec = rich_spec()
         assert pickle.loads(pickle.dumps(spec)) == spec
 
-    def test_scenario_and_mesh_validation(self):
+    def test_scenario_validation(self):
         with pytest.raises(ValueError, match="unknown scenario kind"):
             ScenarioRef(kind="wat")
-        with pytest.raises(ValueError, match="unknown mesh kind"):
-            MeshSpec(kind="wat")
 
     def test_solver_options_order_insensitive(self):
         a = tensor_spec(np.zeros((1, 2, 3, 3)), beam_width=4, return_all_k=False)
@@ -189,8 +191,8 @@ class TestRoundTrip:
 
 
 class TestSpecKwargsParity:
-    """The shim path, the explicit spec path, and the retained _impl
-    must agree bitwise — they ARE the same code by construction; these
+    """The kwargs path and the explicit spec path must agree bitwise —
+    the service calls the entry point with the spec's fields; these
     tests keep it that way."""
 
     @pytest.mark.parametrize("backend", sorted(SW.DP_BACKENDS))
@@ -205,11 +207,7 @@ class TestSpecKwargsParity:
         spec = tensor_spec(C, solver="batched_dp", combine=combine,
                            backend=backend, n_devices=n)
         via_spec = PlannerService().solve(spec, C)
-        via_impl = SW._solve_batched_impl(C, solver="batched_dp",
-                                          combine=combine, backend=backend,
-                                          n_devices=spec.n_devices)
         assert_results_identical(via_kwargs, via_spec)
-        assert_results_identical(via_kwargs, via_impl)
 
     @pytest.mark.parametrize("solver", ["batched_beam", "batched_greedy"])
     def test_beam_and_greedy_parity(self, solver):
@@ -291,20 +289,93 @@ class TestSpecKwargsParity:
             PlannerService().plan(
                 models_spec([], n_devices=None), [])
 
-    def test_mesh_spec_requires_sharded_backend(self):
-        C = rand_tensor(np.random.default_rng(23))
-        with pytest.raises(ValueError, match="backend='sharded' knob"):
-            SW.solve_batched(C, mesh_spec=MeshSpec())
-        with pytest.raises(ValueError, match="numpy only"):
-            SW.solve_batched(C, solver="batched_beam", backend="numpy",
-                             mesh_spec=MeshSpec())
 
-    def test_local_mesh_spec_node_identical_to_default_sharded(self):
-        C = rand_tensor(np.random.default_rng(29))
-        plain = SW.solve_batched(C, backend="sharded")
-        meshed = SW.solve_batched(C, backend="sharded",
-                                  mesh_spec=MeshSpec(kind="local"))
-        assert_results_identical(plain, meshed)
+class _RefusingService:
+    """Stands in for ``PlannerService``: every method call fails."""
+
+    def _refuse(self, *args, **kwargs):
+        raise AssertionError("an entry point called into PlannerService")
+
+    solve = solve_multi_channel = solve_variant_bank = _refuse
+    plan = build_surfaces = _refuse
+
+
+def _batched_case():
+    C = rand_tensor(np.random.default_rng(37))
+    n = (2, 3, 2, 3, 2)
+    return (lambda: SW.solve_batched(C, backend="numpy", n_devices=n),
+            lambda: PlannerService().solve(
+                tensor_spec(C, backend="numpy", n_devices=n), C),
+            assert_results_identical)
+
+
+def _multi_channel_case():
+    rng = np.random.default_rng(41)
+    C = np.stack([rand_tensor(rng, S=4, N=3, L=5) for _ in COST_CHANNELS])
+    kw = dict(backend="numpy", energy_budget=20.0)
+    return (lambda: SW.solve_multi_channel(C, **kw),
+            lambda: PlannerService().solve_multi_channel(
+                channels_spec(C, **kw), C),
+            assert_results_identical)
+
+
+def _variant_bank_case():
+    rng = np.random.default_rng(43)
+    C = np.stack([rand_tensor(rng) for _ in range(2)])
+    kw = dict(backend="numpy", accuracy_proxy=(1.0, 0.95))
+    return (lambda: SW.solve_variant_bank(C, **kw),
+            lambda: PlannerService().solve_variant_bank(
+                variant_bank_spec(C, **kw), C),
+            assert_results_identical)
+
+
+def _plans_identical(a, b):
+    assert [(p.splits, p.total_latency_s) for p in a] == \
+        [(p.splits, p.total_latency_s) for p in b]
+
+
+def _plan_batch_case():
+    models = [paper_cost_model("mobilenet_v2", p) for p in ("esp_now", "ble")]
+    return (lambda: PL.plan_split_batch(models, (2, 3), backend="numpy"),
+            lambda: PlannerService().plan(
+                models_spec(models, n_devices=(2, 3), backend="numpy"),
+                models),
+            _plans_identical)
+
+
+def _families_identical(a, b):
+    assert sorted(a) == sorted(b)
+    for n in a:
+        assert_surfaces_identical(a[n], b[n])
+
+
+def _surfaces_case():
+    from repro.core.surface import build_surfaces
+
+    model = paper_cost_model("mobilenet_v2", "esp_now")
+    protocols = {"esp_now": ESP_NOW}
+    return (lambda: build_surfaces(model, protocols, (2,), backend="numpy",
+                                   **GRID),
+            lambda: PlannerService().build_surfaces(
+                surfaces_spec(model, protocols, (2,), backend="numpy",
+                              **GRID)),
+            _families_identical)
+
+
+@pytest.mark.parametrize("case", [
+    _batched_case, _multi_channel_case, _variant_bank_case,
+    _plan_batch_case, _surfaces_case,
+], ids=["solve_batched", "solve_multi_channel", "solve_variant_bank",
+        "plan_split_batch", "build_surfaces"])
+def test_entry_point_never_calls_the_spec_tier(case, monkeypatch):
+    """Each public entry point runs its own body: with the spec tier's
+    ``PlannerService`` refusing every call it still returns its normal
+    result — the same one the spec path gives once the service is back."""
+    via_kwargs, via_spec, same = case()
+    monkeypatch.setattr(SPEC, "PlannerService", _RefusingService)
+    got = via_kwargs()
+    monkeypatch.undo()
+    same(got, via_spec())
 
 
 class TestManagersRouteThroughSpec:
