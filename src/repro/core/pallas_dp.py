@@ -408,6 +408,44 @@ def _fused_dp0_host(local, tx, dtype):
     return local[0, 0, :].astype(dtype)[None, :] + tx.astype(dtype)
 
 
+def _join_live_stacks(bank_idx: np.ndarray, ns_arr: np.ndarray):
+    """The device stacks the fused bank path launches on, one launch each.
+
+    Device slots at or beyond a scenario's own fleet size ``n_s`` are
+    dead: the kernel masks every step ``k > n_s`` and carries the row's
+    stale table forward, so a scenario can ride any stack whose first
+    ``n_s`` slots are its live slots. The group's distinct (live slots,
+    ``n_s``) pairs are taken longest first, in ``np.unique`` order within
+    a length; a pair joins the first stack already chosen that extends
+    its live slots, else its dead slots copy its last live slot and that
+    stack is chosen. A homogeneous mix thus takes one stack whatever its
+    fleet sizes, and so does a heterogeneous ``(d1, d2, d3)`` over fleet
+    sizes 1-3. The loop runs over pairs, never over scenarios.
+
+    Returns ``(stacks, launch_of, n_canon)``: the ``(U, N)`` stacks, the
+    ``(S,)`` stack of each scenario, and how many distinct stacks the
+    rows have with dead slots read as bank row 0 (the launches one per
+    stack would make without the join)."""
+    N = bank_idx.shape[1]
+    canon = np.where(np.arange(N)[None, :] >= ns_arr[:, None], 0, bank_idx)
+    pairs, inv = np.unique(np.column_stack([canon, ns_arr]), axis=0,
+                           return_inverse=True)
+    n_canon = len(np.unique(pairs[:, :N], axis=0))
+    stacks: list[np.ndarray] = []
+    stack_of = np.empty(len(pairs), dtype=np.int64)
+    for p in np.argsort(-pairs[:, N], kind="stable"):
+        n = int(pairs[p, N])
+        live = pairs[p, :n]
+        for u, stack in enumerate(stacks):
+            if np.array_equal(stack[:n], live):
+                stack_of[p] = u
+                break
+        else:
+            stack_of[p] = len(stacks)
+            stacks.append(np.concatenate([live, np.full(N - n, live[-1])]))
+    return np.stack(stacks), stack_of[inv.reshape(-1)], n_canon
+
+
 def pallas_fused_dp_tables(
     local: np.ndarray,
     tx: np.ndarray,
@@ -425,10 +463,10 @@ def pallas_fused_dp_tables(
     ``C[s,k] = local[k] + tx[s]`` into each reduction step. Plan nodes
     (parents) match the dense path exactly except under exact-cost
     ties; dp costs may differ by construction rounding (<=1 ulp per
-    entry — see the module docstring). Heterogeneous device mixes go
-    through
-    :func:`pallas_fused_optimal_dp`, which subgroups scenarios by
-    device stack before calling this."""
+    entry — see the module docstring). Scenario ``s`` never reads the
+    slots ``local[k]``, ``k >= ns[s]``. Heterogeneous device mixes go
+    through :func:`pallas_fused_optimal_dp`, which joins scenarios into
+    device stacks and launches the same kernel once a stack."""
     local = np.asarray(local, dtype=np.float64)
     tx = np.asarray(tx, dtype=np.float64)
     if local.ndim != 3 or local.shape[1] != local.shape[2]:
@@ -507,13 +545,15 @@ def pallas_fused_optimal_dp(
       combine / return_all_k / n_devices: the
         :func:`repro.core.sweep.batched_optimal_dp` solver contract.
 
-    Heterogeneous mixes are subgrouped by distinct device stack (device
-    slots at or beyond a scenario's own ``n_devices`` are dead filler
-    and are canonicalized first, so mixes differing only in dead slots
-    share a launch); each subgroup runs one fused kernel pass and the
-    tables scatter back into grid order. The bank is small by
-    construction — distinct stacks, not scenarios, bound the subgroup
-    count.
+    Scenarios are subgrouped by device stack: device slots at or beyond
+    a scenario's own ``n_devices`` are dead (the kernel never reads
+    them), so a scenario joins any stack that extends its live slots
+    (:func:`_join_live_stacks`) and one mix takes one stack over all its
+    fleet sizes. Each subgroup runs one fused kernel pass and the tables
+    scatter back into grid order. The bank is small by construction —
+    distinct live stacks, not scenarios, bound the subgroup count. The
+    ``repro.dp`` span counts ``stacks`` (distinct stacks with dead slots
+    read as row 0) and ``launches``.
 
     Bottleneck variants need NO kernel change: a variant reprices only
     the cut (compressed airtime + encoder time), both functions of the
@@ -557,7 +597,7 @@ def pallas_fused_optimal_dp(
     import jax
 
     dtype = jax.dtypes.canonicalize_dtype(np.float64)
-    with span("dp"):
+    with span("dp") as sp:
         t0 = time.perf_counter()
         ns_arr = np.full(Sn, N, dtype=np.int64) if ns is None else ns
         if Sn == 0 or N == 1:
@@ -569,15 +609,12 @@ def pallas_fused_optimal_dp(
             return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
                                               "pallas", ns, return_all_k, t0)
         bs, itp = _resolve_opts(block_s, interpret)
-        # canonicalize dead device slots (>= a scenario's own fleet size)
-        # to row 0 so stacks differing only there share one kernel launch
-        # — the solvers never read those slots (frozen-row contract)
-        canon = bank_idx.copy()
-        canon[np.arange(N)[None, :] >= ns_arr[:, None]] = 0
-        stacks, inv = np.unique(canon, axis=0, return_inverse=True)
+        stacks, launch_of, n_stacks = _join_live_stacks(bank_idx, ns_arr)
+        sp.set_metadata(stacks=n_stacks, launches=len(stacks))
         tables = _empty_tables(Sn, N, L, dtype)
-        for u in range(stacks.shape[0]):
-            _fused_launch(bank[stacks[u]], tx, ns_arr, np.flatnonzero(inv == u),
+        for u in range(len(stacks)):
+            _fused_launch(bank[stacks[u]], tx, ns_arr,
+                          np.flatnonzero(launch_of == u),
                           combine, bs, itp, dtype, tables)
         dp_per_k, parents = SW._dp_tables_to_numpy(*tables, Sn, N, L)
         return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,

@@ -36,7 +36,10 @@ SPANS = {
     "repro.sweep.rows": "one group's rows: whole-array pricing, SweepRow "
                         "construction and placement by index "
                         "(_group_rows); once more, the final tuple",
-    "repro.dp": "a DP solve, solver entry to the wall_time_s stamp",
+    "repro.dp": "a DP solve, solver entry to the wall_time_s stamp; on "
+                "the fused bank path counts stacks (distinct device "
+                "stacks with dead slots read as bank row 0) and launches "
+                "(kernel launches made, one a joined live stack)",
     "repro.dp.launch": "one kernel launch; counts kernel (its jitted name), "
                        "rows, rows_padded, lanes, lanes_padded, h2d_bytes, "
                        "d2h_bytes (padding included)",

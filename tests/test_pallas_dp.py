@@ -213,6 +213,69 @@ class TestFusedKernel:
         b = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, n_devices=ns)
         assert_same_or_exact_tie(a, b, C, "sum", ctx="bank_idx")
 
+    @pytest.mark.parametrize("combine", ["sum", "max"])
+    @pytest.mark.parametrize("n_mixes", [2, 3])
+    def test_homogeneous_mixes_take_one_launch_each(self, monkeypatch,
+                                                    n_mixes, combine):
+        """1-tuple mixes over fleet sizes 2..N, dead slots at row 0 as
+        the sweep leaves them: one launch a mix, and every table, split,
+        cost and feasibility bit-identical to solving each row-0 stack
+        alone."""
+        N, L, per = 6, 9, 3
+        rng = np.random.RandomState(40 + n_mixes)
+        bank = rng.uniform(1e-3, 5.0, size=(2 * n_mixes, L, L))
+        il = np.tril_indices(L, -1)
+        bank[:, il[0], il[1]] = INF
+        rows, ns = [], []
+        for m in range(n_mixes):  # bank rows 2m (first), 2m + 1 (rest)
+            for n in range(2, N + 1):
+                row = [2 * m] + [2 * m + 1] * (n - 1) + [0] * (N - n)
+                rows += [row] * per
+                ns += [n] * per
+        bank_idx, ns = np.array(rows), np.array(ns)
+        tx = rng.uniform(0.0, 2.0, size=(len(ns), L))
+        launches = _count_fused_launches(monkeypatch)
+        got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, combine=combine,
+                                         n_devices=ns)
+        assert len(launches) == n_mixes
+        _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, combine, got,
+                                    launches[0])
+
+    def test_heterogeneous_mix_fleet_sizes_share_one_launch(self,
+                                                            monkeypatch):
+        """A mix (d1, d2, d3) over fleet sizes 1-3: each shorter fleet's
+        live slots are a prefix of the longest, so one launch serves
+        all three (dead slots at row 0 would make three)."""
+        N, L = 3, 10
+        rng = np.random.RandomState(31)
+        bank = rng.uniform(1e-3, 5.0, size=(3, L, L))
+        il = np.tril_indices(L, -1)
+        bank[:, il[0], il[1]] = INF
+        bank_idx = np.array([[0, 0, 0], [0, 1, 0], [0, 1, 2]] * 4)
+        ns = np.array([1, 2, 3] * 4)
+        tx = rng.uniform(0.0, 2.0, size=(len(ns), L))
+        launches = _count_fused_launches(monkeypatch)
+        got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, n_devices=ns)
+        assert len(launches) == 1
+        _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, "sum", got,
+                                    launches[0])
+
+    @pytest.mark.parametrize("combine", ["sum", "max"])
+    def test_dead_slots_are_never_read(self, combine):
+        """NaN in every slot beyond the largest fleet size leaves every
+        table bit-identical: the frozen-row contract that lets a
+        scenario ride a stack that only extends its live slots."""
+        Sn, N, L = 10, 6, 11
+        local, tx = make_local_tx(Sn, N, L, seed=8)
+        ns = np.random.RandomState(8).randint(1, N - 1, size=Sn)
+        poisoned = local.copy()
+        poisoned[int(ns.max()):] = np.nan
+        ref = PD.pallas_fused_dp_tables(local, tx, combine, ns=ns)
+        got = PD.pallas_fused_dp_tables(poisoned, tx, combine, ns=ns)
+        for a, b in zip(ref[0], got[0]):
+            assert np.array_equal(a, b)
+        assert np.array_equal(ref[1], got[1])
+
     def test_all_k_and_ns_mutually_exclusive(self):
         local, tx = make_local_tx(3, 2, 5, seed=1)
         with pytest.raises(ValueError, match="mutually exclusive"):
@@ -231,6 +294,43 @@ class TestFusedKernel:
             PD.pallas_fused_dp_tables(local, tx[:, :3])
         with pytest.raises(ValueError, match="bank_idx must be"):
             PD.pallas_fused_optimal_dp(local, np.zeros((4, 2), dtype=int), tx)
+
+
+def _count_fused_launches(monkeypatch):
+    """Wrap ``PD._fused_launch``: the returned list gets each launch's
+    host ``tables`` (the same object for every launch of one call)."""
+    launches = []
+    launch = PD._fused_launch
+
+    def counted(*args):
+        launches.append(args[-1])
+        return launch(*args)
+
+    monkeypatch.setattr(PD, "_fused_launch", counted)
+    return launches
+
+
+def _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, combine, got,
+                                tables):
+    """Each stack with dead slots at bank row 0, solved alone on its own
+    scenarios, gives the same raw tables and results, bit for bit."""
+    N = bank_idx.shape[1]
+    canon = np.where(np.arange(N)[None, :] >= ns[:, None], 0, bank_idx)
+    stacks, inv = np.unique(canon, axis=0, return_inverse=True)
+    inv = inv.reshape(-1)
+    for u, stack in enumerate(stacks):
+        sel = np.flatnonzero(inv == u)
+        dp_per_k, parents = PD.pallas_fused_dp_tables(
+            bank[stack], tx[sel], combine, ns=ns[sel])
+        assert np.array_equal(tables[0][sel], dp_per_k[0]), u
+        for k in range(N - 1):
+            assert np.array_equal(tables[1][sel, k], dp_per_k[k + 1]), u
+        assert np.array_equal(tables[2][sel], parents), u
+        ref = PD.pallas_fused_optimal_dp(bank[stack], None, tx[sel],
+                                         combine=combine, n_devices=ns[sel])
+        assert np.array_equal(got.splits[sel], ref.splits), u
+        assert np.array_equal(got.cost_s[sel], ref.cost_s), u
+        assert np.array_equal(got.feasible[sel], ref.feasible), u
 
 
 # ---------------------------------------------------------------------------

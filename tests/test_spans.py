@@ -92,9 +92,12 @@ def r50_grid(losses=(None, 0.05, 0.1)) -> ScenarioGrid:
                         loss_p=losses, devices=(ESP32,))
 
 
-@pytest.mark.parametrize("backend,launches", [("pallas", 4), ("jax", 1),
-                                              ("numpy", 0)])
-def test_sweep_span_tree(tmp_path, backend, launches):
+# stacks: the DP's device stacks with dead slots read as bank row 0, which
+# the pallas backend joins into one live stack; jax solves one dense C
+@pytest.mark.parametrize("backend,stacks", [("pallas", 4), ("jax", 1),
+                                            ("numpy", 0)])
+def test_sweep_span_tree(tmp_path, backend, stacks):
+    launches = 1 if backend == "pallas" else stacks
     grid = r50_grid()
     sweep(grid, backend=backend)  # compile outside the trace
     res, spans = traced(tmp_path, lambda: sweep(grid, backend=backend))
@@ -114,8 +117,11 @@ def test_sweep_span_tree(tmp_path, backend, launches):
     assert len(gathers) == (backend != "pallas")
     assert all(sp.stats["budgeted"] == sp.stats["masked"] == 0
                for sp in gathers)
-    # one launch per distinct device stack on pallas, one on jax
+    # one launch for the one joined device stack on pallas, one on jax
     assert len(named(spans, "repro.dp.launch")) == launches
+    if backend == "pallas":
+        (dp,) = named(spans, "repro.dp")
+        assert dp.stats == {"stacks": stacks, "launches": launches}
     for name in ("repro.dp.prepare", "repro.dp.fetch"):
         assert len(named(spans, name)) == launches
     assert named(spans, "repro.dp.reconstruct")
@@ -179,8 +185,8 @@ def _scan_launch(rows):
 
 
 @pytest.mark.parametrize("backend,launches", [
-    # one launch per fleet size, 12 rows each; C is never built
-    ("pallas", [_pallas_launch(12)] * 4),
+    # one launch for the one stack, 48 rows; C is never built
+    ("pallas", [_pallas_launch(48)]),
     # one launch over the C (S, N, L, L) gathered on the host
     ("jax", [_scan_launch(48)]),
 ])
