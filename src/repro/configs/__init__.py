@@ -27,6 +27,7 @@ _MODULES = {
     "qwen2-vl-72b": "qwen2_vl_72b",
     "xlstm-1.3b": "xlstm_1p3b",
     "deepseek-v3": "deepseek_v3",
+    "gigachat3.5-432b-a28b": "gigachat35_432b_a28b",
 }
 
 ARCH_IDS = list(_MODULES)

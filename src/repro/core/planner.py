@@ -373,15 +373,18 @@ def tpu_cost_profile(
     act_dtype_bytes: int = 2,
     param_dtype_bytes: int = 2,
     chips_per_stage: int = 1,
+    state_dtype_bytes: int = 4,
 ) -> ModelCostProfile:
     """Analytic per-layer TPU times: max(compute, memory) roofline terms.
 
     ``bytes_moved`` per layer is the parameters a step reads
-    (``LayerNode.params_read``), activations in+out and the cache a step
-    reads (training adds backward traffic uniformly — a constant factor
-    that does not move split decisions). ``param_bytes`` is what the
-    layer holds, weights plus cache (the cache in the activation dtype),
-    so a segment's memory check sums both."""
+    (``LayerNode.params_read``), activations in+out, the cache a step
+    reads and the recurrent state it reads and writes (training adds
+    backward traffic uniformly — a constant factor that does not move
+    split decisions). ``param_bytes`` is what the layer holds: weights,
+    cache (in the activation dtype) and recurrent state (in
+    ``state_dtype_bytes``), so a segment's memory check sums all
+    three."""
     from repro.core.latency import LayerCost
 
     layers = []
@@ -389,6 +392,7 @@ def tpu_cost_profile(
         bytes_moved = (
             n.params_read * param_dtype_bytes + n.work_elems * act_dtype_bytes
             + n.cache_read_elems * act_dtype_bytes
+            + n.state_rw_elems * state_dtype_bytes
         )
         layers.append(
             LayerCost(
@@ -396,7 +400,8 @@ def tpu_cost_profile(
                 t_infer_s=tpu_layer_time_s(n.flops, bytes_moved, chips_per_stage),
                 act_bytes=n.out_elems * act_dtype_bytes,
                 param_bytes=(n.param_count * param_dtype_bytes
-                             + n.cache_elems * act_dtype_bytes),
+                             + n.cache_elems * act_dtype_bytes
+                             + n.state_elems * state_dtype_bytes),
                 work_bytes=n.work_elems * act_dtype_bytes,
                 flops=n.flops,
             )
@@ -456,16 +461,24 @@ def pipeline_grid(
     token per sequence against ``seq_len`` cached positions, any other a
     forward pass over ``seq_len`` tokens. Each shape becomes one
     ``models`` entry (its :func:`tpu_cost_profile` at one chip, keyed by
-    ``shape.name``), each chip-group size ``c`` one device mix ``"x<c>"``
+    ``shape.name``; a linear-attention layer's recurrent state priced in
+    ``cfg.linear_state_dtype``), each chip-group size ``c`` one device mix ``"x<c>"``
     of :func:`~repro.core.profiles.tpu_stage_device` ``(c)`` (``c``
     chips divide the layer times; ``0.9`` of their HBM bounds a stage),
     ``stages`` the fleet sizes, ``links`` the interconnects. The
     objective is the bottleneck stage time:
     ``sweep(grid, backend="pallas")`` plans it on the device and
-    :func:`~repro.core.sweep.sweep_scalar` is its oracle."""
+    :func:`~repro.core.sweep.sweep_scalar` is its oracle.
+
+    Spans: ``repro.plan.pipeline`` (``shapes``, ``mixes``, ``layers``)
+    and per shape ``repro.plan.pipeline.profile`` (``experts_touched``,
+    ``layers``, and what that shape's graph holds: ``linear_layers``,
+    ``state_bytes``, ``cache_bytes``)."""
     from repro.models.graph import arch_layer_graph, experts_touched
 
     mixes = {f"x{c}": (tpu_stage_device(c),) for c in chips_per_stage}
+    act_b = 2  # bf16 activations and cache
+    state_b = np.dtype(cfg.linear_state_dtype).itemsize
     models = {}
     with span("plan.pipeline", shapes=len(shapes), mixes=len(mixes)) as sp:
         for shape in shapes:
@@ -477,8 +490,13 @@ def pipeline_grid(
             with span("plan.pipeline.profile", experts_touched=touched) as pp:
                 graph = arch_layer_graph(
                     cfg, batch, seq, kv_len=shape.seq_len if decode else None)
-                models[shape.name] = tpu_cost_profile(graph)
-                pp.set_metadata(layers=graph.num_layers)
+                models[shape.name] = tpu_cost_profile(
+                    graph, act_dtype_bytes=act_b, state_dtype_bytes=state_b)
+                pp.set_metadata(
+                    layers=graph.num_layers,
+                    linear_layers=sum(n.state_elems > 0 for n in graph.nodes),
+                    state_bytes=sum(n.state_elems for n in graph.nodes) * state_b,
+                    cache_bytes=sum(n.cache_elems for n in graph.nodes) * act_b)
         sp.set_metadata(layers=max((m.num_layers for m in models.values()),
                                    default=0))
         return SW.ScenarioGrid(

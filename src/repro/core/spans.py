@@ -53,7 +53,12 @@ SPANS = {
                            "mixes",
     "repro.plan.pipeline.profile": "one shape's layer graph and TPU cost "
                                    "profile; counts layers, "
-                                   "experts_touched (a MoE layer, a step)",
+                                   "experts_touched (a MoE layer, a step), "
+                                   "linear_layers (nodes holding a "
+                                   "recurrent state), state_bytes (that "
+                                   "state, in its dtype) and cache_bytes "
+                                   "(the KV or latent cache, bf16) the "
+                                   "shape's graph holds",
     "repro.rebuild": "one in-process surface rebuild on the executor; "
                      "counts queued_ms (first queued request to build start)",
 }

@@ -34,6 +34,7 @@ class ModelConfig:
     moe_d_ff: int = 0  # routed/shared expert width; 0 -> d_ff
     n_shared_experts: int = 0  # experts every token passes through
     n_mtp_modules: int = 0  # multi-token-prediction modules after the head
+    mtp_dense: bool = False  # MTP blocks take the dense FFN (nextn_is_sparse false)
 
     # --- MLA (MiniCPM3 / DeepSeek-V2-style latent attention) ---------------
     use_mla: bool = False
@@ -42,6 +43,17 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    attn_output_gate: bool = False  # sigmoid(x W_G) gates each head's output
+
+    # --- Gated DeltaNet linear attention beside MLA (GigaChat3.5) ------------
+    # indices of the decoder layers that are Gated DeltaNet; the rest are MLA
+    linear_attn_layers: tuple[int, ...] = ()
+    linear_n_k_heads: int = 0
+    linear_n_v_heads: int = 0
+    linear_k_head_dim: int = 0
+    linear_v_head_dim: int = 0
+    linear_conv_kernel: int = 0  # depthwise causal conv over q, k and v
+    linear_state_dtype: str = "float32"  # the held recurrent and conv state
 
     # --- position encoding --------------------------------------------------
     rope_theta: float = 10_000.0
@@ -49,6 +61,7 @@ class ModelConfig:
 
     # --- residual / block style ---------------------------------------------
     parallel_residual: bool = False  # stablelm-2: attn and mlp share the residual
+    pre_post_norm: bool = False  # a norm before and after each sublayer (4 a block)
     gated_mlp: bool = True  # SwiGLU (False -> GELU MLP, e.g. granite-34b)
     tie_embeddings: bool = False
 
@@ -105,6 +118,15 @@ class ModelConfig:
             assert len(self.block_pattern) == self.n_layers, (
                 f"block_pattern len {len(self.block_pattern)} != n_layers {self.n_layers}"
             )
+        if self.linear_attn_layers:
+            if not all(0 <= i < self.n_layers for i in self.linear_attn_layers):
+                raise ValueError(f"{self.name}: linear_attn_layers "
+                                 f"{self.linear_attn_layers} outside 0..{self.n_layers - 1}")
+            if not (self.linear_n_k_heads and self.linear_n_v_heads
+                    and self.linear_k_head_dim and self.linear_v_head_dim
+                    and self.linear_conv_kernel):
+                raise ValueError(f"{self.name}: linear_attn_layers need the "
+                                 f"Gated DeltaNet heads, head dims and conv kernel")
 
     @property
     def is_moe(self) -> bool:
@@ -113,6 +135,37 @@ class ModelConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    def is_linear_layer(self, i: int) -> bool:
+        """Whether decoder layer ``i`` is Gated DeltaNet (else attention)."""
+        return i in self.linear_attn_layers
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels of a Gated DeltaNet layer's conv: q, k and v."""
+        return (2 * self.linear_n_k_heads * self.linear_k_head_dim
+                + self.linear_n_v_heads * self.linear_v_head_dim)
+
+    @property
+    def linear_state_elems(self) -> int:
+        """A Gated DeltaNet layer's held state for one sequence: the
+        ``d_k x d_v`` delta-rule state of every value head, plus the last
+        ``conv_kernel - 1`` inputs of the conv over q, k and v."""
+        hv = self.linear_n_v_heads
+        return (hv * self.linear_k_head_dim * self.linear_v_head_dim
+                + (self.linear_conv_kernel - 1) * self.linear_conv_dim)
+
+    @property
+    def linear_attn_params(self) -> int:
+        """One Gated DeltaNet mixer's weights (arXiv:2412.06464): the input
+        projection to q, k, v, the output gate z and the per-value-head
+        beta and alpha; the depthwise conv over q, k and v; ``A_log`` and
+        ``dt_bias``; the gated output norm (one ``d_v`` weight shared by
+        the heads); the output projection."""
+        d, hv, dv = self.d_model, self.linear_n_v_heads, self.linear_v_head_dim
+        return (d * (self.linear_conv_dim + hv * dv + 2 * hv)
+                + self.linear_conv_dim * self.linear_conv_kernel + 2 * hv + dv
+                + hv * dv * d)
 
     def is_moe_layer(self, i: int) -> bool:
         """Whether decoder layer ``i`` has an expert FFN (the first
@@ -143,7 +196,9 @@ class ModelConfig:
         total = self.vocab * d * (1 if self.tie_embeddings else 2)
         for i, kind in enumerate(self.pattern):
             if kind in ("attn",):
-                if self.use_mla:
+                if self.is_linear_layer(i):
+                    attn = self.linear_attn_params
+                elif self.use_mla:
                     q = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * (
                         self.qk_nope_head_dim + self.qk_rope_head_dim
                     )
@@ -153,6 +208,8 @@ class ModelConfig:
                     )
                     o = self.n_heads * self.v_head_dim * d
                     attn = q + kv + o
+                    if self.attn_output_gate:
+                        attn += d * self.n_heads * self.v_head_dim
                 else:
                     attn = (self.n_heads + 2 * self.n_kv_heads) * hd * d
                     attn += self.n_heads * hd * d
@@ -163,7 +220,7 @@ class ModelConfig:
                     ff += d * self.n_experts
                 else:
                     ff = (3 if self.gated_mlp else 2) * d * self.d_ff
-                total += attn + ff + 2 * d
+                total += attn + ff + (4 if self.pre_post_norm else 2) * d
             elif kind == "mamba":
                 di = self.d_inner
                 total += d * 2 * di + di * self.d_conv + 2 * di * self.ssm_state + di * d + 2 * d
@@ -203,6 +260,12 @@ class ModelConfig:
                          qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm_state:
             small.update(ssm_state=16, ssm_head_dim=16)
+        if self.linear_attn_layers:
+            # the JAX model runs attention layers only: the small variant
+            # keeps Gated DeltaNet widths to match but none of its layers
+            small.update(linear_attn_layers=(), linear_n_k_heads=2,
+                         linear_n_v_heads=4, linear_k_head_dim=16,
+                         linear_v_head_dim=16)
         if self.block_pattern is not None:
             small.update(block_pattern=self._reduced_pattern())
         if self.mrope_sections is not None:

@@ -41,6 +41,8 @@ class LayerNode:
     streamed_params: float | None = None  # parameters read a step
     cache_elems: int = 0  # resident cache elements
     cache_read_elems: int = 0  # cache elements read a step
+    state_elems: int = 0  # resident recurrent state elements (fixed in kv_len)
+    state_rw_elems: int = 0  # state elements read and written a step
 
     @property
     def params_read(self) -> float:
@@ -346,9 +348,11 @@ def arch_layer_graph(cfg, batch: int, seq: int, kv_len: int | None = None,
     """LayerGraph for any assigned :class:`ModelConfig` — walks the block
     pattern with per-kind FLOP/param/activation formulas. Used by the
     analytic roofline terms and by :func:`plan_pipeline` on real archs.
-    A config with a dense prefix, shared experts or MTP modules takes
-    the DeepSeek-V3 layout (:func:`deepseek_layer_graph`)."""
-    if cfg.first_k_dense or cfg.n_shared_experts or cfg.n_mtp_modules:
+    A config with a dense prefix, shared experts, MTP modules or Gated
+    DeltaNet layers takes the DeepSeek-V3 layout
+    (:func:`deepseek_layer_graph`)."""
+    if (cfg.first_k_dense or cfg.n_shared_experts or cfg.n_mtp_modules
+            or cfg.linear_attn_layers):
         return deepseek_layer_graph(cfg, batch, seq, kv_len)
     d = cfg.d_model
     nodes: list[LayerNode] = []
@@ -460,16 +464,66 @@ def _mla_flops(cfg, batch: int, seq: int, kv: int) -> float:
             + 2.0 * T * H * kr * dv + 2.0 * T * H * dv * d)  # W_UV, W_O
 
 
+# Gated DeltaNet's chunk length in the chunkwise (prefill) form
+GDN_CHUNK = 64
+
+
+def _gdn_flops(cfg, batch: int, seq: int) -> float:
+    """One Gated DeltaNet mixer (arXiv:2412.06464) over ``batch x seq``
+    tokens: the input projection (q, k, v, z, beta, alpha), the depthwise
+    conv over q, k and v, the delta rule and the output projection.
+
+    A one-token step runs the recurrent form: per token and value head,
+    the retrieval ``S^T k``, the rank-1 update and the readout ``S q``
+    (three ``d_k x d_v`` products) and the decay of ``S`` (one). Longer
+    steps run the chunkwise form (arXiv:2406.06484 section 3) over
+    ``ceil(seq / GDN_CHUNK)`` chunks a sequence, each per value head:
+    ``A = beta K K^T`` (strictly lower), W and U by forward substitution
+    through ``I + A`` (half a square product each), the masked ``Q K^T``
+    and its product with ``U - W S``, the three chunk-state products
+    ``W S``, ``Q S`` and ``K^T (U - W S)``, and the chunk's decay of
+    ``S``. Element-wise gates and norms are not counted."""
+    d, T = cfg.d_model, batch * seq
+    hv, dk, dv = cfg.linear_n_v_heads, cfg.linear_k_head_dim, cfg.linear_v_head_dim
+    conv = cfg.linear_conv_dim  # q, k, v
+    flops = (2.0 * T * d * (conv + hv * dv + 2 * hv)  # in_proj: + z, beta, alpha
+             + 2.0 * T * conv * cfg.linear_conv_kernel  # depthwise conv
+             + 2.0 * T * hv * dv * d)  # out_proj
+    if seq == 1:
+        return flops + 7.0 * T * hv * dk * dv
+    C = GDN_CHUNK
+    chunk = (2.0 * C * C * dk + 1.0 * C * C * (dk + dv)
+             + 2.0 * C * C * dk + 2.0 * C * C * dv
+             + 6.0 * C * dk * dv + 1.0 * dk * dv)
+    return flops + batch * math.ceil(seq / C) * hv * chunk
+
+
 def _deepseek_block(cfg, i: int | None, batch: int, seq: int, kv: int):
-    """(flops, resident, streamed, cache) of one decoder block: MLA plus
-    a dense SwiGLU (layer ``i`` < ``first_k_dense``) or the routed
-    experts, the shared experts and the router (``i`` None: the MTP
-    module's block, which is MoE)."""
+    """(flops, resident, streamed, cache, state) of one decoder block:
+    MLA (with its output gate where the config has one) or, for a layer
+    ``i`` in ``linear_attn_layers``, Gated DeltaNet; then a dense SwiGLU
+    (layer ``i`` < ``first_k_dense``) or the routed experts, the shared
+    experts and the router. ``i`` None is an MTP module's block: MLA,
+    and MoE unless ``mtp_dense``. ``cache`` is the MLA latent cache,
+    ``state`` the Gated DeltaNet state (fixed in ``kv``)."""
     d, T = cfg.d_model, batch * seq
     mats = 3 if cfg.gated_mlp else 2
-    flops = _mla_flops(cfg, batch, seq, kv)
-    attn = _mla_params(cfg) + 2 * d  # + attention and FFN norms
-    if i is not None and not cfg.is_moe_layer(i):
+    norms = (4 if cfg.pre_post_norm else 2) * d  # attention and FFN norms
+    cache = state = 0
+    if i is not None and cfg.is_linear_layer(i):
+        flops = _gdn_flops(cfg, batch, seq)
+        attn = cfg.linear_attn_params + norms
+        state = batch * cfg.linear_state_elems
+    else:
+        flops = _mla_flops(cfg, batch, seq, kv)
+        attn = _mla_params(cfg) + norms
+        if cfg.attn_output_gate:  # sigmoid(x W_G) on every head's output
+            gate = d * cfg.n_heads * cfg.v_head_dim
+            flops += 2.0 * T * gate
+            attn += gate
+        cache = batch * kv * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+    dense = cfg.mtp_dense if i is None else not cfg.is_moe_layer(i)
+    if dense:
         ffn = mats * d * cfg.d_ff
         flops += 2.0 * T * ffn
         params, streamed = attn + ffn, float(attn + ffn)
@@ -482,8 +536,7 @@ def _deepseek_block(cfg, i: int | None, batch: int, seq: int, kv: int):
         params = attn + E * expert + Es * expert + router
         streamed = (attn + experts_touched(E, cfg.top_k, T) * expert
                     + Es * expert + router)
-    cache = batch * kv * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-    return flops, params, streamed, cache
+    return flops, params, streamed, cache, state
 
 
 def deepseek_layer_graph(cfg, batch: int, seq: int,
@@ -491,22 +544,38 @@ def deepseek_layer_graph(cfg, batch: int, seq: int,
     """DeepSeek-V3's layout as pipeline-stage candidates (arXiv:2412.19437):
     ``embed``, ``layer_0`` ... ``layer_{n-1}``, ``head``.
 
-    Layers below ``first_k_dense`` are MLA plus a dense SwiGLU of
-    ``d_ff``; the rest are MLA plus ``n_experts`` routed experts of
-    ``moe_d_ff`` (``top_k`` a token), ``n_shared_experts`` shared ones
-    and the router. ``head`` holds the final norm, the output head and
-    the MTP modules (each: two norms, the ``2d -> d`` projection, one
-    MLA + MoE block, the shared head's norm and the shared head applied
+    Layers below ``first_k_dense`` have a dense SwiGLU of ``d_ff``; the
+    rest have ``n_experts`` routed experts of ``moe_d_ff`` (``top_k`` a
+    token), ``n_shared_experts`` shared ones and the router. Their mixer
+    is MLA, or Gated DeltaNet for the layers in ``linear_attn_layers``
+    (GigaChat3.5's hybrid). ``head`` holds the final norm, the output
+    head and the MTP modules (each: two norms, the ``2d -> d``
+    projection, one MLA block with the MoE FFN, or the dense one where
+    ``mtp_dense``, the shared head's norm and the shared head applied
     again); MTP sits on ``head`` because it reads both the last hidden
     state and the next token's embedding, so a cut between them would
     ship two tensors. The embedding copy MTP reads is resident there.
 
-    A step of ``batch x seq`` tokens (``kv_len`` cached positions at
-    decode; ``seq`` at prefill) reads the experts it touches
-    (:func:`experts_touched`, uniform routing), the embedding rows it
-    looks up, and every other weight once; the output head is read
-    once per application. Each MLA block holds and reads a latent cache
-    of ``kv_lora_rank + qk_rope_head_dim`` a token.
+    Per block, with ``T = batch x seq``:
+
+    * MLA (:func:`_mla_flops`, :func:`_mla_params`): projections, latent
+      scores and sums over every (query, key) pair; with
+      ``attn_output_gate`` a ``d -> heads x v_head_dim`` gate adds
+      ``2 T d heads v_head_dim`` FLOPs and its weights. It holds and
+      reads a latent cache of ``kv_lora_rank + qk_rope_head_dim`` a token
+      a sequence, in the activation dtype.
+    * Gated DeltaNet (:func:`_gdn_flops`; weights
+      ``ModelConfig.linear_attn_params``): it holds
+      ``ModelConfig.linear_state_elems`` a sequence (the delta-rule state
+      and the conv's last inputs), whatever ``kv_len``, in
+      ``linear_state_dtype``. A step that continues a sequence
+      (``kv_len`` given) reads and writes it; a prefill writes it once.
+    * Norms: two a block, four with ``pre_post_norm``.
+
+    A step (``kv_len`` cached positions at decode; ``seq`` at prefill)
+    reads the experts it touches (:func:`experts_touched`, uniform
+    routing), the embedding rows it looks up, and every other weight
+    once; the output head is read once per application.
 
     Only MLA attention is priced here: a config without ``use_mla`` is
     refused rather than given attention with no weights or cache."""
@@ -517,23 +586,25 @@ def deepseek_layer_graph(cfg, batch: int, seq: int,
     d, V = cfg.d_model, cfg.vocab
     T = batch * seq
     kv = seq if kv_len is None else kv_len
+    rw = 1 if kv_len is None else 2  # state writes, plus reads at decode
     act = T * d
     rows = experts_touched(V, 1, T) * d  # embedding rows a lookup reads
     nodes = [LayerNode("embed", flops=0.0, param_count=V * d, out_elems=act,
                        work_elems=2 * act, streamed_params=rows)]
     for i in range(cfg.n_layers):
-        f, p, s, c = _deepseek_block(cfg, i, batch, seq, kv)
+        f, p, s, c, st = _deepseek_block(cfg, i, batch, seq, kv)
         nodes.append(LayerNode(f"layer_{i}", flops=f, param_count=p,
                                out_elems=act, work_elems=2 * act,
                                streamed_params=s, cache_elems=c,
-                               cache_read_elems=c))
+                               cache_read_elems=c, state_elems=st,
+                               state_rw_elems=rw * st))
     head_w = V * d
     flops = 2.0 * T * d * V
     params = d + head_w
     streamed = float(d + head_w)
     cache = 0
     for _ in range(cfg.n_mtp_modules):
-        f, p, s, c = _deepseek_block(cfg, None, batch, seq, kv)
+        f, p, s, c, _ = _deepseek_block(cfg, None, batch, seq, kv)
         own = 2 * d + 2 * d * d + d  # enorm, hnorm, eh_proj, head norm
         flops += 2.0 * T * 2 * d * d + f + 2.0 * T * d * V
         params += own + p + V * d  # + the embedding copy

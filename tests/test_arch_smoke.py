@@ -113,6 +113,7 @@ class TestArchSmoke:
             "qwen2-vl-72b": (80, 8192, 64, 8, 29568, 152064),
             "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
             "deepseek-v3": (61, 7168, 128, 128, 18432, 129280),
+            "gigachat3.5-432b-a28b": (40, 7168, 64, 64, 18432, 128256),
         }[arch_id]
         got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                cfg.d_ff, cfg.vocab)

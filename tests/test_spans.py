@@ -295,7 +295,34 @@ def test_pipeline_grid_spans_carry_their_counts(tmp_path):
     assert outer.stats == {"shapes": 2, "mixes": 3, "layers": 63}
     inner = named(spans, "repro.plan.pipeline.profile")
     assert [parent(sp, spans) for sp in inner] == [outer, outer]
+    # 61 MLA blocks and the MTP block hold bf16 latent caches; no state
+    latent = 62 * 576 * 2
     assert [sp.stats for sp in inner] == [
-        {"experts_touched": experts_touched(256, 8, 2048), "layers": 63},
-        {"experts_touched": experts_touched(256, 8, 8), "layers": 63}]
+        {"experts_touched": experts_touched(256, 8, 2048), "layers": 63,
+         "linear_layers": 0, "state_bytes": 0, "cache_bytes": latent * 2048},
+        {"experts_touched": experts_touched(256, 8, 8), "layers": 63,
+         "linear_layers": 0, "state_bytes": 0, "cache_bytes": latent * 8 * 4096}]
     assert grid.size == 2 * 3 * 15 * 2
+
+
+def test_pipeline_profile_counts_held_state_and_cache(tmp_path):
+    """GigaChat3.5: 30 Gated DeltaNet layers hold a float32 state fixed in
+    the KV length, 10 MLA layers and 2 MTP blocks a bf16 latent cache."""
+    from repro.configs import get_config
+    from repro.configs.shapes import ShapeSpec
+    from repro.core.planner import pipeline_grid
+
+    shapes = (ShapeSpec("prefill", "prefill", 4096, 2),
+              ShapeSpec("decode", "decode", 262_144, 8))
+    cfg = get_config("gigachat3.5-432b-a28b")
+    _, spans = traced(tmp_path, lambda: pipeline_grid(cfg, shapes, (8,), (2, 4)))
+    assert {sp.name for sp in spans} <= set(SPANS)
+    inner = named(spans, "repro.plan.pipeline.profile")
+    state = 64 * 128 * 128 + 3 * (2 * 32 * 128 + 64 * 128)  # a sequence
+    got = [{k: sp.stats[k] for k in ("layers", "linear_layers", "state_bytes",
+                                     "cache_bytes")} for sp in inner]
+    assert got == [
+        {"layers": 42, "linear_layers": 30, "state_bytes": 30 * 2 * state * 4,
+         "cache_bytes": 12 * 2 * 4096 * 576 * 2},
+        {"layers": 42, "linear_layers": 30, "state_bytes": 30 * 8 * state * 4,
+         "cache_bytes": 12 * 8 * 262_144 * 576 * 2}]
