@@ -59,7 +59,10 @@ claim.
 Entry points up the stack: ``batched_optimal_dp(backend="pallas")``
 (dense), and ``sweep(grid, backend="pallas")`` and
 ``build_surfaces(..., backend="pallas")`` (fused, via
-:func:`pallas_fused_optimal_dp`).
+:func:`pallas_fused_optimal_dp`). On the bank path without all-k (the
+one ``sweep`` takes) the fused kernel's program also selects each
+scenario's cost and walks its split points (``solve_fused_walk``), so
+only those leave the device; every other path fetches the tables.
 
 Precision follows the active JAX config like every JAX-side backend:
 float32 by default, float64 when ``jax.config.jax_enable_x64`` is on.
@@ -292,6 +295,72 @@ def _pallas_dp_solver(mode: str, combine: str, block_s: int,
     return jax.jit(solve)
 
 
+def _pick(x, idx):
+    """``x[s, idx[s]]`` of an ``(S, W)`` array: a minimum over each row
+    with every other entry masked to the dtype's largest value, which
+    returns the picked entry bit for bit and vectorises on the chip as a
+    gather does not."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    hit = lax.broadcasted_iota(jnp.int32, x.shape, 1) == idx[:, None]
+    fill = jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) \
+        else jnp.iinfo(x.dtype).max
+    return jnp.min(jnp.where(hit, x, fill), axis=1)
+
+
+def _walk_tables(dp0, dps, args, ns, L: int):
+    """Each scenario's cost and split points from the fused kernel's
+    padded tables, on the device: the jnp twin of
+    :func:`repro.core.sweep._results_from_dp_tables` with per-scenario
+    fleet sizes ``ns`` ``(Sp,)``.
+
+    The cost is table ``ns[s]``'s entry at boundary ``L`` (``dp0`` for
+    one device), in the kernel dtype. The walk follows
+    :func:`repro.core.sweep._reconstruct_splits`' rules over the same
+    ``args``: from boundary ``L`` down through ``k = N..2``, the parent
+    at ``clip(b - 1, 0, L - 1)``, -1 where the cost is not finite,
+    written only where ``ns[s] >= k`` (else -1), and ``b`` moved only on
+    those rows. Returns ``cost`` ``(Sp,)`` and ``splits`` ``(Sp, N-1)``
+    int32."""
+    import jax.numpy as jnp
+
+    N = dps.shape[1] + 1
+    final = jnp.concatenate([dp0[:, None, L - 1], dps[:, :, L - 1]], axis=1)
+    cost = _pick(final, ns - 1)
+    feas = jnp.isfinite(cost)
+    b = jnp.full_like(ns, L)
+    cols = []
+    for k in range(N, 1, -1):  # unrolled: N is small and static
+        a = jnp.where(feas, _pick(args[:, k - 2], jnp.clip(b - 1, 0, L - 1)),
+                      -1)
+        act = ns >= k
+        cols.append(jnp.where(act, a, -1))
+        b = jnp.where(act, jnp.clip(jnp.where(feas, a, 1), 1, L), b)
+    return cost, jnp.stack(cols[::-1], axis=1)
+
+
+@functools.lru_cache(maxsize=None)
+def _pallas_walk_solver(combine: str, block_s: int, interpret: bool, L: int):
+    """Jitted fused kernel followed by :func:`_walk_tables`, cached per
+    configuration and unpadded layer count ``L``, named
+    ``solve_fused_walk`` (the trace prints ``jit_solve_fused_walk``).
+
+    One program: the tables stay on the device, and a launch returns
+    each scenario's cost and splits, ``Sp * 4 * N`` bytes where the
+    tables are ``Sp * 4 * (2N - 1) * Lp``."""
+    import jax
+
+    fn = _raw_pallas_fn("fused", combine, block_s, interpret)
+
+    def solve_fused_walk(localp, txp, nsp):
+        global _PALLAS_TRACE_COUNT
+        _PALLAS_TRACE_COUNT += 1  # Python side effect: runs at trace only
+        return _walk_tables(*fn(localp, txp, nsp), nsp[:, 0], L)
+
+    return jax.jit(solve_fused_walk)
+
+
 def _resolve_opts(block_s: int | None, interpret: bool | None):
     bs = DEFAULT_BLOCK_S if block_s is None else int(block_s)
     if bs < 1:
@@ -377,10 +446,13 @@ def _empty_tables(Sn: int, N: int, L: int, dtype):
             np.empty((Sn, N - 1, L), dtype=np.int32))
 
 
-def _fused_launch(local, tx, ns_arr, sel, combine, bs, itp, dtype, tables):
+def _fused_launch(local, tx, ns_arr, sel, kernel, solver, bs, dtype, into):
     """One fused kernel launch over the scenarios ``sel`` of ``tx`` /
-    ``ns_arr`` on the shared stack ``local``; its unpadded tables land
-    in rows ``sel`` of ``tables``. N >= 2, at least one scenario."""
+    ``ns_arr`` on the shared stack ``local``, through the jitted
+    ``solver`` named ``kernel``: ``solve_fused`` scatters its unpadded
+    tables into rows ``sel`` of ``into`` (:func:`_empty_tables`),
+    ``solve_fused_walk`` its costs and splits. N >= 2, at least one
+    scenario."""
     N, L, _ = local.shape
     import jax.numpy as jnp
 
@@ -398,9 +470,8 @@ def _fused_launch(local, tx, ns_arr, sel, combine, bs, itp, dtype, tables):
         return (jnp.asarray(localp, dtype=dtype), jnp.asarray(txp, dtype=dtype),
                 jnp.asarray(_pad_ns_column(ns_sel, Sn, Sp)))
 
-    SW._dp_launch("solve_fused", _pallas_dp_solver("fused", combine, bs, itp),
-                  operands, rows=Sn, rows_padded=Sp, lanes=L, lanes_padded=Lp,
-                  into=(tables, sel))
+    SW._dp_launch(kernel, solver, operands, rows=Sn, rows_padded=Sp,
+                  lanes=L, lanes_padded=Lp, into=(into, sel))
 
 
 def _fused_dp0_host(local, tx, dtype):
@@ -485,7 +556,8 @@ def pallas_fused_dp_tables(
                                Sn, N, L, dtype)
     bs, itp = _resolve_opts(block_s, interpret)
     tables = _empty_tables(Sn, N, L, dtype)
-    _fused_launch(local, tx, ns_arr, slice(None), combine, bs, itp, dtype,
+    _fused_launch(local, tx, ns_arr, slice(None), "solve_fused",
+                  _pallas_dp_solver("fused", combine, bs, itp), bs, dtype,
                   tables)
     return SW._dp_tables_to_numpy(*tables, Sn, N, L)
 
@@ -549,11 +621,14 @@ def pallas_fused_optimal_dp(
     a scenario's own ``n_devices`` are dead (the kernel never reads
     them), so a scenario joins any stack that extends its live slots
     (:func:`_join_live_stacks`) and one mix takes one stack over all its
-    fleet sizes. Each subgroup runs one fused kernel pass and the tables
-    scatter back into grid order. The bank is small by construction —
-    distinct live stacks, not scenarios, bound the subgroup count. The
-    ``repro.dp`` span counts ``stacks`` (distinct stacks with dead slots
-    read as row 0) and ``launches``.
+    fleet sizes. Each subgroup runs one fused kernel pass. Without
+    ``return_all_k`` the same program picks each scenario's cost and
+    walks its splits on the device (:func:`_walk_tables`), and only those
+    scatter back into grid order; with it, the tables do. The bank is
+    small by construction — distinct live stacks, not scenarios, bound
+    the subgroup count. The ``repro.dp`` span counts ``stacks``
+    (distinct stacks with dead slots read as row 0), ``launches`` and,
+    on the walked path, ``walked`` (the scenarios).
 
     Bottleneck variants need NO kernel change: a variant reprices only
     the cut (compressed airtime + encoder time), both functions of the
@@ -611,11 +686,29 @@ def pallas_fused_optimal_dp(
         bs, itp = _resolve_opts(block_s, interpret)
         stacks, launch_of, n_stacks = _join_live_stacks(bank_idx, ns_arr)
         sp.set_metadata(stacks=n_stacks, launches=len(stacks))
-        tables = _empty_tables(Sn, N, L, dtype)
+        if return_all_k:  # the caller reads every table: fetch them
+            kernel, solver = "solve_fused", _pallas_dp_solver(
+                "fused", combine, bs, itp)
+            host = _empty_tables(Sn, N, L, dtype)
+        else:
+            sp.set_metadata(walked=Sn)
+            kernel, solver = "solve_fused_walk", _pallas_walk_solver(
+                combine, bs, itp, L)
+            host = (np.empty(Sn, dtype=dtype),
+                    np.empty((Sn, N - 1), dtype=np.int32))
         for u in range(len(stacks)):
             _fused_launch(bank[stacks[u]], tx, ns_arr,
                           np.flatnonzero(launch_of == u),
-                          combine, bs, itp, dtype, tables)
-        dp_per_k, parents = SW._dp_tables_to_numpy(*tables, Sn, N, L)
-        return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
-                                          "pallas", ns, return_all_k, t0)
+                          kernel, solver, bs, dtype, host)
+        if return_all_k:
+            dp_per_k, parents = SW._dp_tables_to_numpy(*host, Sn, N, L)
+            return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn,
+                                              "pallas", ns, True, t0)
+        with span("dp.reconstruct"):
+            cost = host[0].astype(np.float64)
+            splits = host[1].astype(np.int64)
+            feasible = np.isfinite(cost)
+            return SW.BatchedSolverResult(
+                solver="batched_dp", backend="pallas", n_devices=N,
+                splits=splits, cost_s=cost, feasible=feasible,
+                wall_time_s=time.perf_counter() - t0, n_devices_s=ns)

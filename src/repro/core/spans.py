@@ -39,15 +39,20 @@ SPANS = {
     "repro.dp": "a DP solve, solver entry to the wall_time_s stamp; on "
                 "the fused bank path counts stacks (distinct device "
                 "stacks with dead slots read as bank row 0) and launches "
-                "(kernel launches made, one a joined live stack)",
+                "(kernel launches made, one a joined live stack), and "
+                "without all-k walked (the rows whose cost and splits "
+                "were selected on the device)",
     "repro.dp.launch": "one kernel launch; counts kernel (its jitted name), "
                        "rows, rows_padded, lanes, lanes_padded, h2d_bytes, "
                        "d2h_bytes (padding included)",
     "repro.dp.prepare": "padding, the host-to-device copy and the dispatch",
     "repro.dp.fetch": "the wait for the kernel, the device-to-host copy and "
-                      "the scatter of the unpadded tables",
-    "repro.dp.reconstruct": "tables to float64, result selection and the "
-                            "split walk (_reconstruct_splits)",
+                      "the scatter of the unpadded outputs: the tables, or "
+                      "on the walked path each row's cost and splits",
+    "repro.dp.reconstruct": "the result on the host: tables to float64, "
+                            "result selection and the split walk "
+                            "(_reconstruct_splits), or on the walked path "
+                            "the casts of the fetched costs and splits",
     "repro.plan.pipeline": "planner.pipeline_grid: the shapes' cost "
                            "profiles and the grid; counts shapes, layers, "
                            "mixes",

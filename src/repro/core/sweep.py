@@ -548,10 +548,12 @@ def _dp_launch(kernel: str, solver, operands: Callable[[], tuple], *,
 
     ``operands()`` pads the inputs and places them; with the dispatch of
     ``solver`` that is ``repro.dp.prepare``. ``repro.dp.fetch`` waits
-    for the outputs, copies them to the host and slices the padding off
-    (the first ``rows`` rows, the first ``lanes`` lanes); with
-    ``into=(tables, sel)`` it also scatters them into rows ``sel`` of
-    the host ``tables``. Returns the unpadded host (dp0, dps, args)."""
+    for the outputs and copies them to the host. Without ``into`` it
+    slices the padding off the (dp0, dps, args) tables (the first
+    ``rows`` rows, the first ``lanes`` lanes) and returns them. With
+    ``into=(host, sel)`` it cuts each output to the first ``rows`` rows
+    and to the trailing shape of its array in ``host``, and scatters it
+    into rows ``sel`` of that array."""
     with span("dp.launch", kernel=kernel, rows=rows, rows_padded=rows_padded,
               lanes=lanes, lanes_padded=lanes_padded) as sp:
         with span("dp.prepare"):
@@ -560,12 +562,12 @@ def _dp_launch(kernel: str, solver, operands: Callable[[], tuple], *,
         sp.set_metadata(h2d_bytes=sum(int(x.nbytes) for x in ins),
                         d2h_bytes=sum(int(o.nbytes) for o in out))
         with span("dp.fetch"):
-            host = tuple(np.asarray(o)[:rows, ..., :lanes] for o in out)
-            if into is not None:
-                tables, sel = into
-                for t, h in zip(tables, host):
-                    t[sel] = h
-    return host
+            if into is None:
+                return tuple(np.asarray(o)[:rows, ..., :lanes] for o in out)
+            host, sel = into
+            for t, o in zip(host, out):
+                t[sel] = np.asarray(o)[(slice(rows),)
+                                       + tuple(map(slice, t.shape[1:]))]
 
 
 def _dp_tables_to_numpy(dp0, dps, args, Sn: int, N: int, L: int):

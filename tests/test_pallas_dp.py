@@ -15,6 +15,9 @@ fused assertions are: feasibility ``==``, costs allclose, and any
 divergent plan must reprice (float64) to the same optimum.
 """
 
+import dataclasses
+
+import jax
 import numpy as np
 import pytest
 
@@ -28,6 +31,8 @@ from repro.core.latency import (
     ModelCostProfile,
     SplitCostModel,
 )
+from repro.core.planner import pipeline_grid
+from repro.core.profiles import ESP32, PROTOCOLS, TPU_LINKS, resnet50_cost_profile
 from repro.core.surface import build_surfaces
 
 INF = float("inf")
@@ -238,8 +243,7 @@ class TestFusedKernel:
         got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, combine=combine,
                                          n_devices=ns)
         assert len(launches) == n_mixes
-        _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, combine, got,
-                                    launches[0])
+        _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, combine, got)
 
     def test_heterogeneous_mix_fleet_sizes_share_one_launch(self,
                                                             monkeypatch):
@@ -257,8 +261,7 @@ class TestFusedKernel:
         launches = _count_fused_launches(monkeypatch)
         got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, n_devices=ns)
         assert len(launches) == 1
-        _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, "sum", got,
-                                    launches[0])
+        _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, "sum", got)
 
     @pytest.mark.parametrize("combine", ["sum", "max"])
     def test_dead_slots_are_never_read(self, combine):
@@ -298,39 +301,128 @@ class TestFusedKernel:
 
 def _count_fused_launches(monkeypatch):
     """Wrap ``PD._fused_launch``: the returned list gets each launch's
-    host ``tables`` (the same object for every launch of one call)."""
+    kernel name."""
     launches = []
     launch = PD._fused_launch
 
     def counted(*args):
-        launches.append(args[-1])
+        launches.append(args[4])
         return launch(*args)
 
     monkeypatch.setattr(PD, "_fused_launch", counted)
     return launches
 
 
-def _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, combine, got,
-                                tables):
+def _assert_same_as_row0_stacks(bank, bank_idx, tx, ns, combine, got):
     """Each stack with dead slots at bank row 0, solved alone on its own
-    scenarios, gives the same raw tables and results, bit for bit."""
-    N = bank_idx.shape[1]
+    scenarios through the table path, gives the same raw tables as the
+    joined stack its scenarios were launched on, and the same results as
+    the walked launch, bit for bit."""
+    N, L = bank_idx.shape[1], tx.shape[1]
     canon = np.where(np.arange(N)[None, :] >= ns[:, None], 0, bank_idx)
     stacks, inv = np.unique(canon, axis=0, return_inverse=True)
     inv = inv.reshape(-1)
+    joined, launch_of, _ = PD._join_live_stacks(bank_idx, ns)
     for u, stack in enumerate(stacks):
         sel = np.flatnonzero(inv == u)
         dp_per_k, parents = PD.pallas_fused_dp_tables(
             bank[stack], tx[sel], combine, ns=ns[sel])
-        assert np.array_equal(tables[0][sel], dp_per_k[0]), u
-        for k in range(N - 1):
-            assert np.array_equal(tables[1][sel, k], dp_per_k[k + 1]), u
-        assert np.array_equal(tables[2][sel], parents), u
-        ref = PD.pallas_fused_optimal_dp(bank[stack], None, tx[sel],
-                                         combine=combine, n_devices=ns[sel])
+        ref = SW._results_from_dp_tables(dp_per_k, parents, L, N, len(sel),
+                                         "pallas", ns[sel], False, 0.0)
+        (launch,) = np.unique(launch_of[sel])
+        jdp, jpar = PD.pallas_fused_dp_tables(bank[joined[launch]], tx[sel],
+                                              combine, ns=ns[sel])
+        for k in range(N):
+            assert np.array_equal(jdp[k], dp_per_k[k]), u
+        assert np.array_equal(jpar, parents), u
         assert np.array_equal(got.splits[sel], ref.splits), u
         assert np.array_equal(got.cost_s[sel], ref.cost_s), u
         assert np.array_equal(got.feasible[sel], ref.feasible), u
+        assert np.array_equal(got.n_devices_s[sel], ref.n_devices_s), u
+
+
+def _bank_path_on_tables(bank, bank_idx, tx, combine="sum", n_devices=None,
+                         **opts):
+    """:func:`PD.pallas_fused_optimal_dp`'s bank path with the split walk
+    on the host: every joined stack's tables fetched through
+    ``pallas_fused_dp_tables`` and one result selected from them."""
+    Sn, L = tx.shape
+    N = bank_idx.shape[1]
+    ns = SW._normalize_ns(n_devices, Sn, N)
+    stacks, launch_of, _ = PD._join_live_stacks(bank_idx, ns)
+    dp_per_k = [np.empty((Sn, L)) for _ in range(N)]
+    parents = np.empty((Sn, N - 1, L), dtype=np.int64)
+    for u, stack in enumerate(stacks):
+        sel = np.flatnonzero(launch_of == u)
+        dpk, par = PD.pallas_fused_dp_tables(bank[stack], tx[sel], combine,
+                                             ns=ns[sel], **opts)
+        for k in range(N):
+            dp_per_k[k][sel] = dpk[k]
+        parents[sel] = par
+    return SW._results_from_dp_tables(dp_per_k, parents, L, N, Sn, "pallas",
+                                      ns, False, 0.0)
+
+
+def _walk_bank(seed):
+    """Three mixes over fleet sizes 1-5 on L = 11: one mix's later
+    devices hold +inf wherever a segment ends past layer 3 (a memory
+    cliff: its rows with more than one device are wholly infeasible)."""
+    Sn, N, L = 41, 5, 11
+    rng = np.random.RandomState(seed)
+    bank = rng.uniform(1e-3, 5.0, size=(5, L, L))
+    il = np.tril_indices(L, -1)
+    bank[:, il[0], il[1]] = INF
+    bank[4, :, 3:] = INF
+    mixes = np.array([[0, 1, 1, 1, 1], [0, 2, 3, 2, 3], [0, 4, 4, 4, 4]])
+    bank_idx = mixes[rng.randint(0, 3, size=Sn)]
+    ns = rng.randint(1, N + 1, size=Sn)
+    tx = rng.uniform(0.0, 2.0, size=(Sn, L))
+    return bank, bank_idx, tx, ns
+
+
+class TestWalkedBankPath:
+    """The bank path without all-k selects each scenario's cost and walks
+    its splits on the device; every field equals the table path's."""
+
+    @pytest.mark.parametrize("combine", ["sum", "max"])
+    def test_bit_identical_to_the_table_path(self, monkeypatch, combine):
+        bank, bank_idx, tx, ns = _walk_bank(seed=61)
+        launches = _count_fused_launches(monkeypatch)
+        got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, combine=combine,
+                                         n_devices=ns, block_s=3)
+        assert launches == ["solve_fused_walk"] * 3
+        ref = _bank_path_on_tables(bank, bank_idx, tx, combine, ns,
+                                   block_s=3)
+        # the cases the walk has to get right are all there: one-device
+        # rows, launches with replica rows, wholly infeasible rows
+        _, launch_of, _ = PD._join_live_stacks(bank_idx, ns)
+        assert (ns == 1).any() and (np.bincount(launch_of) % 3).any()
+        infeasible = ~ref.feasible
+        assert infeasible.any() and ref.feasible.any()
+        assert (ref.splits[infeasible] == -1).all()
+        assert got.backend == "pallas" and got.n_devices == 5
+        assert got.splits.dtype == np.int64 and got.cost_s.dtype == np.float64
+        assert np.array_equal(got.splits, ref.splits)
+        assert np.array_equal(got.cost_s, ref.cost_s)
+        assert np.array_equal(got.feasible, ref.feasible)
+        assert np.array_equal(got.n_devices_s, ref.n_devices_s)
+
+    def test_without_fleet_sizes_every_row_walks_from_the_last_device(self):
+        bank, bank_idx, tx, _ = _walk_bank(seed=62)
+        got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx, combine="max")
+        ref = _bank_path_on_tables(bank, bank_idx, tx, "max")
+        assert got.n_devices_s is None
+        assert np.array_equal(got.splits, ref.splits)
+        assert np.array_equal(got.cost_s, ref.cost_s)
+        assert np.array_equal(got.feasible, ref.feasible)
+
+    def test_all_k_still_fetches_the_tables(self, monkeypatch):
+        bank, bank_idx, tx, _ = _walk_bank(seed=63)
+        launches = _count_fused_launches(monkeypatch)
+        got = PD.pallas_fused_optimal_dp(bank, bank_idx, tx,
+                                         return_all_k=True)
+        assert set(launches) == {"solve_fused"}
+        assert sorted(got) == [1, 2, 3, 4, 5]
 
 
 # ---------------------------------------------------------------------------
@@ -386,10 +478,50 @@ class TestSweepBackend:
             rb = S.total_cost(fn, b.splits, L)
             assert abs(ra - rb) <= 1e-12 * max(abs(ra), 1e-300)
 
+    @pytest.mark.parametrize("grid_of", ["r50", "dsv3"])
+    def test_sweep_rows_equal_the_table_path(self, monkeypatch, grid_of):
+        """Every field of every row is the same whether the bank path
+        walks the splits on the device or on the host; under x64 both
+        equal ``backend="jax"``, whose dense operand is then built with
+        the same float64 sum as the fused kernel's."""
+        grid = _parity_grid(grid_of)
+        with jax.enable_x64(True):
+            x64 = [_fields(r) for r in SW.sweep(grid, backend="pallas").rows]
+            assert x64 == [_fields(r) for r in
+                           SW.sweep(grid, backend="jax").rows]
+        walked = [_fields(r) for r in SW.sweep(grid, backend="pallas").rows]
+        monkeypatch.setattr(PD, "pallas_fused_optimal_dp",
+                            _bank_path_on_tables)
+        assert walked == [_fields(r) for r in
+                          SW.sweep(grid, backend="pallas").rows]
+        feasible = [f[2] for f in walked]
+        assert 0 < feasible.count(False) and 0 < feasible.count(True)
+
     def test_sweep_rejects_unknown_backend(self):
         grid = tiny_grid()
         with pytest.raises(ValueError, match="unknown backend"):
             SW.sweep(grid, backend="cuda")
+
+
+def _fields(row):
+    return (row.scenario, row.splits, row.feasible, row.objective_cost_s,
+            row.total_latency_s, row.device_s, row.transmission_s,
+            row.accuracy_proxy)
+
+
+def _parity_grid(name):
+    """ResNet50 on 2-5 ESP32s (sum), or a DeepSeek-shaped model of 14
+    blocks (L = 16) as 2-16 pipeline stages (max), whose decode shape's
+    latent cache makes few-stage plans infeasible."""
+    if name == "r50":
+        return SW.ScenarioGrid(models={"r50": resnet50_cost_profile()},
+                               links=dict(PROTOCOLS), n_devices=(2, 3, 4, 5),
+                               loss_p=(None, 0.05, 0.1), devices=(ESP32,))
+    from test_deepseek_v3_pipeline import TINY, TINY_SHAPES
+
+    cfg = dataclasses.replace(TINY, n_layers=14)
+    return pipeline_grid(cfg, TINY_SHAPES, (1, 2), range(2, 17), TPU_LINKS,
+                         loss_p=(None, 5e-4), rate_scale=(1.0, 0.25))
 
 
 def switchy_cost_model():
@@ -448,6 +580,14 @@ class TestJitCaching:
         before = PD._PALLAS_TRACE_COUNT
         SW.batched_optimal_dp(C, backend="pallas")
         SW.batched_optimal_dp(make_C(6, 3, 9, seed=24), backend="pallas")
+        assert PD._PALLAS_TRACE_COUNT == before
+
+    def test_same_shape_repeat_of_the_walk_does_not_retrace(self):
+        bank, bank_idx, tx, ns = _walk_bank(seed=64)
+        PD.pallas_fused_optimal_dp(bank, bank_idx, tx, n_devices=ns)  # warm
+        before = PD._PALLAS_TRACE_COUNT
+        PD.pallas_fused_optimal_dp(bank, bank_idx, tx, n_devices=ns)
+        PD.pallas_fused_optimal_dp(bank, bank_idx, tx * 0.5, n_devices=ns)
         assert PD._PALLAS_TRACE_COUNT == before
 
 

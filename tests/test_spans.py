@@ -119,9 +119,13 @@ def test_sweep_span_tree(tmp_path, backend, stacks):
                for sp in gathers)
     # one launch for the one joined device stack on pallas, one on jax
     assert len(named(spans, "repro.dp.launch")) == launches
+    (dp,) = named(spans, "repro.dp")
     if backend == "pallas":
-        (dp,) = named(spans, "repro.dp")
-        assert dp.stats == {"stacks": stacks, "launches": launches}
+        # every row's cost and splits were selected on the device
+        assert dp.stats == {"stacks": stacks, "launches": launches,
+                            "walked": grid.size}
+    else:
+        assert "walked" not in dp.stats
     for name in ("repro.dp.prepare", "repro.dp.fetch"):
         assert len(named(spans, name)) == launches
     assert named(spans, "repro.dp.reconstruct")
@@ -167,12 +171,12 @@ def test_budgeted_build_is_one_gather_a_group(tmp_path, backend):
 
 def _pallas_launch(rows):
     N, Lp, Sp = 5, 128, -(-rows // 8) * 8  # block_s 8
-    return {"kernel": "solve_fused", "rows": rows, "rows_padded": Sp,
+    return {"kernel": "solve_fused_walk", "rows": rows, "rows_padded": Sp,
             "lanes": 52, "lanes_padded": Lp,
             # local (N, Lp, Lp), tx (Sp, Lp), ns (Sp, 1)
             "h2d_bytes": N * Lp * Lp * F32 + Sp * Lp * F32 + Sp * I32,
-            # dp0 (Sp, Lp), dps and args (Sp, N - 1, Lp)
-            "d2h_bytes": Sp * Lp * F32 + 2 * Sp * (N - 1) * Lp * F32}
+            # cost (Sp,) and splits (Sp, N - 1): the tables stay on the chip
+            "d2h_bytes": Sp * F32 + Sp * (N - 1) * I32}
 
 
 def _scan_launch(rows):
@@ -240,6 +244,9 @@ def _lowered_text(name):
     if name == "solve_fused":
         fn = PD._pallas_dp_solver("fused", "sum", 8, True)
         args = (S((5, 128, 128), f32), S((16, 128), f32), S((16, 1), i32))
+    elif name == "solve_fused_walk":
+        fn = PD._pallas_walk_solver("sum", 8, True, 52)
+        args = (S((5, 128, 128), f32), S((16, 128), f32), S((16, 1), i32))
     elif name == "solve_dense":
         fn = PD._pallas_dp_solver("dense", "sum", 8, True)
         args = (S((16, 5, 128, 128), f32), S((16, 1), i32))
@@ -249,7 +256,8 @@ def _lowered_text(name):
     return fn.lower(*args).as_text()
 
 
-@pytest.mark.parametrize("name", ["solve_fused", "solve_dense", "solve_scan"])
+@pytest.mark.parametrize("name", ["solve_fused", "solve_fused_walk",
+                                  "solve_dense", "solve_scan"])
 def test_jitted_dp_programs_have_stable_names(name):
     """The device trace prints each DP program by this name, and the
     benchmark's kernel readers match on ``solve`` in it."""
