@@ -28,6 +28,7 @@ _MODULES = {
     "xlstm-1.3b": "xlstm_1p3b",
     "deepseek-v3": "deepseek_v3",
     "gigachat3.5-432b-a28b": "gigachat35_432b_a28b",
+    "glm-5.2": "glm52",
 }
 
 ARCH_IDS = list(_MODULES)

@@ -367,6 +367,10 @@ def compare_solvers(
 # ---------------------------------------------------------------------------
 
 
+# int32 top-k indices shipped with the hidden state under IndexShare
+INDEX_BYTES = 4
+
+
 def tpu_cost_profile(
     graph: "LayerGraph",
     *,
@@ -384,7 +388,9 @@ def tpu_cost_profile(
     split decisions). ``param_bytes`` is what the layer holds: weights,
     cache (in the activation dtype) and recurrent state (in
     ``state_dtype_bytes``), so a segment's memory check sums all
-    three."""
+    three. ``act_bytes``, what a cut after the layer ships, is its output
+    in the activation dtype plus the int32 top-k indices a layer sharing
+    the selection needs (``LayerNode.cut_index_elems``)."""
     from repro.core.latency import LayerCost
 
     layers = []
@@ -398,7 +404,8 @@ def tpu_cost_profile(
             LayerCost(
                 name=n.name,
                 t_infer_s=tpu_layer_time_s(n.flops, bytes_moved, chips_per_stage),
-                act_bytes=n.out_elems * act_dtype_bytes,
+                act_bytes=(n.out_elems * act_dtype_bytes
+                           + n.cut_index_elems * INDEX_BYTES),
                 param_bytes=(n.param_count * param_dtype_bytes
                              + n.cache_elems * act_dtype_bytes
                              + n.state_elems * state_dtype_bytes),
@@ -472,13 +479,18 @@ def pipeline_grid(
 
     Spans: ``repro.plan.pipeline`` (``shapes``, ``mixes``, ``layers``)
     and per shape ``repro.plan.pipeline.profile`` (``experts_touched``,
-    ``layers``, and what that shape's graph holds: ``linear_layers``,
-    ``state_bytes``, ``cache_bytes``)."""
+    ``layers``, ``sparse_layers``, ``index_share_layers``, and what that
+    shape's graph holds and moves: ``linear_layers``, ``state_bytes``,
+    ``cache_bytes``, ``cache_read_bytes``, ``cut_index_bytes``)."""
     from repro.models.graph import arch_layer_graph, experts_touched
 
     mixes = {f"x{c}": (tpu_stage_device(c),) for c in chips_per_stage}
     act_b = 2  # bf16 activations and cache
     state_b = np.dtype(cfg.linear_state_dtype).itemsize
+    # MLA blocks over the indexer's top-k (MTP's included), and those
+    # reusing an earlier layer's selection
+    sparse = cfg.n_layers + cfg.n_mtp_modules if cfg.index_topk else 0
+    share = len(cfg.index_shared_layers)
     models = {}
     with span("plan.pipeline", shapes=len(shapes), mixes=len(mixes)) as sp:
         for shape in shapes:
@@ -487,7 +499,8 @@ def pipeline_grid(
             seq = 1 if decode else shape.seq_len
             touched = (experts_touched(cfg.n_experts, cfg.top_k, batch * seq)
                        if cfg.is_moe else 0.0)
-            with span("plan.pipeline.profile", experts_touched=touched) as pp:
+            with span("plan.pipeline.profile", experts_touched=touched,
+                      sparse_layers=sparse, index_share_layers=share) as pp:
                 graph = arch_layer_graph(
                     cfg, batch, seq, kv_len=shape.seq_len if decode else None)
                 models[shape.name] = tpu_cost_profile(
@@ -496,7 +509,11 @@ def pipeline_grid(
                     layers=graph.num_layers,
                     linear_layers=sum(n.state_elems > 0 for n in graph.nodes),
                     state_bytes=sum(n.state_elems for n in graph.nodes) * state_b,
-                    cache_bytes=sum(n.cache_elems for n in graph.nodes) * act_b)
+                    cache_bytes=sum(n.cache_elems for n in graph.nodes) * act_b,
+                    cache_read_bytes=sum(
+                        n.cache_read_elems for n in graph.nodes) * act_b,
+                    cut_index_bytes=sum(
+                        n.cut_index_elems for n in graph.nodes) * INDEX_BYTES)
         sp.set_metadata(layers=max((m.num_layers for m in models.values()),
                                    default=0))
         return SW.ScenarioGrid(

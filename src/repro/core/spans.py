@@ -59,11 +59,20 @@ SPANS = {
     "repro.plan.pipeline.profile": "one shape's layer graph and TPU cost "
                                    "profile; counts layers, "
                                    "experts_touched (a MoE layer, a step), "
+                                   "sparse_layers (MLA blocks attending to "
+                                   "an indexer's top-k, MTP's included), "
+                                   "index_share_layers (those reusing an "
+                                   "earlier layer's selection), "
                                    "linear_layers (nodes holding a "
                                    "recurrent state), state_bytes (that "
                                    "state, in its dtype) and cache_bytes "
-                                   "(the KV or latent cache, bf16) the "
-                                   "shape's graph holds",
+                                   "(the KV or latent cache and index keys, "
+                                   "bf16) the shape's graph holds, "
+                                   "cache_read_bytes (what a step reads of "
+                                   "that cache) and cut_index_bytes (the "
+                                   "int32 top-k indices a cut before each "
+                                   "sharing layer ships, summed over those "
+                                   "cuts)",
     "repro.rebuild": "one in-process surface rebuild on the executor; "
                      "counts queued_ms (first queued request to build start)",
 }

@@ -55,6 +55,16 @@ class ModelConfig:
     linear_conv_kernel: int = 0  # depthwise causal conv over q, k and v
     linear_state_dtype: str = "float32"  # the held recurrent and conv state
 
+    # --- DeepSeek Sparse Attention over MLA (GLM-5.2) --------------------------
+    # a lightning indexer of index_n_heads x index_head_dim scores every cached
+    # token; MLA attends to its top index_topk (0: dense MLA). The layers in
+    # index_shared_layers run no indexer and reuse the selection of the
+    # nearest earlier layer that does (IndexShare)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_shared_layers: tuple[int, ...] = ()
+
     # --- position encoding --------------------------------------------------
     rope_theta: float = 10_000.0
     mrope_sections: tuple[int, int, int] | None = None  # qwen2-vl (t, h, w)
@@ -127,6 +137,28 @@ class ModelConfig:
                     and self.linear_conv_kernel):
                 raise ValueError(f"{self.name}: linear_attn_layers need the "
                                  f"Gated DeltaNet heads, head dims and conv kernel")
+        if self.index_topk or self.index_shared_layers:
+            self._check_dsa()
+
+    def _check_dsa(self) -> None:
+        if not self.index_topk:
+            raise ValueError(f"{self.name}: index_shared_layers need index_topk")
+        if not self.use_mla:
+            raise ValueError(f"{self.name}: sparse attention selects MLA's "
+                             f"keys; it needs use_mla")
+        if self.linear_attn_layers:
+            raise ValueError(f"{self.name}: sparse attention beside Gated "
+                             f"DeltaNet layers is not priced")
+        if not (self.index_n_heads and self.index_head_dim):
+            raise ValueError(f"{self.name}: index_topk needs the indexer's "
+                             f"heads and head dim")
+        shared = set(self.index_shared_layers)
+        if not all(0 <= i < self.n_layers for i in shared):
+            raise ValueError(f"{self.name}: index_shared_layers "
+                             f"{self.index_shared_layers} outside 0..{self.n_layers - 1}")
+        if 0 in shared:  # else layer 0 runs the indexer, before any shared
+            raise ValueError(f"{self.name}: shared layer 0 has no earlier "
+                             f"layer running the indexer")
 
     @property
     def is_moe(self) -> bool:
@@ -166,6 +198,19 @@ class ModelConfig:
         return (d * (self.linear_conv_dim + hv * dv + 2 * hv)
                 + self.linear_conv_dim * self.linear_conv_kernel + 2 * hv + dv
                 + hv * dv * d)
+
+    def is_index_shared(self, i: int) -> bool:
+        """Whether decoder layer ``i`` reuses an earlier layer's top-k
+        (else, under sparse attention, it runs its own indexer)."""
+        return i in self.index_shared_layers
+
+    @property
+    def index_params(self) -> int:
+        """One lightning indexer's weights (DeepSeek-V3.2-Exp): ``q^I``
+        from the query latent, ``k^I`` from the hidden state and its
+        LayerNorm (weight and bias), and the per-head weights ``w``."""
+        d, hi, di = self.d_model, self.index_n_heads, self.index_head_dim
+        return self.q_lora_rank * hi * di + d * di + 2 * di + d * hi
 
     def is_moe_layer(self, i: int) -> bool:
         """Whether decoder layer ``i`` has an expert FFN (the first
@@ -210,6 +255,8 @@ class ModelConfig:
                     attn = q + kv + o
                     if self.attn_output_gate:
                         attn += d * self.n_heads * self.v_head_dim
+                    if self.index_topk and not self.is_index_shared(i):
+                        attn += self.index_params
                 else:
                     attn = (self.n_heads + 2 * self.n_kv_heads) * hd * d
                     attn += self.n_heads * hd * d
@@ -266,6 +313,10 @@ class ModelConfig:
             small.update(linear_attn_layers=(), linear_n_k_heads=2,
                          linear_n_v_heads=4, linear_k_head_dim=16,
                          linear_v_head_dim=16)
+        if self.index_topk:
+            # the JAX model runs dense MLA: the small variant drops DSA
+            small.update(index_n_heads=0, index_head_dim=0, index_topk=0,
+                         index_shared_layers=())
         if self.block_pattern is not None:
             small.update(block_pattern=self._reduced_pattern())
         if self.mrope_sections is not None:

@@ -8,18 +8,21 @@ name, and tests assert the alignment.
 
 Conventions:
   * ``flops`` counts multiply-adds as 2 ops.
-  * ``act_bytes`` is the size of the single tensor crossing a cut placed
+  * ``act_bytes`` is the size of the tensor crossing a cut placed
     *after* the node, in deployment dtype (int8 for the TinyML path,
     bf16 for the TPU path) — the paper's Eq. 1 sequential-chain view
     (Table II packet counts confirm only the main tensor is shipped).
+    Under IndexShare a cut before a layer that reuses the selection
+    also ships the int32 top-k indices (``cut_index_elems``).
   * ``work_bytes`` approximates the peak resident activation set for the
     node (input + output), used for device memory feasibility.
   * ``param_count`` is what the node holds; ``streamed_params`` what a
     step reads of it (``None``: all of it). ``cache_elems`` is the KV or
     latent cache the node holds, ``cache_read_elems`` what a step reads
-    of it. Only the DeepSeek-V3 layout (:func:`deepseek_layer_graph`)
-    sets the three; every other graph reads its parameters whole and
-    counts no cache.
+    of it: the two differ under sparse attention, whose decode step
+    reads the top-k latents and not the whole cache. Only the
+    DeepSeek-V3 layout (:func:`deepseek_layer_graph`) sets these;
+    every other graph reads its parameters whole and counts no cache.
 """
 
 from __future__ import annotations
@@ -43,6 +46,7 @@ class LayerNode:
     cache_read_elems: int = 0  # cache elements read a step
     state_elems: int = 0  # resident recurrent state elements (fixed in kv_len)
     state_rw_elems: int = 0  # state elements read and written a step
+    cut_index_elems: int = 0  # int32 top-k indices crossing a cut after the node
 
     @property
     def params_read(self) -> float:
@@ -348,11 +352,11 @@ def arch_layer_graph(cfg, batch: int, seq: int, kv_len: int | None = None,
     """LayerGraph for any assigned :class:`ModelConfig` — walks the block
     pattern with per-kind FLOP/param/activation formulas. Used by the
     analytic roofline terms and by :func:`plan_pipeline` on real archs.
-    A config with a dense prefix, shared experts, MTP modules or Gated
-    DeltaNet layers takes the DeepSeek-V3 layout
+    A config with a dense prefix, shared experts, MTP modules, Gated
+    DeltaNet layers or sparse attention takes the DeepSeek-V3 layout
     (:func:`deepseek_layer_graph`)."""
     if (cfg.first_k_dense or cfg.n_shared_experts or cfg.n_mtp_modules
-            or cfg.linear_attn_layers):
+            or cfg.linear_attn_layers or cfg.index_topk):
         return deepseek_layer_graph(cfg, batch, seq, kv_len)
     d = cfg.d_model
     nodes: list[LayerNode] = []
@@ -498,30 +502,55 @@ def _gdn_flops(cfg, batch: int, seq: int) -> float:
     return flops + batch * math.ceil(seq / C) * hv * chunk
 
 
-def _deepseek_block(cfg, i: int | None, batch: int, seq: int, kv: int):
-    """(flops, resident, streamed, cache, state) of one decoder block:
-    MLA (with its output gate where the config has one) or, for a layer
+def _dsa_flops(cfg, batch: int, seq: int, kv: int) -> float:
+    """The lightning indexer of a layer that runs it (DeepSeek-V3.2-Exp):
+    the ``q^I``, ``k^I`` and ``w`` projections, then
+    ``I_ts = sum_j w_tj ReLU(q^I_tj . k^I_s)`` over every (query, key)
+    pair, as MLA counts them. Top-k selection is not counted."""
+    d, hi, di = cfg.d_model, cfg.index_n_heads, cfg.index_head_dim
+    T = batch * seq
+    return (2.0 * T * (cfg.q_lora_rank * hi * di + d * di + d * hi)
+            + 2.0 * batch * seq * kv * hi * (di + 1))
+
+
+def _deepseek_block(cfg, i: int | None, batch: int, seq: int, kv: int,
+                    decode: bool):
+    """(flops, resident, streamed, cache, cache_read, state) of one
+    decoder block: MLA (with its output gate where the config has one,
+    over the indexer's top-k under sparse attention) or, for a layer
     ``i`` in ``linear_attn_layers``, Gated DeltaNet; then a dense SwiGLU
     (layer ``i`` < ``first_k_dense``) or the routed experts, the shared
-    experts and the router. ``i`` None is an MTP module's block: MLA,
-    and MoE unless ``mtp_dense``. ``cache`` is the MLA latent cache,
-    ``state`` the Gated DeltaNet state (fixed in ``kv``)."""
+    experts and the router. ``i`` None is an MTP module's block: MLA
+    (running its own indexer under sparse attention), and MoE unless
+    ``mtp_dense``. ``cache`` is the MLA latent cache (and the index
+    keys), ``cache_read`` what a step reads of it (``decode``: a step
+    continuing ``kv`` cached positions), ``state`` the Gated DeltaNet
+    state (fixed in ``kv``)."""
     d, T = cfg.d_model, batch * seq
     mats = 3 if cfg.gated_mlp else 2
     norms = (4 if cfg.pre_post_norm else 2) * d  # attention and FFN norms
-    cache = state = 0
+    cache = cache_read = state = 0
     if i is not None and cfg.is_linear_layer(i):
         flops = _gdn_flops(cfg, batch, seq)
         attn = cfg.linear_attn_params + norms
         state = batch * cfg.linear_state_elems
     else:
-        flops = _mla_flops(cfg, batch, seq, kv)
+        latent = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        attended = min(kv, cfg.index_topk) if cfg.index_topk else kv
+        flops = _mla_flops(cfg, batch, seq, attended)
         attn = _mla_params(cfg) + norms
         if cfg.attn_output_gate:  # sigmoid(x W_G) on every head's output
             gate = d * cfg.n_heads * cfg.v_head_dim
             flops += 2.0 * T * gate
             attn += gate
-        cache = batch * kv * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        cache = batch * kv * latent
+        cache_read = batch * attended * latent if decode else cache
+        if cfg.index_topk and (i is None or not cfg.is_index_shared(i)):
+            keys = batch * kv * cfg.index_head_dim  # k^I of every cached token
+            flops += _dsa_flops(cfg, batch, seq, kv)
+            attn += cfg.index_params
+            cache += keys
+            cache_read += keys
     dense = cfg.mtp_dense if i is None else not cfg.is_moe_layer(i)
     if dense:
         ffn = mats * d * cfg.d_ff
@@ -536,7 +565,7 @@ def _deepseek_block(cfg, i: int | None, batch: int, seq: int, kv: int):
         params = attn + E * expert + Es * expert + router
         streamed = (attn + experts_touched(E, cfg.top_k, T) * expert
                     + Es * expert + router)
-    return flops, params, streamed, cache, state
+    return flops, params, streamed, cache, cache_read, state
 
 
 def deepseek_layer_graph(cfg, batch: int, seq: int,
@@ -564,6 +593,18 @@ def deepseek_layer_graph(cfg, batch: int, seq: int,
       ``2 T d heads v_head_dim`` FLOPs and its weights. It holds and
       reads a latent cache of ``kv_lora_rank + qk_rope_head_dim`` a token
       a sequence, in the activation dtype.
+    * DeepSeek Sparse Attention (``index_topk``; DeepSeek-V3.2-Exp): MLA
+      attends to ``min(kv, index_topk)`` keys a query. A layer that runs
+      the lightning indexer (:func:`_dsa_flops`, weights
+      ``ModelConfig.index_params``; the MTP block does) scores every
+      cached token and holds and reads ``index_head_dim`` index-key
+      values a token besides the latent. A decode step reads the top-k
+      latents, whatever the cache holds; a prefill reads the held cache
+      once. A layer in ``index_shared_layers`` reuses the nearest
+      earlier selection, so a cut just before it ships the
+      ``batch x seq x min(kv, index_topk)`` int32 indices
+      (``cut_index_elems`` of the node before the cut) besides the
+      hidden state.
     * Gated DeltaNet (:func:`_gdn_flops`; weights
       ``ModelConfig.linear_attn_params``): it holds
       ``ModelConfig.linear_state_elems`` a sequence (the delta-rule state
@@ -591,29 +632,34 @@ def deepseek_layer_graph(cfg, batch: int, seq: int,
     rows = experts_touched(V, 1, T) * d  # embedding rows a lookup reads
     nodes = [LayerNode("embed", flops=0.0, param_count=V * d, out_elems=act,
                        work_elems=2 * act, streamed_params=rows)]
+    decode = kv_len is not None
+    picked = T * min(kv, cfg.index_topk)  # top-k indices a step selects
     for i in range(cfg.n_layers):
-        f, p, s, c, st = _deepseek_block(cfg, i, batch, seq, kv)
+        f, p, s, c, cr, st = _deepseek_block(cfg, i, batch, seq, kv, decode)
+        ships = cfg.index_topk and cfg.is_index_shared(i + 1)
         nodes.append(LayerNode(f"layer_{i}", flops=f, param_count=p,
                                out_elems=act, work_elems=2 * act,
                                streamed_params=s, cache_elems=c,
-                               cache_read_elems=c, state_elems=st,
-                               state_rw_elems=rw * st))
+                               cache_read_elems=cr, state_elems=st,
+                               state_rw_elems=rw * st,
+                               cut_index_elems=picked if ships else 0))
     head_w = V * d
     flops = 2.0 * T * d * V
     params = d + head_w
     streamed = float(d + head_w)
-    cache = 0
+    cache = cache_read = 0
     for _ in range(cfg.n_mtp_modules):
-        f, p, s, c, _ = _deepseek_block(cfg, None, batch, seq, kv)
+        f, p, s, c, cr, _ = _deepseek_block(cfg, None, batch, seq, kv, decode)
         own = 2 * d + 2 * d * d + d  # enorm, hnorm, eh_proj, head norm
         flops += 2.0 * T * 2 * d * d + f + 2.0 * T * d * V
         params += own + p + V * d  # + the embedding copy
         streamed += own + s + rows + head_w
         cache += c
+        cache_read += cr
     nodes.append(LayerNode("head", flops=flops, param_count=params,
                            out_elems=T * V, work_elems=act + T * V,
                            streamed_params=streamed, cache_elems=cache,
-                           cache_read_elems=cache))
+                           cache_read_elems=cache_read))
     return LayerGraph(cfg.name, tuple(nodes), T)
 
 

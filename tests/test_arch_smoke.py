@@ -114,6 +114,7 @@ class TestArchSmoke:
             "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
             "deepseek-v3": (61, 7168, 128, 128, 18432, 129280),
             "gigachat3.5-432b-a28b": (40, 7168, 64, 64, 18432, 128256),
+            "glm-5.2": (78, 6144, 64, 64, 12288, 154880),
         }[arch_id]
         got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                cfg.d_ff, cfg.vocab)

@@ -303,13 +303,18 @@ def test_pipeline_grid_spans_carry_their_counts(tmp_path):
     assert outer.stats == {"shapes": 2, "mixes": 3, "layers": 63}
     inner = named(spans, "repro.plan.pipeline.profile")
     assert [parent(sp, spans) for sp in inner] == [outer, outer]
-    # 61 MLA blocks and the MTP block hold bf16 latent caches; no state
+    # 61 MLA blocks and the MTP block hold bf16 latent caches and read all
+    # of them; no state, no sparse attention, no indices on any cut
     latent = 62 * 576 * 2
+    dense = {"sparse_layers": 0, "index_share_layers": 0, "linear_layers": 0,
+             "state_bytes": 0, "cut_index_bytes": 0}
     assert [sp.stats for sp in inner] == [
         {"experts_touched": experts_touched(256, 8, 2048), "layers": 63,
-         "linear_layers": 0, "state_bytes": 0, "cache_bytes": latent * 2048},
+         "cache_bytes": latent * 2048, "cache_read_bytes": latent * 2048,
+         **dense},
         {"experts_touched": experts_touched(256, 8, 8), "layers": 63,
-         "linear_layers": 0, "state_bytes": 0, "cache_bytes": latent * 8 * 4096}]
+         "cache_bytes": latent * 8 * 4096,
+         "cache_read_bytes": latent * 8 * 4096, **dense}]
     assert grid.size == 2 * 3 * 15 * 2
 
 
@@ -334,3 +339,33 @@ def test_pipeline_profile_counts_held_state_and_cache(tmp_path):
          "cache_bytes": 12 * 2 * 4096 * 576 * 2},
         {"layers": 42, "linear_layers": 30, "state_bytes": 30 * 8 * state * 4,
          "cache_bytes": 12 * 8 * 262_144 * 576 * 2}]
+
+
+def test_pipeline_profile_counts_sparse_attention(tmp_path):
+    """GLM-5.2: 78 MLA layers and the MTP block attend to the indexer's
+    top-2,048; 57 layers share an earlier selection, so 57 cuts ship the
+    int32 indices. A decode step past the top-k reads less cache than
+    the graph holds; a prefill reads what it holds."""
+    from repro.configs import get_config
+    from repro.configs.shapes import ShapeSpec
+    from repro.core.planner import pipeline_grid
+
+    shapes = (ShapeSpec("prefill", "prefill", 8192, 1),
+              ShapeSpec("decode", "decode", 1_048_576, 4))
+    cfg = get_config("glm-5.2")
+    _, spans = traced(tmp_path, lambda: pipeline_grid(cfg, shapes, (8,), (2, 4)))
+    assert {sp.name for sp in spans} <= set(SPANS)
+    inner = named(spans, "repro.plan.pipeline.profile")
+    keys = ("layers", "sparse_layers", "index_share_layers", "cache_bytes",
+            "cache_read_bytes", "cut_index_bytes")
+    got = [{k: sp.stats[k] for k in keys} for sp in inner]
+    held = 79 * 576 + 22 * 128  # latents and index keys a token, 96,640 B
+    read = 57 * 2048 * 576 + 22 * (2048 * 576 + 1_048_576 * 128)  # a sequence
+    assert got == [
+        {"layers": 80, "sparse_layers": 79, "index_share_layers": 57,
+         "cache_bytes": held * 8192 * 2, "cache_read_bytes": held * 8192 * 2,
+         "cut_index_bytes": 57 * 8192 * 2048 * 4},
+        {"layers": 80, "sparse_layers": 79, "index_share_layers": 57,
+         "cache_bytes": held * 4 * 1_048_576 * 2,
+         "cache_read_bytes": read * 4 * 2,
+         "cut_index_bytes": 57 * 4 * 2048 * 4}]
