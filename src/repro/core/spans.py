@@ -26,8 +26,13 @@ SPANS = {
     "repro.sweep.enumerate": "grid.scenarios() and grouping by model",
     "repro.sweep.build": "one group's grid build, the interval "
                          "SweepResult.build_time_s adds up",
-    "repro.sweep.bank": "profile-bank matrices and the bank_idx loop",
-    "repro.sweep.tx": "the group's transmission vectors (_group_tx_vectors)",
+    "repro.sweep.bank": "profile-bank matrices and bank_idx, one slot row "
+                        "per distinct (mix, n_devices) gathered over the "
+                        "group (_group_bank); counts slot_rows (those rows)",
+    "repro.sweep.tx": "the group's transmission vectors, priced as arrays "
+                      "over the group (_group_tx_vectors); counts "
+                      "packet_vectors (the distinct (MTU, compression "
+                      "factor) packet-count vectors)",
     "repro.sweep.gather": "dense paths: the cost tensor in one pass over "
                           "scenario blocks (gather, TX add, energy and "
                           "budget mask, cast to the DP's dtype); counts "

@@ -66,6 +66,7 @@ import os
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, replace
+from operator import attrgetter
 from typing import Callable, Mapping, Sequence
 
 import numpy as np
@@ -1889,16 +1890,74 @@ class ParetoFrontier:
         return "\n".join(lines) + "\n"
 
 
+def _codes(values: list) -> tuple[list, np.ndarray, np.ndarray]:
+    """``values``' distinct entries in order of first appearance, the
+    index where each first appears, and every value's code (the position
+    of its distinct entry). Each value is hashed in C, with no Python
+    frame per value."""
+    distinct = list(dict.fromkeys(values))
+    code = dict(zip(distinct, range(len(distinct))))
+    inv = np.fromiter(map(code.__getitem__, values), np.intp, len(values))
+    # codes are handed out in order of first appearance, so code j first
+    # appears where the running maximum of the codes first reaches j
+    first = np.searchsorted(np.maximum.accumulate(inv),
+                            np.arange(len(distinct)))
+    return distinct, first, inv
+
+
+def _group_bank(
+    grid: ScenarioGrid, profile: ModelCostProfile, group: list[Scenario],
+    n_max: int,
+) -> tuple[np.ndarray, dict[tuple[DeviceProfile, bool], int], np.ndarray]:
+    """A sweep group's profile bank: ``(bank, bank_rows, bank_idx)``.
+
+    ``bank`` stacks one local cost matrix per distinct ``(DeviceProfile,
+    is_first)`` pair (``bank_rows`` maps each pair to its row, in the
+    order the group's scenarios first reach it); ``bank_idx[s, k-1]`` is
+    the row scenario ``s``'s device ``k`` runs, and row 0 in the filler
+    slots beyond its fleet size, which the solvers never read (the
+    per-scenario ``n_devices`` vector masks every ``k > n_s``). A
+    scenario's slots depend on its ``(mix, n_devices)`` alone, so one
+    slot row is built per distinct key, in order of first appearance,
+    and gathered over the group. Span ``repro.sweep.bank`` counts
+    ``slot_rows``, the slot rows built."""
+    with span("sweep.bank") as sp:
+        base_model = SplitCostModel(
+            profile=profile, devices=grid.devices_for(group[0]),
+            link=next(iter(grid.links.values())), objective=grid.objective,
+        )
+        bank_rows: dict[tuple[DeviceProfile, bool], int] = {}
+        bank_mats: list[np.ndarray] = []
+        keys, first, inv = _codes(
+            list(map(attrgetter("mix", "n_devices"), group)))
+        slots = np.zeros((len(keys), n_max), dtype=np.int64)
+        for r, ((_mix, n), i) in enumerate(zip(keys, first)):
+            devs = grid.devices_for(group[i])
+            for k in range(1, n + 1):
+                key = (devs[0] if len(devs) == 1 else devs[k - 1], k == 1)
+                row = bank_rows.get(key)
+                if row is None:
+                    row = bank_rows[key] = len(bank_mats)
+                    bank_mats.append(base_model._local_cost_matrix(*key))
+                slots[r, k - 1] = row
+        sp.set_metadata(slot_rows=len(keys))
+        return np.stack(bank_mats), bank_rows, slots[inv]
+
+
 def _group_tx_vectors(
     grid: ScenarioGrid, profile: ModelCostProfile, group: list[Scenario]
 ) -> tuple[np.ndarray, np.ndarray | None, np.ndarray | None,
            np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """(S_g, L) transmission-cost vectors, amortizing packet counts per
-    (MTU, compression factor) against per-scenario packet times.
-    Airtime is priced on each scenario's contention-scaled effective
-    link, matching the scalar oracle's :attr:`SplitCostModel.effective_link`;
-    a scenario with a bottleneck variant prices K on the compressed cut
-    bytes and adds the encoder-time vector, matching
+    """(S_g, L) transmission-cost vectors, priced as arrays over the
+    group: one packet-count vector per distinct (MTU, compression factor)
+    times each scenario's packet time. Airtime is priced on each
+    scenario's contention-scaled effective link, entry for entry the
+    scalar oracle's :attr:`SplitCostModel.effective_link`: the rate is
+    scaled by the scenario's rate scale, then by its contention's, each
+    only where it is not 1.0, as :meth:`ScenarioGrid.link_variant` and
+    :meth:`ContentionModel.apply` do. A scenario with a bottleneck
+    variant prices K on the compressed cut bytes and adds the
+    encoder-time vector, matching
     :meth:`SplitCostModel.transmission_cost_vector` term-for-term.
 
     Returns ``(TX, AIR, ENC, setup_s, feedback_s, tx_power_w,
@@ -1911,53 +1970,69 @@ def _group_tx_vectors(
     ``+ 0.0``. ``setup_s``/``feedback_s`` are the ``(S_g,)`` link setup
     and feedback times of the same effective links, which the rows'
     total latency adds; ``tx_power_w``/``rx_power_w`` their ``(S_g,)``
-    radio powers, which the energy tensor prices airtime at."""
-    L = profile.num_layers
-    act_raw = profile.segment_arrays.boundary_act_bytes[1:].astype(np.float64)
-    variants = [grid.variant_for(sc) for sc in group]
-    any_variant = any(v is not None for v in variants)
-    packets_by_key: dict[tuple[int, float], np.ndarray] = {}
-    enc_by_factor: dict[float, np.ndarray] = {}
-    out = np.empty((len(group), L))
-    air_out = np.empty((len(group), L)) if any_variant else None
-    enc_out = np.zeros((len(group), L)) if any_variant else None
-    setup_s = np.empty(len(group))
-    feedback_s = np.empty(len(group))
-    tx_power_w = np.empty(len(group))
-    rx_power_w = np.empty(len(group))
-    for i, (sc, v) in enumerate(zip(group, variants)):
-        link = grid.effective_link(sc)
-        setup_s[i] = link.t_setup_s
-        feedback_s[i] = link.t_feedback_s
-        tx_power_w[i] = link.tx_power_w
-        rx_power_w[i] = link.rx_power_w
-        factor = 1.0 if v is None else v.compression_factor
-        K = packets_by_key.get((link.mtu_bytes, factor))
-        if K is None:
-            if v is None:
-                act = act_raw
-            else:
-                act = np.where(act_raw > 0,
-                               np.ceil(act_raw / v.compression_factor), 0.0)
-            K = np.where(act > 0, np.ceil(act / link.mtu_bytes), 0.0)
-            packets_by_key[(link.mtu_bytes, factor)] = K
-        tx = K * link.packet_time_s()
-        tx[-1] = 0.0
-        if air_out is not None:
-            air_out[i] = tx
-        if v is not None:
-            enc = enc_by_factor.get(factor)
-            if enc is None:
-                enc = np.where(act_raw > 0,
-                               v.encoder_t_s + act_raw * v.encoder_s_per_byte,
-                               0.0)
-                enc[-1] = 0.0
-                enc_by_factor[factor] = enc
-            enc_out[i] = enc
-            tx = tx + enc
-        out[i] = tx
-    return (out, air_out, enc_out, setup_s, feedback_s, tx_power_w,
-            rx_power_w)
+    radio powers, which the energy tensor prices airtime at. Span
+    ``repro.sweep.tx`` counts ``packet_vectors``, the distinct (MTU,
+    compression factor) packet-count vectors built."""
+    with span("sweep.tx") as sp:
+        S = len(group)
+        act_raw = profile.segment_arrays.boundary_act_bytes[1:].astype(
+            np.float64)
+        names, _, p = _codes(list(map(attrgetter("protocol"), group)))
+        links = [grid.links[name] for name in names]
+
+        def per_link(field: str) -> np.ndarray:
+            """Each scenario's protocol's ``field``."""
+            return np.array([getattr(link, field) for link in links],
+                            dtype=np.float64)[p]
+
+        losses, _, li = _codes(list(map(attrgetter("loss_p"), group)))
+        loss = np.where(np.array([lp is None for lp in losses])[li],
+                        per_link("loss_p"),
+                        np.array([0.0 if lp is None else lp for lp in losses],
+                                 dtype=np.float64)[li])
+        rate = per_link("rate_bytes_per_s")
+        rs = np.fromiter(map(attrgetter("rate_scale"), group), np.float64, S)
+        rate = np.where(rs != 1.0, rate * rs, rate)
+        _, first, ci = _codes(list(map(attrgetter("contention"), group)))
+        cs = np.array([1.0 if con is None else con.rate_scale()
+                       for con in map(grid.contention_model,
+                                      (group[i] for i in first))])[ci]
+        rate = np.where(cs != 1.0, rate * cs, rate)
+        pt = (per_link("mtu_bytes") / (rate * (1.0 - loss))
+              + per_link("t_prop_s") + per_link("t_ack_s"))
+
+        _, first, fi = _codes(list(map(attrgetter("compression"), group)))
+        variants = [grid.variant_for(group[i]) for i in first]
+        pairs, kinv = np.unique(p * len(variants) + fi, return_inverse=True)
+        packets: dict[tuple[int, float], np.ndarray] = {}
+        K = []
+        for pair in pairs.tolist():
+            mtu = links[pair // len(variants)].mtu_bytes
+            v = variants[pair % len(variants)]
+            factor = 1.0 if v is None else v.compression_factor
+            if (mtu, factor) not in packets:
+                act = act_raw if v is None else np.where(
+                    act_raw > 0, np.ceil(act_raw / factor), 0.0)
+                packets[mtu, factor] = np.where(
+                    act > 0, np.ceil(act / mtu), 0.0)
+            K.append(packets[mtu, factor])
+        TX = np.stack(K)[kinv.reshape(-1)] * pt[:, None]
+        TX[:, -1] = 0.0
+        sp.set_metadata(packet_vectors=len(packets))
+        AIR = ENC = None
+        if any(v is not None for v in variants):
+            enc = np.zeros((len(variants), act_raw.size))
+            for f, v in enumerate(variants):
+                if v is not None:
+                    enc[f] = np.where(
+                        act_raw > 0,
+                        v.encoder_t_s + act_raw * v.encoder_s_per_byte, 0.0)
+            enc[:, -1] = 0.0
+            AIR, ENC = TX, enc[fi]
+            has_v = np.array([v is not None for v in variants])[fi]
+            TX = np.where(has_v[:, None], AIR + ENC, AIR)
+        return (TX, AIR, ENC, per_link("t_setup_s"), per_link("t_feedback_s"),
+                per_link("tx_power_w"), per_link("rx_power_w"))
 
 
 def _energy_terms(
@@ -2192,51 +2267,18 @@ def _sweep_groups(grid: ScenarioGrid, solver: str, backend: str,
         profile = grid.models[model_name]
         t0 = time.perf_counter()
         with span("sweep.build"):
-            n_max = max(sc.n_devices for sc in group)
-            ns = np.array([sc.n_devices for sc in group], dtype=np.int64)
-            with span("sweep.bank"):
-                base_model = SplitCostModel(
-                    profile=profile, devices=grid.devices_for(group[0]),
-                    link=next(iter(grid.links.values())),
-                    objective=grid.objective,
-                )
-                # profile bank: one local matrix per (device profile,
-                # is-first); every scenario's tensor is ONE vectorized
-                # gather over the stacked bank, so heterogeneous mixes
-                # cost O(bank) matrix builds + a single fancy-index, not
-                # O(S) Python copies
-                bank_rows: dict[tuple[DeviceProfile, bool], int] = {}
-                bank_mats: list[np.ndarray] = []
-
-                def bank_index(dev: DeviceProfile, is_first: bool) -> int:
-                    key = (dev, is_first)
-                    row = bank_rows.get(key)
-                    if row is None:
-                        row = len(bank_mats)
-                        bank_rows[key] = row
-                        bank_mats.append(
-                            base_model._local_cost_matrix(dev, is_first))
-                    return row
-
-                bank_idx = np.zeros((len(group), n_max), dtype=np.int64)
-                for gi, sc in enumerate(group):
-                    devs = grid.devices_for(sc)
-                    for k in range(1, sc.n_devices + 1):
-                        dev = devs[0] if len(devs) == 1 else devs[k - 1]
-                        bank_idx[gi, k - 1] = bank_index(dev, k == 1)
-                    # device slots beyond a scenario's own fleet size keep
-                    # row 0 filler: the solvers never read them (the
-                    # per-scenario n_devices vector masks every k > n_s)
-                bank = np.stack(bank_mats)
+            ns = np.fromiter(map(attrgetter("n_devices"), group), np.int64,
+                             len(group))
+            bank, bank_rows, bank_idx = _group_bank(
+                grid, profile, group, int(ns.max()))
             # TX = airtime + encoder time per scenario (AIR/ENC split them
-            # out for energy pricing; None when the group is all-identity)
-            with span("sweep.tx"):
-                # (S_g, L) x 3, (S_g,) x 4
-                TX, AIR, ENC, setup_s, feedback_s, tx_p, rx_p = \
-                    _group_tx_vectors(grid, profile, group)
-            budgets = np.array(
-                [INF if sc.energy_budget is None else float(sc.energy_budget)
-                 for sc in group])
+            # out for energy pricing; None when the group is all-identity);
+            # (S_g, L) x 3, (S_g,) x 4
+            TX, AIR, ENC, setup_s, feedback_s, tx_p, rx_p = \
+                _group_tx_vectors(grid, profile, group)
+            caps, _, bi = _codes(list(map(attrgetter("energy_budget"), group)))
+            budgets = np.array([INF if cap is None else float(cap)
+                                for cap in caps])[bi]
             budgeted = bool(np.isfinite(budgets).any())
             # fused path: the kernel builds C[s,k] = bank[idx] + TX[s]
             # inside each reduction step — the (S_g, N, L, L) tensor is

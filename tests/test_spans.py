@@ -169,6 +169,26 @@ def test_budgeted_build_is_one_gather_a_group(tmp_path, backend):
     assert res.n_scenarios == grid.size
 
 
+@pytest.mark.parametrize("protocols,mtus", [(("udp", "ble"), 2),
+                                             (("udp", "tcp"), 1)])
+def test_build_spans_count_slot_rows_and_packet_vectors(tmp_path, protocols,
+                                                        mtus):
+    """``.bank`` builds one slot row per (mix, fleet size); ``.tx`` one
+    packet-count vector per distinct (MTU, compression factor): UDP and
+    TCP share an MTU of 1,460 B, BLE's is 512 B."""
+    slow = dataclasses.replace(ESP32, name="slow", compute_scale=1.5)
+    grid = ScenarioGrid(
+        models={"r50": resnet50_cost_profile()},
+        links={p: PROTOCOLS[p] for p in protocols}, n_devices=(2, 3, 4),
+        loss_p=(None, 0.05), compression_factors=(1.0, 2.0),
+        device_mixes={"esp32": (ESP32,), "slow_head": (slow,) + (ESP32,) * 3})
+    _, spans = traced(tmp_path, lambda: sweep(grid, backend="numpy"))
+    (bank,) = named(spans, "repro.sweep.bank")
+    (tx,) = named(spans, "repro.sweep.tx")
+    assert bank.stats == {"slot_rows": 2 * 3}
+    assert tx.stats == {"packet_vectors": mtus * 2}
+
+
 def _pallas_launch(rows):
     N, Lp, Sp = 5, 128, -(-rows // 8) * 8  # block_s 8
     return {"kernel": "solve_fused_walk", "rows": rows, "rows_padded": Sp,
